@@ -334,6 +334,14 @@ def admm_update(
 # --------------------------------------------------------------- deploy ----
 
 
+def materialize_block(blk: BlockSLR, dtype) -> jax.Array:
+    """X_hat = L + S of one block. L = P @ Vt is taken at full precision: a
+    TPU's default runs a float32 matmul as one bfloat16 pass, which would
+    round the deployed weights themselves."""
+    low_rank = jnp.matmul(blk.p, blk.vt, precision=jax.lax.Precision.HIGHEST)
+    return (low_rank + sparse.to_dense(blk.s_coo).astype(blk.p.dtype)).astype(dtype)
+
+
 def surrogate_params(params: Any, state: SLRState, blocks: list[BlockInfo]) -> Any:
     """X_hat = L + S for selected blocks; other leaves pass through.
 
@@ -346,9 +354,7 @@ def surrogate_params(params: Any, state: SLRState, blocks: list[BlockInfo]) -> A
 
         name = path_str(path)
         if name in by_name and name in state:
-            blk = state[name]
-            dense = blk.p @ blk.vt + sparse.to_dense(blk.s_coo).astype(blk.p.dtype)
-            return dense.astype(leaf.dtype)
+            return materialize_block(state[name], leaf.dtype)
         return leaf
 
     return jax.tree_util.tree_map_with_path(replace_leaf, params)

@@ -28,7 +28,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 __all__ = ["BsrMatrix", "bsr_from_dense", "bsr_to_dense", "bsr_matmul_pallas"]
 
@@ -156,7 +155,7 @@ def _kernel(scalars_ref, x_ref, vals_ref, y_ref, acc_ref, *, maxb: int):
 
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
 def bsr_matmul_pallas(
-    x: jax.Array, bsr: BsrMatrix, bt: int = 128, interpret: bool = True
+    x: jax.Array, bsr: BsrMatrix, bt: int = 128, *, interpret: bool
 ) -> jax.Array:
     """y = x @ S for block-CSC S. x: (T, n) -> y: (T, m)."""
     t_dim, n = x.shape
@@ -198,7 +197,7 @@ def bsr_matmul_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t_pad, m_pad), x.dtype),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")
         ),
     )(scalars, x, bsr.vals)

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -80,7 +79,8 @@ def flash_attention_pallas(
     scale: float | None = None,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     b, hq, t, d = q.shape
     _, hkv, s, _ = k.shape
@@ -121,7 +121,7 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(qf, kf, vf)

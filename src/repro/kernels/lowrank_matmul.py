@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _kernel(x_ref, p_ref, vt_ref, y_ref, t_ref, *, k_tiles: int):
     phase = pl.program_id(1)
@@ -59,7 +57,8 @@ def lowrank_matmul_pallas(
     bm: int = 128,
     bk: int = 128,
     bn: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     t_dim, k_dim = x.shape
     r = p.shape[1]
@@ -106,7 +105,7 @@ def lowrank_matmul_pallas(
         out_shape=jax.ShapeDtypeStruct((t_pad, m_pad), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, r), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
     )(x, p, vt)[:t_dim, :m_dim]

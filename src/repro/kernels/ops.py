@@ -1,13 +1,16 @@
 """Public jit'd entry points for the Pallas kernels.
 
-``interpret`` defaults to auto: Pallas interpret mode when no TPU is attached
-(this container), compiled Mosaic on real TPU. Models and the serving engine
-call these through the ``kernel_impl`` config switch; everything falls back to
-the pure-jnp reference implementations under ``kernel_impl='xla'`` so pjit /
-GSPMD sharding is never blocked by a kernel.
+``interpret`` resolves, in order: the argument; the mode named by an
+enclosing :func:`interpret_mode` block; else Pallas interpret mode when the
+default backend is not a TPU, compiled Mosaic on one. Models and the serving
+engine call these through the ``kernel_impl`` config switch; everything falls
+back to the pure-jnp reference implementations under ``kernel_impl='xla'`` so
+pjit / GSPMD sharding is never blocked by a kernel.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 
 import jax
@@ -29,6 +32,7 @@ from .slr_matmul import (
 from .soft_threshold import soft_threshold_pallas
 
 __all__ = [
+    "interpret_mode",
     "BsrMatrix",
     "BsrStack",
     "bsr_from_dense",
@@ -48,8 +52,26 @@ __all__ = [
 ]
 
 
-@functools.cache
+_INTERPRET: contextvars.ContextVar[bool | None] = contextvars.ContextVar(
+    "pallas_interpret", default=None
+)
+
+
+@contextlib.contextmanager
+def interpret_mode(interpret: bool):
+    """Kernels traced inside the block run in Pallas interpret mode (True) or
+    compiled for the TPU (False), whatever the default backend is."""
+    token = _INTERPRET.set(interpret)
+    try:
+        yield
+    finally:
+        _INTERPRET.reset(token)
+
+
 def _auto_interpret() -> bool:
+    chosen = _INTERPRET.get()
+    if chosen is not None:
+        return chosen
     return jax.default_backend() != "tpu"
 
 
@@ -181,21 +203,30 @@ def slr_matmul_multi(x, p, vt, stack: BsrStack | None, ids,
 
 
 def flash_attention(q, k, v, causal=True, interpret: bool | None = None, **kw):
-    return flash_attention_pallas(
-        q, k, v, causal=causal,
+    fn = functools.partial(
+        flash_attention_pallas, causal=causal,
         interpret=_auto_interpret() if interpret is None else interpret, **kw
     )
+    mesh = _head_shard_mesh(q.shape[1], k.shape[1])
+    if mesh is not None:
+        from jax.sharding import PartitionSpec as P
+
+        heads = P(None, "model", None, None)   # (B, H, T, D) heads sharded
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=(heads, heads, heads),
+                           out_specs=heads, check_vma=False)
+    return fn(q, k, v)
 
 
 def _head_shard_mesh(num_q_heads: int, num_kv_heads: int):
-    """The active mesh, iff paged attention should shard_map over heads.
+    """The active mesh, iff an attention kernel should shard_map over heads.
 
-    The Pallas paged kernels use scalar-prefetched DMA index maps, which GSPMD
-    cannot partition — so under an active mesh with model > 1 the dispatchers
-    below wrap them in ``shard_map`` over the KV-head axis. Per-(batch, head)
-    attention is independent, and GQA groups stay co-located (hq/m q heads +
-    hkv/m kv heads per rank), so the body needs NO collective; the psum
-    happens later at the row-parallel o-projection, exactly as for dense TP.
+    GSPMD cannot partition a Mosaic kernel (the TPU compiler refuses one with
+    sharded operands), so under an active mesh with model > 1 the attention
+    dispatchers wrap their kernels in ``shard_map`` over the KV-head axis.
+    Per-(batch, head) attention is independent, and GQA groups stay
+    co-located (hq/m q heads + hkv/m kv heads per rank), so the body needs NO
+    collective; the psum happens later at the row-parallel o-projection,
+    exactly as for dense TP.
     """
     from ..parallel.sharding import current_mesh
 
@@ -213,10 +244,9 @@ def paged_attention(q, k_pages, v_pages, block_table, lengths,
     interp = _auto_interpret() if interpret is None else interpret
     mesh = _head_shard_mesh(q.shape[1], k_pages.shape[1])
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(paged_attention_pallas, interpret=interp),
             mesh=mesh,
             in_specs=(
@@ -227,7 +257,7 @@ def paged_attention(q, k_pages, v_pages, block_table, lengths,
                 P(),                           # lengths
             ),
             out_specs=P(None, "model", None),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(q, k_pages, v_pages, block_table, lengths)
     return paged_attention_pallas(
@@ -244,10 +274,9 @@ def paged_attention_kquery(q, k_pages, v_pages, block_table, lengths,
     interp = _auto_interpret() if interpret is None else interpret
     mesh = _head_shard_mesh(q.shape[1], k_pages.shape[1])
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(
                 paged_attention_kquery_pallas, interpret=interp, q_tile=q_tile
             ),
@@ -260,7 +289,7 @@ def paged_attention_kquery(q, k_pages, v_pages, block_table, lengths,
                 P(),
             ),
             out_specs=P(None, "model", None, None),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(q, k_pages, v_pages, block_table, lengths)
     return paged_attention_kquery_pallas(

@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _copy_kernel(ids_ref, src_ref, o_ref):
     del ids_ref  # consumed by the index maps
@@ -39,7 +37,8 @@ def page_copy_pallas(
     pool: jax.Array,   # (L, num_pages, H, bs, D) — payload or scale pool
     src: jax.Array,    # (n,) int32 source page ids
     dst: jax.Array,    # (n,) int32 destination page ids
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     """``out[:, dst[i]] = pool[:, src[i]]``; every other page unchanged."""
     l, _, h, bs, d = pool.shape
@@ -63,7 +62,7 @@ def page_copy_pallas(
         # written, everything else stays in place (no full-pool roundtrip)
         input_output_aliases={1: 0},
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
     )(ids, pool)

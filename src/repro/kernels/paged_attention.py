@@ -39,7 +39,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -93,7 +92,8 @@ def paged_attention_pallas(
     v_pages: jax.Array,
     block_table: jax.Array,  # (B, pages_per_slot) int32
     lengths: jax.Array,      # (B,) int32 pre-insert valid length per slot
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     b, hq, d = q.shape
     n, hkv, bs, _ = k_pages.shape
@@ -137,7 +137,7 @@ def paged_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hkv, group, d), q.dtype),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
     )(tables, qf, k_pages, v_pages)
@@ -209,7 +209,8 @@ def paged_attention_kquery_pallas(
     v_pages: jax.Array,
     block_table: jax.Array,  # (B, pages_per_slot) int32
     lengths: jax.Array,      # (B,) int32 pre-insert valid length per slot
-    interpret: bool = True,
+    *,
+    interpret: bool,
     q_tile: int | None = None,  # queries per grid tile; None = auto (whole
     #                             window while kq * group <= _MAX_Q_ROWS)
 ) -> jax.Array:
@@ -272,7 +273,7 @@ def paged_attention_kquery_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hkv, kq_pad * group, d), q.dtype),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(tables, qf, k_pages, v_pages)

@@ -48,7 +48,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .bsr_matmul import BsrMatrix
-from .compat import CompilerParams
 
 __all__ = [
     "BsrStack",
@@ -199,7 +198,8 @@ def slr_matmul_pallas(
     vt: jax.Array,     # (r, M)
     bsr: BsrMatrix,    # block-CSC S, shape (K, M)
     bt: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     """y = x @ P @ Vt + x @ S in one Pallas pass. x: (T, K) -> y: (T, M)."""
     t_dim, k_dim = x.shape
@@ -262,7 +262,7 @@ def slr_matmul_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t_pad, m_pad), x.dtype),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
     )(scalars, x, p, vt, bsr.vals)
@@ -320,7 +320,8 @@ def slr_matmul_stacked_pallas(
     stack: BsrStack,   # per-layer block-CSC S, shape (K, M)
     layer: jax.Array,  # () int32 — which layer's tables to use
     bt: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     """Layer-``lax.scan``-able fused SLR matmul.
 
@@ -393,7 +394,7 @@ def slr_matmul_stacked_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t_pad, m_pad), x.dtype),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
     )(scalars, x, p, vt, stack.vals)
@@ -452,7 +453,8 @@ def slr_matmul_multi_pallas(
     stack: BsrStack,   # per-adapter block-CSC S, shape (K, M)
     ids: jax.Array,    # (B,) int32 — slot -> adapter row
     bt: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     """Batched heterogeneous-adapter fused SLR matmul: y[b] uses adapter
     ``ids[b]``'s (P, Vt, S) tables.
@@ -539,7 +541,7 @@ def slr_matmul_multi_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b_dim * t_pad, m_pad), x.dtype),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
     )(scalars, x, p, vt, stack.vals)

@@ -27,7 +27,8 @@ def soft_threshold_pallas(
     x: jax.Array,
     tau: jax.Array | float,
     block: tuple[int, int] = DEFAULT_BLOCK,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     """Shrinkage of a 2-D array, tiled (block[0], block[1]) in VMEM."""
     n, m = x.shape

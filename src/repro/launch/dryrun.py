@@ -304,7 +304,7 @@ def _compile_cell(
         surrogate_dtype=jnp.bfloat16,
     ) if salaad else None
 
-    with mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             state_abs = abstract_train_state(params_abs, scfg)
             blocks = select_blocks(params_abs, scfg.selection) if scfg else []

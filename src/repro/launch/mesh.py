@@ -25,7 +25,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ServingMesh.AXES
     # ServingMesh validates the axis names (it allows the leading "pod")
-    return ServingMesh(jax.make_mesh(shape, axes)).mesh
+    # Auto axes: the model code places arrays through sharding constraints
+    # (parallel.sharding.constrain), which Explicit axes would reject
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return ServingMesh(jax.make_mesh(shape, axes, axis_types=auto)).mesh
 
 
 def make_host_mesh(data: int | None = None, model: int = 1):

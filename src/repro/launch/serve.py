@@ -44,6 +44,7 @@ from repro.configs.base import get_arch
 from repro.core.admm import SalaadConfig, init_slr_state
 from repro.core.hpa import hpa_keep_ratio
 from repro.core.selection import SelectionConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as model_lib
 from repro.serving.adapters import AdapterBank, adapterize
 from repro.serving.deployed import DeployedModel
@@ -292,6 +293,7 @@ def main():
                          "turns on LRU swapping (None = all resident)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -29,6 +29,7 @@ from repro.configs.base import get_arch                       # noqa: E402
 from repro.core.admm import SalaadConfig                      # noqa: E402
 from repro.core.selection import SelectionConfig              # noqa: E402
 from repro.data.synthetic import DataConfig, SyntheticC4      # noqa: E402
+from repro.launch.compile_cache import use_compile_cache      # noqa: E402
 from repro.optim.adam import AdamConfig                       # noqa: E402
 from repro.train.trainer import Trainer, TrainerConfig        # noqa: E402
 
@@ -49,6 +50,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--distributed", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.distributed:
         jax.distributed.initialize()

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..parallel.sharding import _current_mesh, logical_to_mesh
+from ..parallel.sharding import current_mesh, logical_to_mesh
 
 
 def init_moe(key, d: int, d_ff: int, num_experts: int, dtype) -> dict:
@@ -117,7 +117,7 @@ def moe_ffn(
 ) -> tuple[jax.Array, jax.Array]:
     """Returns (output (B, T, d), aux_loss ())."""
     b, t, d = x.shape
-    mesh = _current_mesh()
+    mesh = current_mesh()
     tp = mesh.shape.get("model", 1) if mesh is not None else 1
 
     if mesh is not None and tp > 1 and num_experts % tp == 0:
@@ -141,10 +141,8 @@ def moe_ffn(
             aux = jax.lax.pmean(aux, dp_ax) if dp_ax else aux
             return out.reshape(bl, t, d), aux[None]
 
-        from jax.experimental.shard_map import shard_map
-
         batch_ax = dp_ax if b % max(dp, 1) == 0 else None
-        fn = shard_map(
+        fn = jax.shard_map(
             local_fn,
             mesh=mesh,
             in_specs=(
@@ -153,7 +151,7 @@ def moe_ffn(
                 jax.tree.map(lambda _: P("model", None, None), params["experts"]),
             ),
             out_specs=(P(batch_ax, None, None), P(None)),
-            check_rep=False,
+            check_vma=False,
         )
         out, aux = fn(x, params["router"], params["experts"])
         return out.astype(x.dtype), jnp.mean(aux)

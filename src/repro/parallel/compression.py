@@ -76,16 +76,14 @@ def make_compressed_grad_fn(loss_fn, mesh: Mesh, data_axis: str = "data"):
         return compressed_psum_tree(g, data_axis)
 
     def grad_fn(params, batch):
-        from jax.experimental.shard_map import shard_map
-
         batch_specs = jax.tree.map(lambda _: P(data_axis), batch)
         param_specs = jax.tree.map(lambda _: P(), params)
-        fn = shard_map(
+        fn = jax.shard_map(
             local_grads,
             mesh=mesh,
             in_specs=(param_specs, batch_specs),
             out_specs=param_specs,
-            check_rep=False,
+            check_vma=False,
         )
         return fn(params, batch)
 

@@ -16,7 +16,7 @@ from typing import Any
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
     "constrain",
@@ -35,22 +35,11 @@ __all__ = [
 ]
 
 
-def _current_mesh() -> Mesh | None:
-    mesh = jax.sharding.get_abstract_mesh() if hasattr(jax.sharding, "get_abstract_mesh") else None
-    try:
-        from jax._src import mesh as mesh_lib
-
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
-
-
-def current_mesh() -> Mesh | None:
-    """The active physical mesh (``with mesh:`` context), or None."""
-    return _current_mesh()
+def current_mesh() -> AbstractMesh | None:
+    """The mesh made active by ``jax.set_mesh`` (or a :class:`ServingMesh`
+    context), or None. Readable at trace time, inside ``jit``."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def dp_axes(mesh: Mesh):
@@ -89,7 +78,7 @@ def logical_to_mesh(axis: str | None, mesh: Mesh) -> Any:
 
 def constrain(x: jax.Array, logical: tuple[str | None, ...]) -> jax.Array:
     """with_sharding_constraint by logical axis names; no-op without a mesh."""
-    mesh = _current_mesh()
+    mesh = current_mesh()
     if mesh is None:
         return x
     spec = P(*(logical_to_mesh(a, mesh) for a in logical))
@@ -306,6 +295,7 @@ class ServingMesh:
                 f"('pod', 'data', 'model'); got unknown {extra}"
             )
         self.mesh = mesh
+        self._scopes: list[jax.set_mesh] = []
 
     @classmethod
     def create(cls, *, data: int = 1, model: int = 1, devices=None) -> "ServingMesh":
@@ -361,8 +351,8 @@ class ServingMesh:
     # -------------------------------------------------------------- context --
 
     def __enter__(self) -> "ServingMesh":
-        self.mesh.__enter__()
+        self._scopes.append(jax.set_mesh(self.mesh))
         return self
 
     def __exit__(self, *exc):
-        return self.mesh.__exit__(*exc)
+        self._scopes.pop().__exit__(*exc)

@@ -38,8 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import sparse
-from ..core.admm import SLRState, surrogate_params
+from ..core.admm import SLRState, materialize_block, surrogate_params
 from ..core.selection import BlockInfo, path_str
 from ..models import model as model_lib
 from .slr_params import SLRLinear, build_slr_linears, coo_to_bsr, coo_to_bsr_stack
@@ -54,12 +53,6 @@ def is_linear_site(info: BlockInfo) -> bool:
     """Can this block be served structured (its use site is a plain matmul)?"""
     last = info.name.split("/")[-1]
     return last in _LINEAR_KEYS and "moe" not in info.name and not info.is_embedding
-
-
-def _materialize_dense(blk, leaf_dtype) -> jax.Array:
-    """X_hat = L + S for blocks that cannot be served structured."""
-    dense = blk.p @ blk.vt + sparse.to_dense(blk.s_coo).astype(blk.p.dtype)
-    return dense.astype(leaf_dtype)
 
 
 def _coo_slice_to_bsr(lin: SLRLinear, bsr_block: int) -> SLRLinear:
@@ -135,7 +128,7 @@ class DeployedModel:
                 return leaf
             if is_linear_site(info):
                 return linears[name]
-            return _materialize_dense(state[name], leaf.dtype)
+            return materialize_block(state[name], leaf.dtype)
 
         serving = jax.tree_util.tree_map_with_path(replace_leaf, params)
 

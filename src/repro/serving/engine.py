@@ -716,6 +716,17 @@ class ServingEngine:
             "metrics": self.metrics.snapshot(),
         }
 
+    def lower_decode(self, tier: int = 0) -> jax.stages.Lowered:
+        """Tier ``tier``'s decode program lowered at the engine's live
+        argument shapes, for inspection (compiled text, memory analysis)."""
+        s = self.ecfg.max_slots
+        with self._mesh_scope():
+            return self._decode.lower(
+                self._call_params(tier, self._slot_pool),
+                jnp.zeros((s, 1), jnp.int32), self._device_cache(),
+                jnp.zeros((s,), bool), jnp.asarray(self._steps, jnp.int32),
+            )
+
     # ------------------------------------------------------------ intake ---
 
     def submit(self, prompt: list[int], max_new_tokens: int = 16,

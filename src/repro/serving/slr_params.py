@@ -176,13 +176,15 @@ class SLRLayerView:
 
 
 def _fit_block(n: int, m: int, bsr_block: int) -> int:
-    """Halve the block size while it divides neither dim (floor 8) — keeps
-    tile granularity useful on small matrices. A size that still doesn't
-    divide is fine: ``bsr_from_dense`` zero-pads trailing partial blocks."""
-    bs = bsr_block
-    while (n % bs or m % bs) and bs > 8:
-        bs //= 2
-    return bs
+    """``bsr_block``, or one block spanning both dims (rounded up to the
+    sublane tile) when the matrix is smaller than that.
+
+    The kernels' x and y blocks take the block size as their lane dimension,
+    which Mosaic tiles by 128 unless the block spans the whole array — so the
+    size is never shrunk to divide a dim (512x1376 would fall to 32, refused
+    on TPU); ``bsr_from_dense`` zero-pads the trailing partial blocks instead.
+    On TPU ``bsr_block`` must be a multiple of 128 (the default is)."""
+    return min(bsr_block, -(-max(n, m) // 8) * 8)
 
 
 def coo_to_bsr(s_coo: sparse.CooMatrix, bsr_block: int) -> BsrMatrix:

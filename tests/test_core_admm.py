@@ -192,6 +192,15 @@ class TestADMMCycle:
         rel = float(jnp.linalg.norm(surr["embed"]["embedding"] - x) / jnp.linalg.norm(x))
         assert rel < 0.12  # ground-truth SLR matrix is recoverable
 
+    def test_surrogate_low_rank_product_at_full_precision(self):
+        """A TPU's default precision would round L = P @ Vt through bfloat16;
+        the deployed weights ask for HIGHEST whatever the backend."""
+        state, _ = admm_update(self.params, self.state, self.blocks, self.cfg, 0)
+        hlo = jax.jit(surrogate_params, static_argnums=2).lower(
+            self.params, state, self.blocks).as_text()
+        dots = [ln for ln in hlo.splitlines() if "dot_general" in ln]
+        assert dots and all("precision = [HIGHEST, HIGHEST]" in ln for ln in dots)
+
     def test_dual_update_identity(self):
         """Y_{k+1} - Y_k == rho (X - L - S) (ADMM dual ascent, Eq. 5)."""
         cfg = SalaadConfig(

@@ -33,7 +33,7 @@ COMMON = textwrap.dedent(
     """
     import json
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     from repro.configs.base import get_arch
     from repro.core.admm import SalaadConfig
     from repro.core.selection import SelectionConfig, select_blocks
@@ -63,9 +63,9 @@ class TestShardedTraining:
         prog = COMMON + textwrap.dedent(
             """
             cfg, state, step, data, blocks = build()
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
             pshard = param_sharding_tree(state.params, mesh)
-            with mesh:
+            with jax.set_mesh(mesh):
                 jstep = jax.jit(step)
                 for s in range(3):
                     state, metrics = jstep(state, data.batch(s))
@@ -76,8 +76,8 @@ class TestShardedTraining:
             """
         )
         multi = run_py(prog, devices=8)
-        single = run_py(prog.replace('jax.make_mesh((4, 2), ("data", "model"))',
-                                     'jax.make_mesh((1, 1), ("data", "model"))'),
+        single = run_py(prog.replace('jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)',
+                                     'jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)'),
                         devices=8)
         assert abs(multi["loss"] - single["loss"]) < 2e-3
         assert abs(multi["w0"] - single["w0"]) / (abs(single["w0"]) + 1e-9) < 1e-3
@@ -89,7 +89,7 @@ class TestShardedTraining:
             """
             from repro.launch.dryrun import batch_shardings, slr_shardings
             cfg, state, step, data, blocks = build()
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
             pshard = param_sharding_tree(state.params, mesh)
             state = jax.device_put(state, state._replace(
                 params=pshard,
@@ -99,7 +99,7 @@ class TestShardedTraining:
                 step=NamedSharding(mesh, P()),
             ))
             losses = []
-            with mesh:
+            with jax.set_mesh(mesh):
                 jstep = jax.jit(step, donate_argnums=(0,))
                 for s in range(8):
                     state, metrics = jstep(state, data.batch(s))
@@ -124,8 +124,8 @@ class TestShardedTraining:
             batch = data.batch(0)
             batch = {k: v[:4] for k, v in batch.items()}
             loss_single, _ = model_lib.loss_fn(state.params, batch, cfg)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
-            with mesh:
+            mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+            with jax.set_mesh(mesh):
                 loss_sharded, _ = jax.jit(
                     lambda p, b: model_lib.loss_fn(p, b, cfg)
                 )(state.params, batch)
@@ -148,8 +148,8 @@ class TestElasticRestore:
             f"""
             from repro.train import checkpoint
             cfg, state, step, data, blocks = build()
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
-            with mesh:
+            mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+            with jax.set_mesh(mesh):
                 jstep = jax.jit(step)
                 for s in range(2):
                     state, m = jstep(state, data.batch(s))
@@ -164,7 +164,7 @@ class TestElasticRestore:
             from repro.train import checkpoint
             from repro.launch.dryrun import slr_shardings
             cfg, state, step, data, blocks = build()
-            mesh = jax.make_mesh((2, 2), ("data", "model"))
+            mesh = jax.make_mesh((2, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
             pshard = param_sharding_tree(state.params, mesh)
             shardings = state._replace(
                 params=pshard,
@@ -175,7 +175,7 @@ class TestElasticRestore:
             )
             state = checkpoint.restore({ckpt!r}, state, shardings=shardings)
             assert int(state.step) == 2
-            with mesh:
+            with jax.set_mesh(mesh):
                 jstep = jax.jit(step)
                 state, m = jstep(state, data.batch(2))
             print(json.dumps({{"loss": float(m["loss"]), "step": int(state.step)}}))
@@ -192,8 +192,9 @@ class TestMultiPodMesh:
         prog = COMMON + textwrap.dedent(
             """
             cfg, state, step, data, blocks = build(salaad=False)
-            mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-            with mesh:
+            mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                                 axis_types=(AxisType.Auto,) * 3)
+            with jax.set_mesh(mesh):
                 jstep = jax.jit(step)
                 state, m = jstep(state, data.batch(0))
             print(json.dumps({"loss": float(m["loss"])}))

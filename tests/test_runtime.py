@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs.base import get_arch
 from repro.core.admm import SalaadConfig
@@ -209,16 +210,17 @@ class TestGradCompression:
 
     def test_compressed_psum_matches_mean(self):
         """int8 all-reduce mean within quantization error of the exact mean."""
-        mesh = jax.make_mesh((len(jax.devices()),), ("data",))
-        from jax.experimental.shard_map import shard_map
+        mesh = jax.make_mesh(
+            (len(jax.devices()),), ("data",), axis_types=(AxisType.Auto,)
+        )
         from jax.sharding import PartitionSpec as P
 
         n = mesh.shape["data"]
         g = jax.random.normal(jax.random.PRNGKey(1), (n, 64))
 
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda x: compressed_psum_tree({"g": x[0]}, "data")["g"][None],
-            mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_rep=False,
+            mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False,
         )
         out = fn(g)  # (n, 64): each shard returns the reduced mean
         exact = jnp.mean(g, axis=0)
@@ -226,8 +228,7 @@ class TestGradCompression:
         np.testing.assert_allclose(out[0], exact, atol=2 * scale)
 
     def test_error_feedback_reduces_bias(self):
-        mesh = jax.make_mesh((1,), ("data",))
-        from jax.experimental.shard_map import shard_map
+        mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
         from jax.sharding import PartitionSpec as P
 
         g = {"w": jnp.full((64,), 0.003)}  # small constant grad: EF must not lose it
@@ -236,10 +237,10 @@ class TestGradCompression:
         def step(gv, rv):
             return compressed_psum_tree({"w": gv}, "data", {"w": rv})
 
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda gv, rv: step(gv[0], rv[0]),
             mesh=mesh, in_specs=(P("data"), P("data")), out_specs=P("data"),
-            check_rep=False,
+            check_vma=False,
         )
         total = jnp.zeros(64)
         gg, rr = g["w"][None], r["w"][None]
@@ -294,3 +295,33 @@ class TestServingEngine:
             tok = int(jnp.argmax(lg[0, -1]))
             ref.append(tok)
         assert out == ref
+
+
+class TestCompileCache:
+    """The launchers' persistent compilation cache: the environment's
+    directory when ``JAX_COMPILATION_CACHE_DIR`` is set, else one fixed,
+    git-ignored directory inside the checkout."""
+
+    @pytest.fixture(autouse=True)
+    def restore_config(self):
+        was = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_environment_wins(self, monkeypatch, tmp_path):
+        from repro.launch.compile_cache import use_compile_cache
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+
+    def test_fixed_checkout_dir_when_unset(self, monkeypatch):
+        from pathlib import Path
+
+        from repro.launch.compile_cache import CHECKOUT_CACHE_DIR, use_compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = Path(__file__).resolve().parents[1]
+        assert use_compile_cache() == str(CHECKOUT_CACHE_DIR)
+        assert CHECKOUT_CACHE_DIR == root / ".jax_cache"
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
