@@ -119,7 +119,7 @@ def run(
     def install(arm: str):
         tel = off_tel if arm == "telemetry_off" else on_tel
         tr = tracer if arm == "traced" else None
-        eng.metrics, eng.tracer, tel.tracer = tel, tr, tr
+        eng.metrics, eng.tracer = tel, tr
 
     _drive(eng, trace)                         # warmup (traced: every path)
 

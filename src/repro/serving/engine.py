@@ -678,11 +678,10 @@ class ServingEngine:
 
     def start_trace(self, tracer: RequestTracer | None = None) -> RequestTracer:
         """Attach a per-request span tracer (serving/trace.py); subsequent
-        activity records slot-track spans and program-track slices. Returns
-        the tracer (``tracer.save_chrome(path)`` / ``save_jsonl(path)``)."""
+        activity records slot-track spans. Returns the tracer
+        (``tracer.save_chrome(path)`` / ``save_jsonl(path)``)."""
         self.tracer = tracer if tracer is not None \
             else RequestTracer(type(self).__name__)
-        self.metrics.tracer = self.tracer
         return self.tracer
 
     # Migrated ad-hoc counters: read-only views over the metrics registry
@@ -713,6 +712,7 @@ class ServingEngine:
             "prefill_calls": self.prefill_calls,
             "prefill_traces": self.prefill_traces,
             "jit_retraces": self.metrics.retraces(),
+            "slowest_step": self.metrics.slowest_step(),
             "metrics": self.metrics.snapshot(),
         }
 
@@ -985,7 +985,8 @@ class ServingEngine:
                 slot_ids[i] = slot
                 rows[i] = self._slot_pool[slot]
             with self.metrics.measure_program(
-                f"prefill[{bucket}]", key, traces=lambda: self.prefill_traces
+                f"prefill[{bucket}]", key, traces=lambda: self.prefill_traces,
+                rows=len(group),
             ):
                 first, self.cache = self._prefill(
                     self._call_params(key, rows), jnp.asarray(tokens),
@@ -993,10 +994,12 @@ class ServingEngine:
                     jnp.asarray(step, jnp.int32),
                 )
                 self.prefill_calls += 1
-                firsts = np.asarray(first)       # one fetch per tier group
-            for i, (slot, req) in enumerate(group):
-                req.prefill_emitted += 1
-                self._record(slot, req, int(firsts[i]), free, done)
+                with self.metrics.span("serve.fetch"):
+                    firsts = np.asarray(first)   # one fetch per tier group
+            with self.metrics.span("serve.record"):
+                for i, (slot, req) in enumerate(group):
+                    req.prefill_emitted += 1
+                    self._record(slot, req, int(firsts[i]), free, done)
 
     def _record(self, slot: int, req: Request, tok: int, free, done):
         now = _now()
@@ -1087,7 +1090,8 @@ class ServingEngine:
         advance mid-prefill slots by one chunk, then one jitted decode step
         per active tier over the decode-phase slots. Returns requests that
         finished this tick."""
-        with self.metrics.measure_tick():
+        with self.metrics.measure_tick(self._steps + 1, len(self._active),
+                                       len(self._queue)):
             with self._mesh_scope():
                 done = self._step_inner()
             self._update_gauges()
@@ -1098,7 +1102,8 @@ class ServingEngine:
         s = self.ecfg.max_slots
         self._steps += 1
         free = [x for x in range(s) if x not in self._active]
-        self._admit(free, done, self._steps)
+        with self.metrics.span("serve.admit"):
+            self._admit(free, done, self._steps)
         if not self._active:
             return done
         self._update_tier_shift()
@@ -1145,19 +1150,22 @@ class ServingEngine:
             mask = np.zeros((s,), bool)
             mask[slots] = True
             with self.metrics.measure_program(
-                "decode", key, traces=lambda: self.decode_traces
+                "decode", key, traces=lambda: self.decode_traces,
+                rows=len(slots),
             ):
                 nxt, self.cache = self._decode(
                     self._call_params(key, self._slot_pool), tok_dev,
                     self._device_cache(), jnp.asarray(mask), step_dev,
                 )
                 self.decode_calls += 1
-                toks = np.asarray(nxt)       # one host sync per active tier
+                with self.metrics.span("serve.fetch"):
+                    toks = np.asarray(nxt)   # one host sync per active tier
             out[slots] = toks[slots]
-        for slot, req in list(self._active.items()):
-            if slot in self._progress:
-                continue
-            self._record(slot, req, int(out[slot]), free, done)
+        with self.metrics.span("serve.record"):
+            for slot, req in list(self._active.items()):
+                if slot in self._progress:
+                    continue
+                self._record(slot, req, int(out[slot]), free, done)
 
     def run(self, max_steps: int = 10_000) -> list[Request]:
         """Drive everything to completion (batch mode)."""
@@ -1682,9 +1690,10 @@ class PagedServingEngine(ServingEngine):
             firsts = self._prefill_admitted(
                 tokens, lengths, slot_ids, page_map, step, key, rows
             )
-            for i, (slot, req, _, _) in enumerate(group):
-                req.prefill_emitted += 1
-                self._record(slot, req, int(firsts[i]), free, done)
+            with self.metrics.span("serve.record"):
+                for i, (slot, req, _, _) in enumerate(group):
+                    req.prefill_emitted += 1
+                    self._record(slot, req, int(firsts[i]), free, done)
         # prefix hits prefill ONLY the unmatched suffix, through the chunk
         # program (slot-indexed rows starting at s0). The sample key
         # (step, salt=1, slot) matches the one-shot prefill's exactly, so a
@@ -1707,10 +1716,11 @@ class PagedServingEngine(ServingEngine):
                 starts[slot] = s0
             firsts = self._chunk_call(tokens, counts, slot_ids, starts, step,
                                       key)
-            for slot in slots:
-                req = hits[slot][0]
-                req.prefill_emitted += 1
-                self._record(slot, req, int(firsts[slot]), free, done)
+            with self.metrics.span("serve.record"):
+                for slot in slots:
+                    req = hits[slot][0]
+                    req.prefill_emitted += 1
+                    self._record(slot, req, int(firsts[slot]), free, done)
 
     def _alloc(self, n: int) -> list[int] | None:
         """Pool allocation with the prefix cache as the reclaim tail: when
@@ -1738,7 +1748,7 @@ class PagedServingEngine(ServingEngine):
         pad = pairs + [(0, 0)] * (n - len(pairs))
         src = jnp.asarray([p for p, _ in pad], jnp.int32)
         dst = jnp.asarray([q for _, q in pad], jnp.int32)
-        with self.metrics.measure_program(f"page_copy[{n}]"):
+        with self.metrics.measure_program(f"page_copy[{n}]", rows=len(pairs)):
             self._apply_cow(src, dst)
 
     def _apply_cow(self, src: jax.Array, dst: jax.Array):
@@ -1757,6 +1767,7 @@ class PagedServingEngine(ServingEngine):
         with self.metrics.measure_program(
             f"prefill[{tokens.shape[1]}]", tier,
             traces=lambda: self.prefill_traces,
+            rows=int(np.count_nonzero(slot_ids < slot_ids.size)),
         ):
             first, self.cache = self._prefill(
                 self._call_params(tier, rows), jnp.asarray(tokens),
@@ -1765,7 +1776,8 @@ class PagedServingEngine(ServingEngine):
                 jnp.asarray(step, jnp.int32),
             )
             self.prefill_calls += 1
-            return np.asarray(first)
+            with self.metrics.span("serve.fetch"):
+                return np.asarray(first)
 
     def _prefill_progress(self, free: list[int], done: list[Request],
                           step: int):
@@ -1856,16 +1868,17 @@ class PagedServingEngine(ServingEngine):
                                                start=int(starts[slot]),
                                                tokens=int(counts[slot]))
                         self.tracer.end_span(slot, "prefill_chunk", t=t1)
-            for slot in tier_slots:
-                req = self._active.get(slot)
-                if req is None:
-                    continue
-                self._progress[slot] += int(counts[slot])
-                if self._progress[slot] >= self._ptarget[slot]:
-                    del self._progress[slot]
-                    del self._ptarget[slot]
-                    req.prefill_emitted += 1
-                    self._record(slot, req, int(firsts[slot]), free, done)
+            with self.metrics.span("serve.record"):
+                for slot in tier_slots:
+                    req = self._active.get(slot)
+                    if req is None:
+                        continue
+                    self._progress[slot] += int(counts[slot])
+                    if self._progress[slot] >= self._ptarget[slot]:
+                        del self._progress[slot]
+                        del self._ptarget[slot]
+                        req.prefill_emitted += 1
+                        self._record(slot, req, int(firsts[slot]), free, done)
 
     def _chunk_call(self, tokens, counts, slot_ids, starts, step,
                     tier: int = 0):
@@ -1876,6 +1889,7 @@ class PagedServingEngine(ServingEngine):
         with self.metrics.measure_program(
             f"chunk[{tokens.shape[1]}]", tier,
             traces=lambda: self.chunk_traces,
+            rows=int(np.count_nonzero(counts)),
         ):
             first, self.cache = self._chunk_prog(
                 self._call_params(tier, self._slot_pool), jnp.asarray(tokens),
@@ -1884,7 +1898,8 @@ class PagedServingEngine(ServingEngine):
                 jnp.asarray(step, jnp.int32),
             )
             self.chunk_calls += 1
-            return np.asarray(first)
+            with self.metrics.span("serve.fetch"):
+                return np.asarray(first)
 
     def _pre_decode(self, free: list[int], done: list[Request]):
         """Grow each active slot's pages to cover this tick's KV writes; evict
@@ -2140,6 +2155,7 @@ class ReferenceEngine:
             "prefill_calls": 0,
             "prefill_traces": 0,
             "jit_retraces": self.metrics.retraces(),
+            "slowest_step": self.metrics.slowest_step(),
             "metrics": self.metrics.snapshot(),
         }
 
@@ -2188,7 +2204,8 @@ class ReferenceEngine:
         """One engine tick (Engine protocol): admit into free slots, then one
         token for every active slot (the seed per-slot loop — one device call
         and one host sync per slot)."""
-        with self.metrics.measure_tick():
+        with self.metrics.measure_tick(self._steps + 1, len(self._active),
+                                       len(self._queue)):
             done = self._step_inner()
             self.metrics.set_pool(queue=len(self._queue),
                                   active=len(self._active))
@@ -2199,47 +2216,52 @@ class ReferenceEngine:
         self._steps += 1
         tr = self.tracer
         free = [s for s in range(self.ecfg.max_slots) if s not in self._active]
-        while self._queue and free:
-            slot = free.pop()
-            req = self._queue.pop(0)
-            now = _now()
-            self.metrics.on_admit(req, slot, now,
-                                  prefill_tokens=len(req.prompt))
-            req.admitted_at = now
-            self._active[slot] = req
-            if tr is not None:
-                tr.request_begin(slot, req.uid, t=now, tier=req.tier)
-                tr.begin_span(slot, "prefill", t=now, tokens=len(req.prompt))
-            self._prefill_into_slot(slot, req)
-        for slot, req in list(self._active.items()):
-            last = (req.out_tokens or req.prompt)[-1]
-            nxt = self._step_slot(slot, last)
-            req.out_tokens.append(int(nxt))
-            now = _now()
-            req.token_times.append(now)
-            first = req.first_token_at == 0.0
-            if first:
-                req.first_token_at = now
-            self.metrics.on_token(req, now, first)
-            if tr is not None and tr.has_open(slot, "prefill"):
-                tr.end_span(slot, "prefill", t=now)
-                if first:
-                    tr.instant(slot, "first_token", t=now, uid=req.uid)
-                tr.begin_span(slot, "decode", t=now, uid=req.uid)
-            if (
-                len(req.out_tokens) >= req.max_new_tokens
-                or (self.ecfg.eos_token is not None and nxt == self.ecfg.eos_token)
-            ):
-                req.done = True
-                req.finished_at = now
-                self.metrics.on_finish()
+        with self.metrics.span("serve.admit"):
+            while self._queue and free:
+                slot = free.pop()
+                req = self._queue.pop(0)
+                now = _now()
+                self.metrics.on_admit(req, slot, now,
+                                      prefill_tokens=len(req.prompt))
+                req.admitted_at = now
+                self._active[slot] = req
                 if tr is not None:
-                    if tr.has_open(slot, "decode"):
-                        tr.end_span(slot, "decode", t=now)
-                    tr.request_end(slot, req.uid, t=now,
-                                   tokens=len(req.out_tokens))
-                done.append(req)
-                del self._active[slot]
+                    tr.request_begin(slot, req.uid, t=now, tier=req.tier)
+                    tr.begin_span(slot, "prefill", t=now,
+                                  tokens=len(req.prompt))
+                self._prefill_into_slot(slot, req)
+        tokens = {slot: self._step_slot(slot, (req.out_tokens or req.prompt)[-1])
+                  for slot, req in self._active.items()}
+        with self.metrics.span("serve.record"):
+            for slot, nxt in tokens.items():
+                req = self._active[slot]
+                req.out_tokens.append(int(nxt))
+                now = _now()
+                req.token_times.append(now)
+                first = req.first_token_at == 0.0
+                if first:
+                    req.first_token_at = now
+                self.metrics.on_token(req, now, first)
+                if tr is not None and tr.has_open(slot, "prefill"):
+                    tr.end_span(slot, "prefill", t=now)
+                    if first:
+                        tr.instant(slot, "first_token", t=now, uid=req.uid)
+                    tr.begin_span(slot, "decode", t=now, uid=req.uid)
+                if (
+                    len(req.out_tokens) >= req.max_new_tokens
+                    or (self.ecfg.eos_token is not None
+                        and nxt == self.ecfg.eos_token)
+                ):
+                    req.done = True
+                    req.finished_at = now
+                    self.metrics.on_finish()
+                    if tr is not None:
+                        if tr.has_open(slot, "decode"):
+                            tr.end_span(slot, "decode", t=now)
+                        tr.request_end(slot, req.uid, t=now,
+                                       tokens=len(req.out_tokens))
+                    done.append(req)
+                    del self._active[slot]
         return done
 
     def run(self, max_steps: int = 10_000) -> list[Request]:
@@ -2262,18 +2284,20 @@ class ReferenceEngine:
         tok = jnp.asarray([[token]], jnp.int32)
         req = self._active[slot]
         params = self._tier_params[req.tier]
-        with self.metrics.measure_program(
-            "decode_ref", req.tier, traces=lambda: self.decode_traces
-        ):
-            logits, new_sub = self._decode(params, tok, sub_cache)
-            self.decode_calls += 1
 
         def write_back(full, sub):
             if full.ndim >= 2 and full.shape[1] == self.ecfg.max_slots:
                 return full.at[:, slot : slot + 1].set(sub)
             return full
 
+        with self.metrics.measure_program(
+            "decode_ref", req.tier, traces=lambda: self.decode_traces, rows=1
+        ):
+            logits, new_sub = self._decode(params, tok, sub_cache)
+            self.decode_calls += 1
+            with self.metrics.span("serve.fetch"):
+                nxt = int(jnp.argmax(logits[0, -1]))
         updated = jax.tree.map(write_back, self.cache, new_sub)
         self.cache = updated._replace(length=self.cache.length)
         self._slot_len[slot] += 1
-        return int(jnp.argmax(logits[0, -1]))
+        return nxt

@@ -572,6 +572,7 @@ class SpeculativeEngine(PagedServingEngine):
         with self.metrics.measure_program(
             f"prefill[{tokens.shape[1]}]", tier,
             traces=lambda: self.prefill_traces,
+            rows=int(np.count_nonzero(slot_ids < slot_ids.size)),
         ):
             first, self.cache, self._dpools = self._prefill2(
                 self.params, self.draft_params, jnp.asarray(tokens),
@@ -580,13 +581,15 @@ class SpeculativeEngine(PagedServingEngine):
                 jnp.asarray(step, jnp.int32),
             )
             self.prefill_calls += 1
-            return np.asarray(first)
+            with self.metrics.span("serve.fetch"):
+                return np.asarray(first)
 
     def _chunk_call(self, tokens, counts, slot_ids, starts, step,
                     tier: int = 0):
         with self.metrics.measure_program(
             f"chunk[{tokens.shape[1]}]", tier,
             traces=lambda: self.chunk_traces,
+            rows=int(np.count_nonzero(counts)),
         ):
             first, self.cache, self._dpools = self._chunk2(
                 self.params, self.draft_params, jnp.asarray(tokens),
@@ -595,7 +598,8 @@ class SpeculativeEngine(PagedServingEngine):
                 jnp.asarray(step, jnp.int32),
             )
             self.chunk_calls += 1
-            return np.asarray(first)
+            with self.metrics.span("serve.fetch"):
+                return np.asarray(first)
 
     def _release(self, slot: int):
         super()._release(slot)
@@ -624,6 +628,7 @@ class SpeculativeEngine(PagedServingEngine):
         with self.metrics.measure_program(
             f"spec_decode[k={k}]", self._target_tier,
             traces=lambda: self.decode_traces,
+            rows=int(np.count_nonzero(active)),
         ):
             if self._parallel:
                 out, guesses, emitted, accepted, self.cache, self._dpools = \
@@ -632,7 +637,8 @@ class SpeculativeEngine(PagedServingEngine):
                         self._device_cache(), self._dpools,
                         jnp.asarray(active), step_arr,
                     )
-                guess_np = np.asarray(guesses)
+                with self.metrics.span("serve.fetch"):
+                    guess_np = np.asarray(guesses)
                 drafted = max(k - 1, 1)  # k-1 verifiable guesses per window
             else:
                 out, emitted, accepted, self.cache, self._dpools = self._spec(
@@ -643,49 +649,53 @@ class SpeculativeEngine(PagedServingEngine):
                 guess_np = None
                 drafted = k
             self.decode_calls += 1
-            out_np = np.asarray(out)                # ONE host sync per tick
-            emitted_np = np.asarray(emitted)
-            accepted_np = np.asarray(accepted)
+            with self.metrics.span("serve.fetch"):
+                out_np = np.asarray(out)            # ONE host sync per tick
+                emitted_np = np.asarray(emitted)
+                accepted_np = np.asarray(accepted)
 
-        ema_sum = 0.0
-        n_active = 0
-        tick_drafted = 0
-        tick_accepted = 0
-        tr = self.tracer
-        for slot, req in list(self._active.items()):
-            if slot in self._progress:   # drafted nothing this tick
-                continue
-            n_active += 1
-            m = int(emitted_np[slot])
-            rate = float(accepted_np[slot]) / drafted
-            prev = self._accept_ema[slot]
-            self._accept_ema[slot] = (
-                rate if np.isnan(prev)
-                else _SLOT_EMA * prev + (1.0 - _SLOT_EMA) * rate
-            )
-            ema_sum += self._accept_ema[slot]
-            tick_drafted += drafted
-            tick_accepted += int(accepted_np[slot])
-            if tr is not None:
-                tr.instant(slot, "spec_accept", uid=req.uid, drafted=drafted,
-                           accepted=int(accepted_np[slot]), emitted=m)
-            if guess_np is not None:
-                # d_chain[i] predicts position n+i+1; next window starts at
-                # n+m, so its guesses are d_chain[m:]; the tail (positions the
-                # draft has not seen yet) falls back to no-guess zeros
-                tail = guess_np[slot, m : m + k - 1]
-                self._guess[slot, : len(tail)] = tail
-                self._guess[slot, len(tail):] = 0
-            for j in range(m):
-                if req.done:
-                    break                           # max_new/eos mid-burst
-                self._record(slot, req, int(out_np[slot, j]), free, done)
-        self.spec_ticks += 1
-        if n_active:
-            self.metrics.on_spec_tick(tick_drafted, tick_accepted,
-                                      ema_sum / n_active, self._k)
-        if self.controller is not None and n_active:
-            # the window integrates the observed PER-SLOT acceptance (EMA per
-            # slot, mean over currently-active slots)
-            self._k = self.controller.update(ema_sum / n_active)
-            self._write_window = self._k
+        with self.metrics.span("serve.record"):
+            ema_sum = 0.0
+            n_active = 0
+            tick_drafted = 0
+            tick_accepted = 0
+            tr = self.tracer
+            for slot, req in list(self._active.items()):
+                if slot in self._progress:   # drafted nothing this tick
+                    continue
+                n_active += 1
+                m = int(emitted_np[slot])
+                rate = float(accepted_np[slot]) / drafted
+                prev = self._accept_ema[slot]
+                self._accept_ema[slot] = (
+                    rate if np.isnan(prev)
+                    else _SLOT_EMA * prev + (1.0 - _SLOT_EMA) * rate
+                )
+                ema_sum += self._accept_ema[slot]
+                tick_drafted += drafted
+                tick_accepted += int(accepted_np[slot])
+                if tr is not None:
+                    tr.instant(slot, "spec_accept", uid=req.uid,
+                               drafted=drafted,
+                               accepted=int(accepted_np[slot]), emitted=m)
+                if guess_np is not None:
+                    # d_chain[i] predicts position n+i+1; next window starts
+                    # at n+m, so its guesses are d_chain[m:]; the tail
+                    # (positions the draft has not seen yet) falls back to
+                    # no-guess zeros
+                    tail = guess_np[slot, m : m + k - 1]
+                    self._guess[slot, : len(tail)] = tail
+                    self._guess[slot, len(tail):] = 0
+                for j in range(m):
+                    if req.done:
+                        break                           # max_new/eos mid-burst
+                    self._record(slot, req, int(out_np[slot, j]), free, done)
+            self.spec_ticks += 1
+            if n_active:
+                self.metrics.on_spec_tick(tick_drafted, tick_accepted,
+                                          ema_sum / n_active, self._k)
+            if self.controller is not None and n_active:
+                # the window integrates the observed PER-SLOT acceptance
+                # (EMA per slot, mean over currently-active slots)
+                self._k = self.controller.update(ema_sum / n_active)
+                self._write_window = self._k

@@ -33,6 +33,19 @@ operator watching them) read from:
     *retrace* (``serve_jit_retraces_total``), the metric the SLO benchmarks
     and the CI telemetry smoke assert stays 0 in steady state.
 
+``serve.*`` spans
+    ``span``, ``measure_tick`` and ``measure_program`` also open
+    ``jax.profiler.TraceAnnotation`` spans (``serve.step``, ``serve.admit``,
+    ``serve.program``, ``serve.fetch``, ``serve.record``), so a profiler
+    trace shows what the engine's tick was doing on the device trace's own
+    clock. They are emitted with telemetry on or off: with no profiler
+    recording a span costs about a microsecond. ``serve.step`` carries
+    ``mono_ns``, ``time.monotonic_ns()`` at its entry, the anchor that maps
+    ``Request`` timestamps and ``RequestTracer`` events onto that clock
+    (``docs/observability.md``). With telemetry on, each step's phases also
+    add their host time to a per-step record, and the slowest step so far
+    is kept (``slowest_step``).
+
 Telemetry is zero-cost on the DEVICE path by construction: every hook runs
 on the host, reads only host state, and adds no device fetches — greedy
 token streams are bitwise-identical with telemetry on or off
@@ -56,6 +69,8 @@ import threading
 import time
 from contextlib import contextmanager
 from dataclasses import asdict
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Counter",
@@ -441,11 +456,9 @@ class EngineTelemetry:
     # unconditionally so the scheduler keeps one code path
     enabled = True
 
-    def __init__(self, engine: str, registry: MetricsRegistry | None = None,
-                 tracer=None):
+    def __init__(self, engine: str, registry: MetricsRegistry | None = None):
         self.engine = engine
         self.registry = registry if registry is not None else MetricsRegistry()
-        self.tracer = tracer          # serving.trace.RequestTracer or None
         r = self.registry
         e = ("engine",)
         self.requests = r.counter(
@@ -476,8 +489,10 @@ class EngineTelemetry:
             "serve_tick_seconds", "Wall time of one engine step()", e)
         self.program = r.histogram(
             "serve_program_seconds",
-            "Wall time per jitted program call, including the host fetch of "
-            "its outputs (count = device calls)", e + ("program", "tier"))
+            "Host wall time of one jitted program call site (the serve.program "
+            "span): argument flattening, pending block-table pushes and the "
+            "dispatch, plus the blocking fetch of its outputs where the site "
+            "reads them (count = device calls)", e + ("program", "tier"))
         self.jit_compiles = r.counter(
             "serve_jit_compiles_total",
             "Compilation-cache misses per (program, tier): first-use "
@@ -552,6 +567,11 @@ class EngineTelemetry:
         self._set_active = self.active_slots.setter(eng)
         self._set_shift = self.tier_shift.setter(eng)
         self._prog_obs: dict[tuple[str, str], object] = {}
+        # the slowest step so far; while a step runs, its phase totals (host
+        # ns per span name) and the programs it ran
+        self._slowest: dict | None = None
+        self._phases: dict[str, int] | None = None
+        self._programs: list | None = None
 
     # ---------------------------------------------------------- low level --
 
@@ -644,25 +664,60 @@ class EngineTelemetry:
             self.kv_pool_device_bytes.set(nbytes, self.engine, device)
 
     @contextmanager
-    def measure_tick(self):
-        t0 = time.monotonic()
+    def span(self, name: str, **stats):
+        """A ``serve.*`` span on the profiler's timeline; inside a step its
+        host nanoseconds also add to the step's total for ``name``."""
+        phases = self._phases
+        t0 = time.perf_counter_ns()
         try:
-            yield
+            with TraceAnnotation(name, **stats):
+                yield
         finally:
-            self._obs_tick(time.monotonic() - t0)
+            if phases is not None:
+                phases[name] = phases.get(name, 0) + time.perf_counter_ns() - t0
 
     @contextmanager
-    def measure_program(self, program: str, tier: int = 0, traces=None):
-        """Time one jitted call (call + host fetch of its outputs) and run
-        the retrace detector: ``traces`` is a zero-arg callable reading the
-        engine's python-side trace counter for this program; a positive
-        delta on a (program, tier) pair that already ran is a RETRACE."""
-        t0 = time.monotonic()
-        before = traces() if traces is not None else None
+    def measure_tick(self, step: int = 0, active: int = 0, queued: int = 0):
+        """Time one engine step inside its ``serve.step`` span, and keep it
+        as ``slowest_step`` if no step so far took longer."""
+        self._phases, self._programs = {}, []
+        compiles = self.jit_compiles.total()
+        t0 = time.perf_counter_ns()
         try:
-            yield
+            with self.span("serve.step", step=step, mono_ns=time.monotonic_ns(),
+                           active=active, queued=queued):
+                yield
         finally:
-            dt = time.monotonic() - t0
+            ns = time.perf_counter_ns() - t0
+            self._obs_tick(ns / 1e9)
+            if self._slowest is None or ns / 1e9 > self._slowest["seconds"]:
+                self._slowest = {
+                    "step": step, "seconds": ns / 1e9,
+                    "phases_ms": {k: v / 1e6 for k, v in self._phases.items()},
+                    "programs": self._programs,
+                    "compiles": int(self.jit_compiles.total() - compiles),
+                }
+            self._phases = self._programs = None
+
+    @contextmanager
+    def measure_program(self, program: str, tier=0, traces=None,
+                        rows: int = 0):
+        """Open ``serve.program`` around one jitted call site and time it
+        from entry (argument flattening, pending block-table pushes, the
+        dispatch) to exit, which includes the blocking fetch of its outputs
+        where the site reads them (its ``serve.fetch`` child). Also runs the
+        retrace detector: ``traces`` is a zero-arg callable reading the
+        engine's python-side trace counter for this program; a positive
+        delta on a (program, tier) pair that already ran is a RETRACE.
+        ``rows`` is the number of slots in the call."""
+        before = traces() if traces is not None else None
+        t0 = time.perf_counter_ns()
+        try:
+            with self.span("serve.program", program=program, tier=tier,
+                           rows=rows):
+                yield
+        finally:
+            dt = (time.perf_counter_ns() - t0) / 1e9
             e = self.engine
             ts = str(tier)
             obs = self._prog_obs.get((program, ts))
@@ -670,6 +725,8 @@ class EngineTelemetry:
                 obs = self._prog_obs[(program, ts)] = \
                     self.program.observer(e, program, ts)
             obs(dt)
+            if self._programs is not None:
+                self._programs.append([program, tier, dt * 1e3])
             if traces is not None:
                 delta = traces() - before
                 key = (program, ts)
@@ -678,8 +735,6 @@ class EngineTelemetry:
                     if key in self._seen_programs:
                         self.jit_retraces.inc(delta, e, program, ts)
                 self._seen_programs.add(key)
-            if self.tracer is not None:
-                self.tracer.program_span(program, tier, t0, dt)
 
     # ------------------------------------------------------------- reads ---
 
@@ -687,12 +742,21 @@ class EngineTelemetry:
         """Total steady-state recompiles across every (program, tier)."""
         return int(self.jit_retraces.total())
 
+    def slowest_step(self) -> dict | None:
+        """The longest ``step()`` so far: its number, seconds, host ms per
+        ``serve.*`` phase (inclusive of nested phases), the programs it ran
+        as ``[program, tier, host ms]``, and the compiles it triggered.
+        None before the first step, and always with telemetry off."""
+        return self._slowest
+
     def reset_histograms(self):
-        """Benchmark seam: drop histogram state after warmup so the measured
-        window's percentiles are clean (counters stay cumulative)."""
+        """Benchmark seam: drop histogram state and the slowest-step record
+        after warmup so the measured window's percentiles are clean
+        (counters stay cumulative)."""
         for m in self.registry:
             if isinstance(m, Histogram):
                 m.reset()
+        self._slowest = None
 
     def snapshot(self) -> dict:
         return self.registry.snapshot()
@@ -700,10 +764,12 @@ class EngineTelemetry:
 
 class NullTelemetry(EngineTelemetry):
     """Telemetry OFF: every hook is a no-op and the timing context managers
-    yield without reading the clock. The engines call hooks unconditionally —
-    keeping ONE scheduler code path — and this class makes 'off' actually
-    free. The registry still exists (declared but never written), so
-    ``snapshot()``/``prometheus_text()`` stay callable and simply read empty.
+    are the bare ``serve.*`` profiler spans, which read no clock of their own
+    (``serve.step`` still reads its ``mono_ns`` anchor). The engines call
+    hooks unconditionally — keeping ONE scheduler code path — and this class
+    makes 'off' actually free. The registry still exists (declared but never
+    written), so ``snapshot()``/``prometheus_text()`` stay callable and
+    simply read empty; ``slowest_step()`` reads None.
     """
 
     enabled = False
@@ -745,13 +811,17 @@ class NullTelemetry(EngineTelemetry):
     def set_pool_device_bytes(self, bytes_by_device):
         pass
 
-    @contextmanager
-    def measure_tick(self):
-        yield
+    def span(self, name, **stats):
+        return TraceAnnotation(name, **stats)
 
-    @contextmanager
-    def measure_program(self, program, tier=0, traces=None):
-        yield
+    def measure_tick(self, step=0, active=0, queued=0):
+        return TraceAnnotation("serve.step", step=step,
+                               mono_ns=time.monotonic_ns(), active=active,
+                               queued=queued)
+
+    def measure_program(self, program, tier=0, traces=None, rows=0):
+        return TraceAnnotation("serve.program", program=program, tier=tier,
+                               rows=rows)
 
 
 # ----------------------------------------------------- request-level helpers ---

@@ -4,14 +4,17 @@ Where :mod:`repro.serving.telemetry` answers "how is the fleet doing"
 (aggregates), this module answers "why was THIS request slow" (timelines).
 A :class:`RequestTracer` collects spans and instants from the engine hooks:
 
-* one track (``tid``) per engine SLOT under pid 1 — a request's life renders
-  as nested spans on the slot it occupied: ``request`` envelope >
-  ``admission_wait`` > ``prefill`` / ``prefill_chunk`` spans > ``decode``
-  span, with instant markers for first token, tier switches, CoW copies,
-  prefix-cache hits, speculative accept runs, and eviction/resume;
-* one track per JITTED PROGRAM under pid 2 — ``decode[t0]``, ``prefill[32]``,
-  ``chunk``, ``verify`` wall-clock slices, so a TTFT bubble on a slot track
-  lines up visually with the program call that caused it.
+one track (``tid``) per engine SLOT under pid 1 — a request's life renders
+as nested spans on the slot it occupied: ``request`` envelope >
+``prefill`` / ``prefill_chunk`` spans > ``decode`` span, with instant
+markers for first token, tier switches, CoW copies, prefix-cache hits,
+speculative accept runs, and eviction/resume. A request's life spans many
+engine ticks, which a scoped profiler span cannot hold; what each tick ran
+(its jitted programs, their fetches, the host bookkeeping) is on the
+profiler's own trace instead, as the ``serve.*`` spans of
+:mod:`repro.serving.telemetry`, and the ``mono_ns`` stat of each
+``serve.step`` maps this tracer's ``time.monotonic`` timestamps onto it
+(``docs/observability.md``).
 
 Export formats:
 
@@ -38,7 +41,6 @@ from dataclasses import dataclass, field
 __all__ = ["RequestTracer", "validate_chrome_trace"]
 
 _SLOT_PID = 1      # one tid per engine slot
-_PROGRAM_PID = 2   # one tid per jitted program
 
 
 @dataclass
@@ -50,7 +52,7 @@ class _Span:
 
 
 class RequestTracer:
-    """Collects slot-track spans + program-track slices for one engine run.
+    """Collects slot-track spans and instants for one engine run.
 
     All timestamps are ``time.monotonic()`` seconds; export converts to µs
     relative to the tracer's construction (so traces start near ts=0).
@@ -64,18 +66,11 @@ class RequestTracer:
         self.epoch = time.monotonic()
         self.events: list[dict] = []       # closed events, export order
         self._open: dict[int, list[_Span]] = {}   # slot -> span stack
-        self._programs: dict[str, int] = {}       # program name -> tid
 
     # ------------------------------------------------------------ helpers --
 
     def _us(self, t: float) -> int:
         return int(round((t - self.epoch) * 1e6))
-
-    def _program_tid(self, name: str) -> int:
-        tid = self._programs.get(name)
-        if tid is None:
-            tid = self._programs[name] = len(self._programs) + 1
-        return tid
 
     # -------------------------------------------------------- slot spans ---
 
@@ -124,15 +119,6 @@ class RequestTracer:
             "args": dict(args),
         })
 
-    def program_span(self, program: str, tier: int, t0: float, dur_s: float):
-        """One jitted-program call on the program pid (called by
-        ``EngineTelemetry.measure_program``)."""
-        self.events.append({
-            "kind": "program", "name": program, "tier": tier,
-            "ts_us": self._us(t0), "dur_us": max(int(round(dur_s * 1e6)), 0),
-            "args": {"tier": tier},
-        })
-
     # --------------------------------------------------- request lifecycle --
 
     def request_begin(self, slot: int, uid: int, t: float | None = None,
@@ -149,16 +135,11 @@ class RequestTracer:
         out = [
             {"ph": "M", "pid": _SLOT_PID, "tid": 0, "name": "process_name",
              "args": {"name": f"{self.engine} slots"}},
-            {"ph": "M", "pid": _PROGRAM_PID, "tid": 0, "name": "process_name",
-             "args": {"name": f"{self.engine} jitted programs"}},
         ]
         slots = sorted({e["slot"] for e in self.events if "slot" in e})
         for s in slots:
             out.append({"ph": "M", "pid": _SLOT_PID, "tid": s,
                         "name": "thread_name", "args": {"name": f"slot {s}"}})
-        for prog, tid in sorted(self._programs.items(), key=lambda kv: kv[1]):
-            out.append({"ph": "M", "pid": _PROGRAM_PID, "tid": tid,
-                        "name": "thread_name", "args": {"name": prog}})
         for e in self.events:
             if e["kind"] == "span":
                 out.append({"ph": "X", "pid": _SLOT_PID, "tid": e["slot"],
@@ -169,12 +150,6 @@ class RequestTracer:
                 out.append({"ph": "i", "pid": _SLOT_PID, "tid": e["slot"],
                             "name": e["name"], "ts": e["ts_us"], "s": "t",
                             "cat": "request", "args": e["args"]})
-            elif e["kind"] == "program":
-                out.append({"ph": "X", "pid": _PROGRAM_PID,
-                            "tid": self._program_tid(e["name"]),
-                            "name": e["name"], "ts": e["ts_us"],
-                            "dur": e["dur_us"], "cat": "program",
-                            "args": e["args"]})
         return out
 
     def save_chrome(self, path):

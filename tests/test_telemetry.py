@@ -3,12 +3,15 @@
 The load-bearing claim is ZERO COST ON THE DEVICE PATH: greedy token
 streams must be bitwise identical with telemetry (and tracing) on vs off,
 across every engine configuration — paged, chunked prefill, speculative,
-elastic pressure tiers, prefix cache. The rest covers the registry
+elastic pressure tiers, prefix cache — and with a profiler recording the
+engine's ``serve.*`` spans or not. The rest covers the registry
 primitives (counters / gauges / histograms and their pre-bound fast
-paths), the Chrome-trace export schema, the Prometheus text exposition +
-HTTP endpoint, exactly-once token accounting through eviction/resume
-(satellite: the accounting audit), centralized provenance key identity
-across engines, and the retrace detector.
+paths), the ``serve.*`` span tree and its ``mono_ns`` anchor in a
+recorded profile, the slowest-step record, the Chrome-trace export schema,
+the Prometheus text exposition + HTTP endpoint, exactly-once token
+accounting through eviction/resume (satellite: the accounting audit),
+centralized provenance key identity across engines, and the retrace
+detector.
 
 Each (config, telemetry on/off) engine is built and driven EXACTLY ONCE
 through the module-scoped ``driven`` fixture and every test reads from
@@ -16,7 +19,9 @@ that one run — engines jit their own programs, and pointless re-compiles
 are what pushes a long single-core suite run over the edge.
 """
 import json
+import sys
 import urllib.request
+from pathlib import Path
 
 import jax
 import pytest
@@ -42,6 +47,9 @@ from repro.serving.telemetry import (
     validate_prometheus_text,
 )
 from repro.serving.trace import validate_chrome_trace
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+from bench.lib import spans as bench_spans  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -73,8 +81,20 @@ CONFIGS = {
     # the exactly-once accounting audit (same jitted shapes as "paged")
     "paged_tight": (PagedServingEngine, 1,
                     dict(_BASE, num_blocks=6)),
+    # requests alternate between two tiers: one decode program per tier a
+    # tick, as a multi-tier deployment runs
+    "two_tiers": (PagedServingEngine, 2, dict(_BASE)),
 }
 _MAX_NEW = {"paged_tight": 16}
+
+
+def _submit_all(eng, name: str, vocab: int) -> int:
+    """Submit the shared prompts; "two_tiers" alternates their tiers."""
+    prompts = _prompts(vocab)
+    for i, p in enumerate(prompts):
+        eng.submit(list(p), max_new_tokens=_MAX_NEW.get(name, 6),
+                   tier=i % 2 if name == "two_tiers" else None)
+    return len(prompts)
 
 
 def _build(tiny, name: str, telemetry: bool):
@@ -99,12 +119,9 @@ def driven(tiny):
             eng = _build(tiny, name, telemetry)
             if telemetry:
                 eng.start_trace()
-            prompts = _prompts(cfg.vocab_size)
-            for p in prompts:
-                eng.submit(list(p),
-                           max_new_tokens=_MAX_NEW.get(name, 6))
+            n = _submit_all(eng, name, cfg.vocab_size)
             done = eng.run()
-            assert len(done) == len(prompts)
+            assert len(done) == n
             streams = [r.out_tokens
                        for r in sorted(done, key=lambda r: r.uid)]
             cache[key] = (eng, streams, done)
@@ -304,7 +321,10 @@ class TestChromeTrace:
         doc = json.loads(path.read_text())
         rep = validate_chrome_trace(doc)
         assert rep["events"] > 0
-        assert rep["tracks"] >= 2          # slot tracks + program tracks
+        assert rep["tracks"] >= 2          # one track per slot (2 slots)
+        # jitted programs are on the profiler's trace (serve.program), not
+        # on a track of their own here
+        assert {ev["pid"] for ev in doc["traceEvents"]} == {1}
         for ev in doc["traceEvents"]:
             assert ev["ph"] in ("X", "i", "M")
             assert "pid" in ev and "tid" in ev and "name" in ev
@@ -389,7 +409,171 @@ class TestProvenance:
     def test_stats_snapshot_schema(self, driven):
         eng, _, _ = driven("paged", True)
         snap = eng.stats_snapshot()
-        for key in ("engine", "steps", "jit_retraces", "metrics"):
+        for key in ("engine", "steps", "jit_retraces", "slowest_step",
+                    "metrics"):
             assert key in snap, key
         assert snap["engine"] == "PagedServingEngine"
         assert "serve_tokens_total" in snap["metrics"]
+
+    def test_stats_snapshot_keys_identical_across_engines(self, tiny):
+        cfg, params = tiny
+        bank = ModelBank.single(cfg, params)
+        engines = [
+            ServingEngine(bank, EngineConfig(max_slots=1, max_len=16)),
+            PagedServingEngine(bank, EngineConfig(max_slots=1, max_len=16,
+                                                  block_size=8)),
+            ReferenceEngine(bank, EngineConfig(max_slots=1, max_len=16)),
+            SpeculativeEngine(bank, EngineConfig(max_slots=1, max_len=16,
+                                                 block_size=8, spec_k=2)),
+        ]
+        keysets = {frozenset(e.stats_snapshot()) for e in engines}
+        assert len(keysets) == 1, keysets
+        assert all(e.stats_snapshot()["slowest_step"] is None
+                   for e in engines)          # no step has run yet
+
+
+# ----------------------------------------------------------- serve.* spans ---
+
+
+def _serve_spans(profile_dir) -> list[tuple[str, float, float, dict]]:
+    """(name, start_ns, end_ns, stats) of the ``serve.*`` host events of the
+    newest profile under ``profile_dir``, by start, as the benchmark's
+    readers load them."""
+    return bench_spans.load(profile_dir)[1]
+
+
+def _inside(child, parent) -> bool:
+    return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+@pytest.fixture(scope="module")
+def profiled(tiny, tmp_path_factory):
+    """Memoized (config, telemetry) -> (engine, streams, done, spans): a
+    fresh engine driven over the shared prompts while ``jax.profiler``
+    records, and the ``serve.*`` spans it left in the profile."""
+    cfg, _ = tiny
+    cache = {}
+
+    def get(name: str, telemetry: bool):
+        key = (name, telemetry)
+        if key not in cache:
+            eng = _build(tiny, name, telemetry)
+            d = str(tmp_path_factory.mktemp(f"profile_{name}_{telemetry}"))
+            _submit_all(eng, name, cfg.vocab_size)
+            jax.profiler.start_trace(d)
+            try:
+                done = eng.run()
+            finally:
+                jax.profiler.stop_trace()
+            streams = [r.out_tokens for r in sorted(done, key=lambda r: r.uid)]
+            cache[key] = (eng, streams, done, _serve_spans(d))
+        return cache[key]
+
+    return get
+
+
+class TestServeSpans:
+    """The engine's tick on the profiler's timeline: ``serve.step`` >
+    ``serve.admit`` > ``serve.program`` > ``serve.fetch``, and
+    ``serve.record`` after each program's outputs arrive."""
+
+    @pytest.mark.parametrize("telemetry", [True, False])
+    def test_spans_nest_with_their_stats(self, profiled, telemetry):
+        eng, _, _, spans = profiled("two_tiers", telemetry)
+        by = {n: [s for s in spans if s[0] == n] for n in
+              ("serve.step", "serve.admit", "serve.program", "serve.fetch",
+               "serve.record")}
+        assert all(by.values()), {n: len(v) for n, v in by.items()}
+        assert {s[0] for s in spans} == set(by)
+        steps = by["serve.step"]
+        assert len(steps) == eng.stats_snapshot()["steps"]
+        assert [s[3]["step"] for s in steps] == list(range(1, len(steps) + 1))
+        for s in steps:
+            assert set(s[3]) == {"step", "mono_ns", "active", "queued"}
+        for name in ("serve.admit", "serve.program", "serve.record"):
+            for child in by[name]:
+                assert any(_inside(child, s) for s in steps), child
+        for f in by["serve.fetch"]:
+            assert any(_inside(f, p) for p in by["serve.program"]), f
+        programs = by["serve.program"]
+        for p in programs:
+            assert set(p[3]) == {"program", "tier", "rows"}
+            assert p[3]["rows"] >= 1
+        names = {p[3]["program"] for p in programs}
+        assert "decode" in names and any(n.startswith("prefill[")
+                                         for n in names)
+        # every prefill runs inside admission; both tiers decode
+        for p in programs:
+            if p[3]["program"].startswith("prefill["):
+                assert any(_inside(p, a) for a in by["serve.admit"]), p
+        assert {p[3]["tier"] for p in programs
+                if p[3]["program"] == "decode"} == {0, 1}
+
+    def test_speculative_and_chunked_call_sites_mark_their_fetch(
+            self, profiled):
+        for name, prog in (("speculative", "spec_decode[k="),
+                           ("chunked_prefill", "chunk[")):
+            _, _, _, spans = profiled(name, False)
+            programs = [s for s in spans if s[0] == "serve.program"
+                        and s[3]["program"].startswith(prog)]
+            fetches = [s for s in spans if s[0] == "serve.fetch"]
+            assert programs, name
+            for p in programs:
+                assert any(_inside(f, p) for f in fetches), (name, p)
+
+    def test_mono_ns_anchor_maps_admission_into_its_admit_span(
+            self, profiled):
+        _, _, done, spans = profiled("two_tiers", True)
+        steps = [s for s in spans if s[0] == "serve.step"]
+        admits = [s for s in spans if s[0] == "serve.admit"]
+        for r in done:
+            assert any(
+                _inside(a, st)
+                and a[1] <= r.admitted_at * 1e9 + st[1] - st[3]["mono_ns"]
+                <= a[2]
+                for st in steps for a in admits), r.uid
+
+    @pytest.mark.parametrize("telemetry", [True, False])
+    def test_streams_identical_with_profiler_recording(self, driven,
+                                                       profiled, telemetry):
+        _, s_plain, _ = driven("two_tiers", telemetry)
+        _, s_prof, _, _ = profiled("two_tiers", telemetry)
+        assert s_prof == s_plain
+
+
+class TestSlowestStep:
+    def test_filled_with_telemetry_on(self, driven):
+        eng, _, _ = driven("two_tiers", True)
+        rec = eng.stats_snapshot()["slowest_step"]
+        assert set(rec) == {"step", "seconds", "phases_ms", "programs",
+                            "compiles"}
+        assert 1 <= rec["step"] <= eng.stats_snapshot()["steps"]
+        assert rec["seconds"] >= max(
+            s for _, _, s in rec["programs"]) / 1e3 > 0
+        assert rec["phases_ms"]["serve.step"] == pytest.approx(
+            rec["seconds"] * 1e3, rel=0.1)
+        assert set(rec["phases_ms"]) <= {"serve.step", "serve.admit",
+                                         "serve.program", "serve.fetch",
+                                         "serve.record"}
+        # the first step compiles; so the slowest step is one that did
+        assert rec["compiles"] >= 1
+        json.dumps(rec)
+
+    def test_none_with_telemetry_off(self, driven):
+        eng, _, _ = driven("two_tiers", False)
+        assert eng.stats_snapshot()["slowest_step"] is None
+
+    def test_reset_after_warmup_keeps_only_later_steps(self):
+        tel = EngineTelemetry("T")
+        with tel.measure_tick(1):
+            with tel.measure_program("p", 0):
+                pass
+        assert tel.slowest_step()["step"] == 1
+        tel.reset_histograms()
+        assert tel.slowest_step() is None
+        with tel.measure_tick(2):
+            with tel.span("serve.record"):
+                pass
+        rec = tel.slowest_step()
+        assert rec["step"] == 2 and rec["programs"] == []
+        assert set(rec["phases_ms"]) == {"serve.step", "serve.record"}
