@@ -429,7 +429,7 @@ def main():
                                      tiers=tiers)
     print(json.dumps({
         "fmt": args.fmt,
-        "bank": bank.report(),
+        "bank": bank.report(widths=(ecfg.max_slots,)),
         "tier_policy": args.tier_policy,
         **stats,
         "tiers": tier_rows,

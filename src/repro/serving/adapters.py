@@ -873,7 +873,7 @@ class AdapterBank(ModelBank):
             "sites": len(self._sites),
         }
 
-    def report(self) -> dict:
-        out = super().report()
+    def report(self, widths=()) -> dict:
+        out = super().report(widths)
         out["adapters"] = self.adapter_report()
         return out

@@ -32,6 +32,7 @@ experts are einsum-dispatched, so those blocks are served dense-materialized;
 """
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Any
 
 import jax
@@ -40,10 +41,11 @@ import numpy as np
 
 from ..core.admm import SLRState, materialize_block, surrogate_params
 from ..core.selection import BlockInfo, path_str
+from ..kernels.slr_matmul import fused_grid, row_tile
 from ..models import model as model_lib
 from .slr_params import SLRLinear, build_slr_linears, coo_to_bsr, coo_to_bsr_stack
 
-__all__ = ["DeployedModel", "is_linear_site"]
+__all__ = ["DeployedModel", "SlrKernelPlan", "is_linear_site"]
 
 # Param-dict keys that are consumed via apply_weight (plain x @ w sites).
 _LINEAR_KEYS = frozenset({"q", "k", "v", "o", "gate", "up", "down", "w"})
@@ -84,6 +86,41 @@ def _fuse_linear(lin: SLRLinear, bsr_block: int) -> SLRLinear:
         p=lin.p, vt=lin.vt, s_coo=None, s_bsr=s_bsr, shape=lin.shape,
         use_kernel=True, fuse=True,
     )
+
+
+@dataclass(frozen=True)
+class SlrKernelPlan:
+    """What one forward of a ``fused`` deployment asks of the fused SLR
+    kernel, read from its tables once at deploy time.
+
+    ``steps_per_row_tile`` sums the grid steps of every fused call of the
+    forward (each stacked site once per layer) for one row tile;
+    ``live_slots`` over ``fetched_slots`` is the share of the S tiles the
+    kernel streams that ``counts`` marks live (a column streams whole slot
+    groups up to its last live one, and at least one).
+    """
+
+    calls: int
+    steps_per_row_tile: int
+    live_slots: int
+    fetched_slots: int
+    x_dtype: Any
+
+    def grid_steps(self, width: int) -> int:
+        """Grid steps of one program over ``width`` token rows."""
+        return -(-width // row_tile(width, self.x_dtype)) * self.steps_per_row_tile
+
+    @property
+    def live_tile_share(self) -> float:
+        return self.live_slots / self.fetched_slots if self.fetched_slots else 1.0
+
+    def report(self, widths=()) -> dict:
+        return {
+            "calls": self.calls,
+            "steps_per_row_tile": self.steps_per_row_tile,
+            "grid_steps": {int(w): self.grid_steps(w) for w in widths},
+            "live_tile_share": self.live_tile_share,
+        }
 
 
 class DeployedModel:
@@ -201,3 +238,31 @@ class DeployedModel:
             "total_bytes": structured + dense,
             "format": self.fmt,
         }
+
+    def slr_kernel_plan(self) -> SlrKernelPlan | None:
+        """The fused SLR kernel's grid figures for one forward (None unless
+        some site runs the kernel). Mirrors ``kernels.ops``' dispatch: an
+        empty S runs the low-rank kernel and is left out; a stacked site
+        with S but no rank rides the kernel at rank 1."""
+        if self.fmt != "fused":
+            return None
+        x_dtype = self.cfg.param_dtype
+        calls = steps = live = fetched = 0
+        is_slr = lambda x: isinstance(x, SLRLinear)  # noqa: E731
+        for lin in jax.tree_util.tree_leaves(self.params, is_leaf=is_slr):
+            if not isinstance(lin, SLRLinear) or not lin.fuse:
+                continue
+            r = 0 if lin.p is None else lin.p.shape[-1]
+            tables = lin.s_stack if lin.s_stack is not None else lin.s_bsr
+            if tables is None or tables.empty or (lin.s_stack is None and not r):
+                continue
+            p_dtype = x_dtype if lin.p is None else lin.p.dtype
+            g = fused_grid(1, tables, max(r, 1), x_dtype=x_dtype, p_dtype=p_dtype)
+            counts = np.asarray(tables.counts).reshape(-1, g.jb)  # (calls, JB)
+            calls += counts.shape[0]
+            steps += counts.shape[0] * g.steps_per_row_tile
+            live += int(counts.sum())
+            fetched += int((np.maximum(-(-counts // g.group), 1) * g.group).sum())
+        if not calls:
+            return None
+        return SlrKernelPlan(calls, steps, live, fetched, x_dtype)

@@ -51,7 +51,7 @@ import jax
 import numpy as np
 
 from ..core.hpa import hpa_keep_ratio
-from .deployed import DeployedModel
+from .deployed import DeployedModel, SlrKernelPlan
 from .slr_params import SLRLinear
 
 __all__ = [
@@ -76,6 +76,7 @@ class Tier:
     keep: float | None          # HPA keep-ratio, None for wrapped weights
     model: DeployedModel
     param_bytes: int            # total served bytes of this tier's view
+    slr_kernel: SlrKernelPlan | None = None  # fused SLR grid, deploy time
 
     @property
     def params(self) -> Any:
@@ -118,7 +119,8 @@ class ModelBank:
             )
             self._tiers.append(
                 Tier(index=i, name=name, keep=keeps[i], model=model,
-                     param_bytes=_tier_bytes(model))
+                     param_bytes=_tier_bytes(model),
+                     slr_kernel=model.slr_kernel_plan())
             )
 
     # ------------------------------------------------------------- build ---
@@ -226,8 +228,11 @@ class ModelBank:
             for i in common
         )
 
-    def report(self) -> dict:
-        """Per-tier served bytes + the shared base, for provenance payloads."""
+    def report(self, widths=()) -> dict:
+        """Per-tier served bytes + the shared base, for provenance payloads.
+        A tier that runs the fused SLR kernel also reports its grid steps
+        per program of each of ``widths`` token rows and the live share of
+        the S tiles it streams (``SlrKernelPlan``)."""
         return {
             "num_tiers": len(self._tiers),
             "tiers": [
@@ -237,6 +242,8 @@ class ModelBank:
                     "keep": t.keep,
                     "fmt": t.model.fmt,
                     "param_bytes": t.param_bytes,
+                    "slr_kernel": None if t.slr_kernel is None
+                    else t.slr_kernel.report(widths),
                 }
                 for t in self._tiers
             ],

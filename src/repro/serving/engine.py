@@ -670,6 +670,10 @@ class ServingEngine:
                         else NullTelemetry)(type(self).__name__)
         if self._adapters is not None:
             self.metrics.set_resident_adapters(len(self._adapters.resident))
+        for tier in bank:
+            if tier.slr_kernel is not None:
+                self.metrics.set_slr_kernel(tier.index, ecfg.max_slots,
+                                            tier.slr_kernel)
         self.tracer: RequestTracer | None = None
         if ecfg.trace:
             self.start_trace()

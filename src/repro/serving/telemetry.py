@@ -551,6 +551,15 @@ class EngineTelemetry:
             "KV payload bytes resident per device (pools + int8 scales); "
             "under a ServingMesh each device holds its head-axis shard, so "
             "this shrinks ~1/N with the model-axis size", e + ("device",))
+        self.slr_grid_steps = r.gauge(
+            "serve_slr_grid_steps",
+            "Grid steps the fused SLR kernel runs in one decode program of "
+            "the tier, at the program's width in token rows (read from the "
+            "tier's tables once, at deploy time)", e + ("tier", "width"))
+        self.slr_live_tile_share = r.gauge(
+            "serve_slr_live_tile_share",
+            "Share of the S tiles the tier's fused SLR kernel streams that "
+            "hold live blocks (deploy time)", e + ("tier",))
         # (program, tier) pairs whose first call already happened: a compile
         # observed later is a RETRACE (the generalized retraces_on_switch)
         self._seen_programs: set[tuple[str, str]] = set()
@@ -655,6 +664,14 @@ class EngineTelemetry:
             self._set_active(active)
         if shift is not None:
             self._set_shift(shift)
+
+    def set_slr_kernel(self, tier: int, width: int, plan):
+        """A tier's fused SLR kernel figures (``deployed.SlrKernelPlan``);
+        static for the engine's lifetime, so set once at construction."""
+        self.slr_grid_steps.set(plan.grid_steps(width), self.engine,
+                                str(tier), str(width))
+        self.slr_live_tile_share.set(plan.live_tile_share, self.engine,
+                                     str(tier))
 
     def set_pool_device_bytes(self, bytes_by_device: dict):
         """Per-device KV pool residency (label: device). Called once at cache
@@ -806,6 +823,9 @@ class NullTelemetry(EngineTelemetry):
 
     def set_pool(self, free=None, cached=None, queue=None, active=None,
                  shift=None):
+        pass
+
+    def set_slr_kernel(self, tier, width, plan):
         pass
 
     def set_pool_device_bytes(self, bytes_by_device):

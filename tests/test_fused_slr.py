@@ -25,7 +25,13 @@ from repro.core.admm import SalaadConfig, admm_update, init_slr_state
 from repro.core.selection import SelectionConfig
 from repro.kernels import ops, ref
 from repro.kernels.bsr_matmul import bsr_from_dense
-from repro.kernels.slr_matmul import row_tile, stack_bsr
+from repro.kernels.slr_matmul import (
+    TILE_BYTES,
+    BsrStack,
+    fused_grid,
+    row_tile,
+    stack_bsr,
+)
 from repro.models import model as model_lib
 from repro.serving.deployed import DeployedModel
 from repro.serving.elastic import ModelBank
@@ -50,6 +56,42 @@ def make_sparse(key, n, m, occupancy, bs, dtype=jnp.float32):
         jnp.repeat(mask, bs, 0), bs, 1
     ).astype(dtype)
     return np.asarray(full[:n, :m], np.float32)
+
+
+def sparse_with_counts(key, n, m, bs, counts, dtype=jnp.float32):
+    """Dense S whose column window ``j`` holds ``counts[j]`` live blocks at
+    scattered row blocks."""
+    ib = -(-n // bs)
+    rng = np.random.default_rng(key)
+    s = np.zeros((ib * bs, len(counts) * bs), np.float32)
+    for j, c in enumerate(counts):
+        for i in rng.choice(ib, size=c, replace=False):
+            s[i * bs:(i + 1) * bs, j * bs:(j + 1) * bs] = rng.standard_normal(
+                (bs, bs))
+    return np.asarray(jnp.asarray(s[:n, :m]).astype(dtype), np.float32)
+
+
+# Tables that need more than one P chunk and more than one slot group: 34 row
+# blocks of 128 (MAXB 34, not a power of two: groups of 17) at rank 349 (not
+# a multiple of 128). Per layer, the live blocks of each column window: a
+# full column, one whose trailing group is dead, an empty one, one that ends
+# inside its second group, one with a single live block.
+GROUPED = dict(k=34 * 128, m=5 * 128, r=349, bs=128,
+               counts=((34, 17, 0, 20, 1), (1, 34, 17, 0, 33)))
+
+
+def grouped_tables(lead, x_dtype, seed=40):
+    k, m, r, bs = GROUPED["k"], GROUPED["m"], GROUPED["r"], GROUPED["bs"]
+    layers = GROUPED["counts"]
+    p = rnd(seed + 1, (lead, k, r), x_dtype) / np.sqrt(k)
+    vt = rnd(seed + 2, (lead, r, m), x_dtype)
+    mats = [bsr_from_dense(sparse_with_counts(seed + l, k, m, bs,
+                                              layers[l % len(layers)]), bs)
+            for l in range(lead)]
+    stack = stack_bsr(mats)
+    g = fused_grid(8, stack, r, x_dtype=x_dtype, p_dtype=p.dtype)
+    assert g.chunks > 1 and g.groups > 1 and g.maxb == 34, (g.chunks, g.groups)
+    return p, vt, stack
 
 
 def assert_close(got, want, dtype):
@@ -120,13 +162,24 @@ class TestStackedKernel:
         ]
         return p, vt, stack_bsr(mats)
 
-    def test_layers_match_per_matrix_oracle(self):
-        p, vt, stack = self._stacked()
-        x = rnd(0, (8, 64))
+    @pytest.mark.parametrize("case", ["mixed", "grouped_f32", "grouped_bf16"])
+    def test_layers_match_per_matrix_oracle(self, case):
+        """``mixed``: per-layer occupancies, one layer empty. ``grouped_*``:
+        several P chunks and slot groups, counts below MAXB, dead trailing
+        groups, rank 349 (``GROUPED``); bf16 activations and factors over
+        float32 tables, as deployed."""
+        if case == "mixed":
+            p, vt, stack = self._stacked()
+            x, dtype = rnd(0, (8, 64)), jnp.float32
+        else:
+            dtype = jnp.float32 if case == "grouped_f32" else jnp.bfloat16
+            p, vt, stack = grouped_tables(2, dtype)
+            x = rnd(0, (8, GROUPED["k"]), dtype)
         for l in range(stack.num_layers):
             got = ops.slr_matmul_stacked(x, p, vt, stack, jnp.int32(l), **I)
             want = ref.slr_matmul_stacked_ref(x, p, vt, stack, jnp.int32(l))
-            assert_close(got, want, jnp.float32)
+            assert got.dtype == x.dtype
+            assert_close(got, want, dtype)
 
     def test_scannable_over_layers(self):
         """The whole point of the layer axis: the stack rides lax.scan."""
@@ -176,12 +229,19 @@ class TestMultiAdapterKernel:
         return p, vt, stack_bsr(mats)
 
     @pytest.mark.parametrize("t", [1, 8])            # decode / prefill widths
-    def test_slots_match_per_slot_oracle(self, t):
-        p, vt, stack = self._pool()
-        x = rnd(0, (6, t, 64))
+    @pytest.mark.parametrize("pool", ["mixed", "grouped"])
+    def test_slots_match_per_slot_oracle(self, pool, t):
+        """``grouped``: adapters whose tables need several P chunks and slot
+        groups (``GROUPED``)."""
+        if pool == "mixed":
+            p, vt, stack = self._pool()
+        else:
+            p, vt, stack = grouped_tables(4, jnp.float32)
+        k, m = stack.shape
+        x = rnd(0, (6, t, k))
         ids = jnp.asarray([2, 0, 3, 0, 1, 2], jnp.int32)   # repeats included
         got = ops.slr_matmul_multi(x, p, vt, stack, ids, **I)
-        assert got.shape == (6, t, 128) and got.dtype == x.dtype
+        assert got.shape == (6, t, m) and got.dtype == x.dtype
         assert_close(got, ref.slr_matmul_multi_ref(x, p, vt, stack, ids),
                      jnp.float32)
 
@@ -231,6 +291,45 @@ class TestMultiAdapterKernel:
         got = ops.slr_matmul_multi(x, p, vt, stack, ids)
         assert_close(got, ref.slr_matmul_multi_ref(x, p, vt, stack, ids),
                      jnp.float32)
+
+
+class TestGridArithmetic:
+    """The fused kernel's grid at OLMo-1B's sites, from shapes alone: every
+    block of S live (MAXB = K blocks), bf16 activations and factors, float32
+    block tables; no kernel runs."""
+
+    @pytest.mark.parametrize("width", [8, 128])
+    @pytest.mark.parametrize("r", [512, 349])
+    @pytest.mark.parametrize("site", [(2048, 2048), (2048, 8192), (8192, 2048)])
+    def test_olmo_sites(self, site, r, width):
+        n, m = site
+        bs, layers = 128, 16
+        k_tiles, jb = n // bs, m // bs
+        sds = jax.ShapeDtypeStruct
+        stack = BsrStack(
+            sds((layers, jb), jnp.int32), sds((layers, jb, k_tiles), jnp.int32),
+            sds((layers, jb, k_tiles, bs, bs), jnp.float32), (n, m), bs)
+        g = fused_grid(width, stack, r, x_dtype=jnp.bfloat16,
+                       p_dtype=jnp.bfloat16)
+        one_block_per_step = k_tiles + jb * k_tiles
+        assert g.steps_per_row_tile * 10 <= one_block_per_step
+        assert g.grid == (-(-width // g.bt), g.chunks + jb * g.groups)
+        assert k_tiles % g.chunk == 0 and k_tiles % g.group == 0
+        assert all(b <= TILE_BYTES for b in g.tile_bytes.values())
+        assert g.vmem_bytes <= g.vmem_limit_bytes
+
+    def test_olmo_decode_program(self):
+        """One layer of OLMo-1B (q, k, v, o; gate, up; down) at r 512 runs
+        234 grid steps a row tile, where one block a step ran 4,256."""
+        sds = jax.ShapeDtypeStruct
+        steps = 0
+        for n, m in [(2048, 2048)] * 4 + [(2048, 8192)] * 2 + [(8192, 2048)]:
+            kt, jb = n // 128, m // 128
+            stack = BsrStack(None, sds((1, jb, kt), jnp.int32),
+                             sds((1, jb, kt, 128, 128), jnp.float32), (n, m), 128)
+            steps += fused_grid(8, stack, 512, x_dtype=jnp.bfloat16,
+                                p_dtype=jnp.bfloat16).steps
+        assert steps == 4 * 17 + 2 * 65 + 36 == 234
 
 
 # ---------------------------------------------------------------- fast paths ---
@@ -334,6 +433,31 @@ class TestFusedFormat:
         np.testing.assert_allclose(np.asarray(lf), np.asarray(lu),
                                    atol=2e-3, rtol=2e-3)
         assert bool((lf.argmax(-1) == lu.argmax(-1)).all())
+
+    def test_bank_reports_kernel_grid(self, banks):
+        """Each fused tier records its kernel grid at build: the report and
+        the engine's gauges carry the steps of a decode program and the live
+        share of the S tiles it streams."""
+        bank = banks["fused"]
+        for tier in bank:
+            plan = tier.slr_kernel
+            assert plan is not None and plan.calls > 0
+            assert 0.0 < plan.live_tile_share <= 1.0
+            assert plan.live_slots <= plan.fetched_slots
+            assert plan.grid_steps(300) == 3 * plan.steps_per_row_tile
+        rep = bank.report(widths=(3,))
+        assert [t["slr_kernel"]["grid_steps"] for t in rep["tiers"]] == [
+            {3: t.slr_kernel.grid_steps(3)} for t in bank]
+        assert banks["factored"][0].slr_kernel is None
+        eng = PagedServingEngine(bank, EngineConfig(max_slots=3, max_len=32,
+                                                    block_size=8))
+        gauges = eng.stats_snapshot()["metrics"]
+        steps = gauges["serve_slr_grid_steps"]["values"]
+        share = gauges["serve_slr_live_tile_share"]["values"]
+        for tier in bank:
+            key = f"PagedServingEngine,{tier.index}"
+            assert steps[f"{key},3"] == tier.slr_kernel.grid_steps(3)
+            assert share[key] == tier.slr_kernel.live_tile_share
 
     def test_param_bytes_accounts_stacked_tables(self, trained):
         cfg, params, state, blocks = trained

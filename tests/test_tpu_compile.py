@@ -44,6 +44,12 @@ PAGES_PER_SLOT = 16    # 256 positions per slot
 SITES = {"attn": (D, D), "mlp_up": (D, FF), "mlp_down": (FF, D)}
 WIDTHS = {"decode": SLOTS, "prefill": 512}
 DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
+# OLMo-1B's sites as the benchmark deploys them: every S block live (MAXB =
+# K blocks), bf16 activations and factors, float32 block tables, at the
+# ranks HPA keeps at budgets 1.0 and 0.6
+OLMO_SITES = {"olmo_attn": (2048, 2048), "olmo_up": (2048, 8192),
+              "olmo_down": (8192, 2048)}
+OLMO_RANKS = (512, 349)
 
 
 @pytest.fixture(scope="module")
@@ -81,17 +87,30 @@ def spec(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def stack_spec(sharding, lead: int, n: int, m: int, dtype) -> BsrStack:
+def stack_spec(sharding, lead: int, n: int, m: int, dtype,
+               maxb: int = MAXB) -> BsrStack:
     """Block-CSC tables of an (n, m) site stacked over ``lead`` layers or
     adapters, tiled with the block size the deployment build picks."""
     bs = _fit_block(n, m, 128)
     jb = -(-m // bs)
     return BsrStack(
         spec(sharding, (lead, jb), jnp.int32),
-        spec(sharding, (lead, jb, MAXB), jnp.int32),
-        spec(sharding, (lead, jb, MAXB, bs, bs), dtype),
+        spec(sharding, (lead, jb, maxb), jnp.int32),
+        spec(sharding, (lead, jb, maxb, bs, bs), dtype),
         (n, m), bs,
     )
+
+
+def stacked_cases():
+    """(site, width, dtype) at 60m widths, then OLMo-1B's sites at ranks 512
+    and 349 and row widths 8 and 128."""
+    cases = [pytest.param(site, width, dtype, id=f"{site}-{width}-{dtype}")
+             for site in sorted(SITES) for width in sorted(WIDTHS)
+             for dtype in sorted(DTYPES)]
+    cases += [pytest.param(site, rows, r, id=f"{site}-r{r}-rows{rows}")
+              for site in sorted(OLMO_SITES) for r in OLMO_RANKS
+              for rows in (8, 128)]
+    return cases
 
 
 def compiled_text(fn, *args) -> str:
@@ -100,36 +119,53 @@ def compiled_text(fn, *args) -> str:
     return text
 
 
-@pytest.mark.parametrize("dtype", sorted(DTYPES))
-@pytest.mark.parametrize("width", sorted(WIDTHS))
-@pytest.mark.parametrize("site", sorted(SITES))
+@pytest.mark.parametrize("site,width,dtype", stacked_cases())
 def test_slr_matmul_stacked(one_chip, site, width, dtype):
     """The fused-format hot path at every linear site of the model. The MLP
     sites are the regression for block sizes that divide 1376 (32 and below),
-    whose x blocks Mosaic refuses."""
-    n, m = SITES[site]
-    dt = DTYPES[dtype]
+    whose x blocks Mosaic refuses. At OLMo-1B's sites ``width`` is the row
+    count and ``dtype`` the rank: a VMEM refusal at the benchmark's sizes
+    shows here."""
+    if site in OLMO_SITES:
+        (n, m), rows, r = OLMO_SITES[site], width, dtype
+        layers, x_dt, s_dt, maxb = 16, jnp.bfloat16, jnp.float32, n // 128
+    else:
+        (n, m), rows, r = SITES[site], WIDTHS[width], RANK
+        layers, x_dt, s_dt, maxb = L, DTYPES[dtype], DTYPES[dtype], MAXB
     compiled_text(
         functools.partial(slr_matmul_stacked_pallas, interpret=False),
-        spec(one_chip, (WIDTHS[width], n), dt),
-        spec(one_chip, (L, n, RANK), dt),
-        spec(one_chip, (L, RANK, m), dt),
-        stack_spec(one_chip, L, n, m, dt),
+        spec(one_chip, (rows, n), x_dt),
+        spec(one_chip, (layers, n, r), x_dt),
+        spec(one_chip, (layers, r, m), x_dt),
+        stack_spec(one_chip, layers, n, m, s_dt, maxb),
         spec(one_chip, (), jnp.int32),
     )
 
 
-@pytest.mark.parametrize("site", sorted(SITES))
-def test_slr_matmul_multi(one_chip, site):
-    """Multi-adapter decode: each of 8 slots runs one of 4 pooled adapters."""
-    n, m = SITES[site]
+def multi_cases():
+    cases = [pytest.param(site, 1, RANK, id=site) for site in sorted(SITES)]
+    cases += [pytest.param(site, t, r, id=f"{site}-r{r}-rows{SLOTS * t}")
+              for site in sorted(OLMO_SITES) for r in OLMO_RANKS
+              for t in (1, 16)]
+    return cases
+
+
+@pytest.mark.parametrize("site,t,r", multi_cases())
+def test_slr_matmul_multi(one_chip, site, t, r):
+    """Multi-adapter decode: each of 8 slots runs one of 4 pooled adapters
+    (``t`` rows each). At OLMo-1B's sites: bf16 activations and factors,
+    float32 tables, every block live."""
     adapters = 4
+    if site in OLMO_SITES:
+        (n, m), x_dt, maxb = OLMO_SITES[site], jnp.bfloat16, OLMO_SITES[site][0] // 128
+    else:
+        (n, m), x_dt, maxb = SITES[site], jnp.float32, MAXB
     compiled_text(
         functools.partial(slr_matmul_multi_pallas, interpret=False),
-        spec(one_chip, (SLOTS, 1, n), jnp.float32),
-        spec(one_chip, (adapters, n, RANK), jnp.float32),
-        spec(one_chip, (adapters, RANK, m), jnp.float32),
-        stack_spec(one_chip, adapters, n, m, jnp.float32),
+        spec(one_chip, (SLOTS, t, n), x_dt),
+        spec(one_chip, (adapters, n, r), x_dt),
+        spec(one_chip, (adapters, r, m), x_dt),
+        stack_spec(one_chip, adapters, n, m, jnp.float32, maxb),
         spec(one_chip, (SLOTS,), jnp.int32),
     )
 
