@@ -1,4 +1,5 @@
-"""Phi-3-medium-14B: dense RoPE SwiGLU GQA. [arXiv:2404.14219]"""
+"""Phi-3-medium-4k: dense RoPE SwiGLU GQA, RMSNorm, untied head.
+[arXiv:2404.14219; hf microsoft/Phi-3-medium-4k-instruct config.json]"""
 from .base import ArchConfig
 
 CONFIG = ArchConfig(
@@ -10,6 +11,10 @@ CONFIG = ArchConfig(
     num_kv_heads=10,
     head_dim=128,
     d_ff=17920,
-    vocab_size=100352,
-    source="arXiv:2404.14219; unverified",
+    vocab_size=32064,
+    mlp_type="swiglu",
+    norm_type="rmsnorm",
+    rope_theta=10000.0,
+    tie_embeddings=False,
+    source="https://huggingface.co/microsoft/Phi-3-medium-4k-instruct; arXiv:2404.14219",
 )

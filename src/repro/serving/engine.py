@@ -990,7 +990,8 @@ class ServingEngine:
                 rows[i] = self._slot_pool[slot]
             with self.metrics.measure_program(
                 f"prefill[{bucket}]", key, traces=lambda: self.prefill_traces,
-                rows=len(group),
+                rows=len(group), tokens=int(lengths[: len(group)].sum()),
+                positions=tokens.size,
             ):
                 first, self.cache = self._prefill(
                     self._call_params(key, rows), jnp.asarray(tokens),
@@ -1768,10 +1769,12 @@ class PagedServingEngine(ServingEngine):
         adapter-pool row map (batched adapter mode only; the prefill batch
         is group-indexed, unlike the slot-indexed decode/chunk programs).
         Returns first tokens (host)."""
+        real = slot_ids < slot_ids.size
         with self.metrics.measure_program(
             f"prefill[{tokens.shape[1]}]", tier,
             traces=lambda: self.prefill_traces,
-            rows=int(np.count_nonzero(slot_ids < slot_ids.size)),
+            rows=int(np.count_nonzero(real)),
+            tokens=int(lengths[real].sum()), positions=tokens.size,
         ):
             first, self.cache = self._prefill(
                 self._call_params(tier, rows), jnp.asarray(tokens),
@@ -1894,6 +1897,7 @@ class PagedServingEngine(ServingEngine):
             f"chunk[{tokens.shape[1]}]", tier,
             traces=lambda: self.chunk_traces,
             rows=int(np.count_nonzero(counts)),
+            tokens=int(counts.sum()), positions=tokens.size,
         ):
             first, self.cache = self._chunk_prog(
                 self._call_params(tier, self._slot_pool), jnp.asarray(tokens),

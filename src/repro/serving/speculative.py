@@ -569,10 +569,12 @@ class SpeculativeEngine(PagedServingEngine):
         # `rows` is the adapter-pool map — always None here: _init_common
         # rejects AdapterBanks on speculative engines
         del rows
+        real = slot_ids < slot_ids.size
         with self.metrics.measure_program(
             f"prefill[{tokens.shape[1]}]", tier,
             traces=lambda: self.prefill_traces,
-            rows=int(np.count_nonzero(slot_ids < slot_ids.size)),
+            rows=int(np.count_nonzero(real)),
+            tokens=int(lengths[real].sum()), positions=tokens.size,
         ):
             first, self.cache, self._dpools = self._prefill2(
                 self.params, self.draft_params, jnp.asarray(tokens),
@@ -590,6 +592,7 @@ class SpeculativeEngine(PagedServingEngine):
             f"chunk[{tokens.shape[1]}]", tier,
             traces=lambda: self.chunk_traces,
             rows=int(np.count_nonzero(counts)),
+            tokens=int(counts.sum()), positions=tokens.size,
         ):
             first, self.cache, self._dpools = self._chunk2(
                 self.params, self.draft_params, jnp.asarray(tokens),

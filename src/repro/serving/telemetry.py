@@ -526,6 +526,17 @@ class EngineTelemetry:
             "serve_adapter_tokens_total",
             "Tokens emitted per adapter id (multi-tenant serving)",
             e + ("adapter",))
+        self.prefill_tokens = r.counter(
+            "serve_prefill_tokens_total",
+            "Real prompt tokens in the prefill and chunk programs run per "
+            "tier (the 'tokens' argument of their serve.program spans)",
+            e + ("tier",))
+        self.prefill_positions = r.counter(
+            "serve_prefill_positions_total",
+            "Token positions the prefill and chunk programs computed per "
+            "tier: rows x bucket width, padding included (the 'positions' "
+            "argument of their serve.program spans); 1 - tokens/positions "
+            "is the padded share of prefill compute", e + ("tier",))
         self.queue_depth = r.gauge(
             "serve_queue_depth", "Requests waiting for admission", e)
         self.active_slots = r.gauge(
@@ -718,7 +729,8 @@ class EngineTelemetry:
 
     @contextmanager
     def measure_program(self, program: str, tier=0, traces=None,
-                        rows: int = 0):
+                        rows: int = 0, tokens: int | None = None,
+                        positions: int | None = None):
         """Open ``serve.program`` around one jitted call site and time it
         from entry (argument flattening, pending block-table pushes, the
         dispatch) to exit, which includes the blocking fetch of its outputs
@@ -726,17 +738,24 @@ class EngineTelemetry:
         retrace detector: ``traces`` is a zero-arg callable reading the
         engine's python-side trace counter for this program; a positive
         delta on a (program, tier) pair that already ran is a RETRACE.
-        ``rows`` is the number of slots in the call."""
+        ``rows`` is the number of slots in the call. A prefill or chunk
+        program also passes ``tokens``, the real prompt tokens it computes,
+        and ``positions``, the token positions it computes (rows x bucket
+        width, padding included): both go on the span and into the per-tier
+        prefill counters."""
         before = traces() if traces is not None else None
         t0 = time.perf_counter_ns()
         try:
-            with self.span("serve.program", program=program, tier=tier,
-                           rows=rows):
+            with self.span("serve.program", **_program_stats(
+                    program, tier, rows, tokens, positions)):
                 yield
         finally:
             dt = (time.perf_counter_ns() - t0) / 1e9
             e = self.engine
             ts = str(tier)
+            if tokens is not None:
+                self.prefill_tokens.inc(tokens, e, ts)
+                self.prefill_positions.inc(positions, e, ts)
             obs = self._prog_obs.get((program, ts))
             if obs is None:
                 obs = self._prog_obs[(program, ts)] = \
@@ -839,9 +858,19 @@ class NullTelemetry(EngineTelemetry):
                                mono_ns=time.monotonic_ns(), active=active,
                                queued=queued)
 
-    def measure_program(self, program, tier=0, traces=None, rows=0):
-        return TraceAnnotation("serve.program", program=program, tier=tier,
-                               rows=rows)
+    def measure_program(self, program, tier=0, traces=None, rows=0,
+                        tokens=None, positions=None):
+        return TraceAnnotation("serve.program", **_program_stats(
+            program, tier, rows, tokens, positions))
+
+
+def _program_stats(program, tier, rows, tokens, positions) -> dict:
+    """The arguments of a ``serve.program`` span; ``tokens`` and
+    ``positions`` only where the call site gives them (prefill programs)."""
+    stats = dict(program=program, tier=tier, rows=rows)
+    if tokens is not None:
+        stats.update(tokens=tokens, positions=positions)
+    return stats
 
 
 # ----------------------------------------------------- request-level helpers ---

@@ -116,7 +116,7 @@ EXPECTED_DIMS = {
     "qwen3_moe_30b_a3b": dict(num_layers=48, d_model=2048, num_heads=32, num_kv_heads=4, d_ff=768, vocab_size=151936, num_experts=128, top_k=8),
     "whisper_small": dict(num_layers=12, d_model=768, num_heads=12, d_ff=3072, vocab_size=51865),
     "olmo_1b": dict(num_layers=16, d_model=2048, num_heads=16, d_ff=8192, vocab_size=50304),
-    "phi3_medium_14b": dict(num_layers=40, d_model=5120, num_heads=40, num_kv_heads=10, d_ff=17920, vocab_size=100352),
+    "phi3_medium_14b": dict(num_layers=40, d_model=5120, num_heads=40, num_kv_heads=10, d_ff=17920, vocab_size=32064, tie_embeddings=False, norm_type="rmsnorm"),
     "gemma_7b": dict(num_layers=28, d_model=3072, num_heads=16, d_ff=24576, vocab_size=256000, head_dim=256),
     "qwen1p5_4b": dict(num_layers=40, d_model=2560, num_heads=20, d_ff=6912, vocab_size=151936, qkv_bias=True),
     "internvl2_76b": dict(num_layers=80, d_model=8192, num_heads=64, num_kv_heads=8, d_ff=28672, vocab_size=128256),

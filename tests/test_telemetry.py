@@ -497,7 +497,9 @@ class TestServeSpans:
             assert any(_inside(f, p) for p in by["serve.program"]), f
         programs = by["serve.program"]
         for p in programs:
-            assert set(p[3]) == {"program", "tier", "rows"}
+            prefill = p[3]["program"].startswith("prefill[")
+            assert set(p[3]) == {"program", "tier", "rows"} | (
+                {"tokens", "positions"} if prefill else set())
             assert p[3]["rows"] >= 1
         names = {p[3]["program"] for p in programs}
         assert "decode" in names and any(n.startswith("prefill[")
@@ -539,6 +541,49 @@ class TestServeSpans:
         _, s_plain, _ = driven("two_tiers", telemetry)
         _, s_prof, _, _ = profiled("two_tiers", telemetry)
         assert s_prof == s_plain
+
+
+class TestPrefillPadding:
+    """Prefill and chunk programs carry the real prompt tokens they compute
+    (``tokens``) and the positions they compute (``positions``: rows x
+    bucket width); the per-tier counters add them up over the ticks."""
+
+    @staticmethod
+    def _prefills(spans, prefix):
+        return [s[3] for s in spans if s[0] == "serve.program"
+                and s[3]["program"].startswith(prefix)]
+
+    @pytest.mark.parametrize("telemetry", [True, False])
+    def test_one_shot_prefill_span_carries_tokens_and_positions(
+            self, profiled, telemetry):
+        eng, _, done, spans = profiled("two_tiers", telemetry)
+        progs = self._prefills(spans, "prefill[")
+        assert progs
+        for p in progs:
+            bucket = int(p["program"][len("prefill["):-1])
+            assert p["positions"] == eng.ecfg.max_slots * bucket
+            assert p["rows"] <= p["tokens"] <= p["rows"] * bucket
+        assert sum(p["tokens"] for p in progs) == sum(
+            len(r.prompt) for r in done)
+
+    @pytest.mark.parametrize("telemetry", [True, False])
+    def test_counters_add_up_across_ticks(self, profiled, telemetry):
+        for name, prefix in (("two_tiers", "prefill["),
+                             ("chunked_prefill", "chunk[")):
+            eng, _, done, spans = profiled(name, telemetry)
+            progs = self._prefills(spans, prefix)
+            assert len(progs) > 1, name
+            m = eng.metrics
+            for tier in {p["tier"] for p in progs}:
+                mine = [p for p in progs if p["tier"] == tier]
+                want = (sum(p["tokens"] for p in mine),
+                        sum(p["positions"] for p in mine))
+                got = (m.counter_value(m.prefill_tokens, str(tier)),
+                       m.counter_value(m.prefill_positions, str(tier)))
+                assert got == (want if telemetry else (0, 0)), (name, tier)
+            assert sum(p["tokens"] for p in progs) == sum(
+                len(r.prompt) for r in done)
+            assert all(p["tokens"] <= p["positions"] for p in progs)
 
 
 class TestSlowestStep:

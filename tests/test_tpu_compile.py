@@ -50,6 +50,13 @@ DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
 OLMO_SITES = {"olmo_attn": (2048, 2048), "olmo_up": (2048, 8192),
               "olmo_down": (8192, 2048)}
 OLMO_RANKS = (512, 349)
+# Phi-3-medium-4k's sites at the rank HPA keeps at budget 0.6 (4 layers, as
+# the benchmark's one-chip stage deploys them), every S block live, at
+# decode (2 slots) and one-shot prefill (2 rows of 2048) widths
+PHI3_SITES = {"phi3_qo": (5120, 5120, 870), "phi3_kv": (5120, 1280, 261),
+              "phi3_up": (5120, 17920, 870), "phi3_down": (17920, 5120, 870)}
+PHI3_ROWS = (2, 2 * 2048)
+PHI3_H, PHI3_KV, PHI3_SLOTS, PHI3_PAGE = 40, 10, 2, 128
 
 
 @pytest.fixture(scope="module")
@@ -103,13 +110,17 @@ def stack_spec(sharding, lead: int, n: int, m: int, dtype,
 
 def stacked_cases():
     """(site, width, dtype) at 60m widths, then OLMo-1B's sites at ranks 512
-    and 349 and row widths 8 and 128."""
+    and 349 and row widths 8 and 128, and Phi-3-medium's at rank 870 (261 at
+    k and v) and row widths 2 and 4096."""
     cases = [pytest.param(site, width, dtype, id=f"{site}-{width}-{dtype}")
              for site in sorted(SITES) for width in sorted(WIDTHS)
              for dtype in sorted(DTYPES)]
     cases += [pytest.param(site, rows, r, id=f"{site}-r{r}-rows{rows}")
               for site in sorted(OLMO_SITES) for r in OLMO_RANKS
               for rows in (8, 128)]
+    cases += [pytest.param(site, rows, PHI3_SITES[site][2],
+                           id=f"{site}-rows{rows}")
+              for site in sorted(PHI3_SITES) for rows in PHI3_ROWS]
     return cases
 
 
@@ -123,12 +134,15 @@ def compiled_text(fn, *args) -> str:
 def test_slr_matmul_stacked(one_chip, site, width, dtype):
     """The fused-format hot path at every linear site of the model. The MLP
     sites are the regression for block sizes that divide 1376 (32 and below),
-    whose x blocks Mosaic refuses. At OLMo-1B's sites ``width`` is the row
-    count and ``dtype`` the rank: a VMEM refusal at the benchmark's sizes
-    shows here."""
+    whose x blocks Mosaic refuses. At OLMo-1B's and Phi-3-medium's sites
+    ``width`` is the row count and ``dtype`` the rank: a VMEM refusal at the
+    benchmark's sizes shows here."""
     if site in OLMO_SITES:
         (n, m), rows, r = OLMO_SITES[site], width, dtype
         layers, x_dt, s_dt, maxb = 16, jnp.bfloat16, jnp.float32, n // 128
+    elif site in PHI3_SITES:
+        (n, m, _), rows, r = PHI3_SITES[site], width, dtype
+        layers, x_dt, s_dt, maxb = 4, jnp.bfloat16, jnp.float32, n // 128
     else:
         (n, m), rows, r = SITES[site], WIDTHS[width], RANK
         layers, x_dt, s_dt, maxb = L, DTYPES[dtype], DTYPES[dtype], MAXB
@@ -191,24 +205,33 @@ def test_lowrank_and_bsr_matmul(one_chip, site):
     compiled_text(functools.partial(bsr_matmul_pallas, interpret=False), x, bsr)
 
 
-def paged_pool(pool_sharding, table_sharding, dtype):
+def paged_pool(pool_sharding, table_sharding, dtype, slots=SLOTS, heads=H,
+               page=PAGE, per_slot=PAGES_PER_SLOT, head_dim=HD):
     """K and V page pools, block table and lengths."""
-    pages = SLOTS * PAGES_PER_SLOT
+    pages = slots * per_slot
     return (
-        spec(pool_sharding, (pages, H, PAGE, HD), dtype),
-        spec(pool_sharding, (pages, H, PAGE, HD), dtype),
-        spec(table_sharding, (SLOTS, PAGES_PER_SLOT), jnp.int32),
-        spec(table_sharding, (SLOTS,), jnp.int32),
+        spec(pool_sharding, (pages, heads, page, head_dim), dtype),
+        spec(pool_sharding, (pages, heads, page, head_dim), dtype),
+        spec(table_sharding, (slots, per_slot), jnp.int32),
+        spec(table_sharding, (slots,), jnp.int32),
     )
 
 
-@pytest.mark.parametrize("dtype", sorted(DTYPES))
+@pytest.mark.parametrize("dtype", sorted(DTYPES) + ["phi3"])
 def test_paged_attention_decode(one_chip, dtype):
-    dt = DTYPES[dtype]
+    """At 60m widths in each dtype; "phi3" is Phi-3-medium's decode: 40
+    query heads over 10 KV heads of 128 (GQA group 4), 2 slots of 2048
+    positions in pages of 128, bfloat16."""
+    if dtype == "phi3":
+        dt, slots, heads = jnp.bfloat16, PHI3_SLOTS, PHI3_H
+        pool = paged_pool(one_chip, one_chip, dt, PHI3_SLOTS, PHI3_KV,
+                          PHI3_PAGE, 2048 // PHI3_PAGE, 128)
+    else:
+        dt, slots, heads = DTYPES[dtype], SLOTS, H
+        pool = paged_pool(one_chip, one_chip, dt)
     compiled_text(
         functools.partial(paged_attention_pallas, interpret=False),
-        spec(one_chip, (SLOTS, H, HD), dt),
-        *paged_pool(one_chip, one_chip, dt),
+        spec(one_chip, (slots, heads, pool[0].shape[-1]), dt), *pool,
     )
 
 
@@ -234,13 +257,19 @@ def test_page_copy(one_chip, dtype):
     )
 
 
-@pytest.mark.parametrize("seq", [128, 256])
+@pytest.mark.parametrize("seq", [128, 256, "phi3-2048"])
 def test_flash_attention(one_chip, seq):
-    """One-shot prefill attention at the serving prompt buckets."""
-    q = spec(one_chip, (SLOTS, H, seq, HD), jnp.float32)
+    """One-shot prefill attention at the serving prompt buckets; "phi3-2048"
+    is Phi-3-medium's longest bucket: 2 rows of 2048 positions, 40 query
+    heads over 10 KV heads of 128, bfloat16."""
+    if seq == "phi3-2048":
+        q = spec(one_chip, (PHI3_SLOTS, PHI3_H, 2048, 128), jnp.bfloat16)
+        kv = spec(one_chip, (PHI3_SLOTS, PHI3_KV, 2048, 128), jnp.bfloat16)
+    else:
+        q = kv = spec(one_chip, (SLOTS, H, seq, HD), jnp.float32)
     compiled_text(
         functools.partial(flash_attention_pallas, causal=True, interpret=False),
-        q, q, q,
+        q, kv, kv,
     )
 
 
