@@ -239,43 +239,48 @@ def _head_shard_mesh(num_q_heads: int, num_kv_heads: int):
     return mesh
 
 
-def paged_attention(q, k_pages, v_pages, block_table, lengths,
+def paged_attention(q, k_pages, v_pages, block_table, lengths, layer,
                     interpret: bool | None = None):
+    """Single-query paged decode attention over layer ``layer`` of the
+    stacked ``(L, num_pages, Hkv, bs, D)`` pools, read in place."""
     interp = _auto_interpret() if interpret is None else interpret
-    mesh = _head_shard_mesh(q.shape[1], k_pages.shape[1])
+    mesh = _head_shard_mesh(q.shape[1], k_pages.shape[2])
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
 
+        pool = P(None, None, "model", None, None)  # (L, pages, Hkv, bs, D)
         fn = jax.shard_map(
             functools.partial(paged_attention_pallas, interpret=interp),
             mesh=mesh,
             in_specs=(
                 P(None, "model", None),        # q: (B, Hq, D) heads sharded
-                P(None, "model", None, None),  # k pool: (pages, Hkv, bs, D)
-                P(None, "model", None, None),  # v pool
+                pool, pool,
                 P(),                           # block table: host bookkeeping
                 P(),                           # lengths
+                P(),                           # layer
             ),
             out_specs=P(None, "model", None),
             check_vma=False,
         )
-        return fn(q, k_pages, v_pages, block_table, lengths)
+        return fn(q, k_pages, v_pages, block_table, lengths, layer)
     return paged_attention_pallas(
-        q, k_pages, v_pages, block_table, lengths, interpret=interp,
+        q, k_pages, v_pages, block_table, lengths, layer, interpret=interp,
     )
 
 
-def paged_attention_kquery(q, k_pages, v_pages, block_table, lengths,
+def paged_attention_kquery(q, k_pages, v_pages, block_table, lengths, layer,
                            interpret: bool | None = None,
                            q_tile: int | None = None):
     """Multi-query paged attention: the speculative-verify window (kq = draft
     k) and chunked-prefill chunks (kq = prefill_chunk) share this kernel —
-    wide windows tile the query axis across the grid (``q_tile``)."""
+    wide windows tile the query axis across the grid (``q_tile``). Pools as
+    in ``paged_attention``."""
     interp = _auto_interpret() if interpret is None else interpret
-    mesh = _head_shard_mesh(q.shape[1], k_pages.shape[1])
+    mesh = _head_shard_mesh(q.shape[1], k_pages.shape[2])
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
 
+        pool = P(None, None, "model", None, None)  # (L, pages, Hkv, bs, D)
         fn = jax.shard_map(
             functools.partial(
                 paged_attention_kquery_pallas, interpret=interp, q_tile=q_tile
@@ -283,17 +288,18 @@ def paged_attention_kquery(q, k_pages, v_pages, block_table, lengths,
             mesh=mesh,
             in_specs=(
                 P(None, "model", None, None),  # q: (B, Hq, kq, D)
-                P(None, "model", None, None),
-                P(None, "model", None, None),
+                pool, pool,
+                P(),
                 P(),
                 P(),
             ),
             out_specs=P(None, "model", None, None),
             check_vma=False,
         )
-        return fn(q, k_pages, v_pages, block_table, lengths)
+        return fn(q, k_pages, v_pages, block_table, lengths, layer)
     return paged_attention_kquery_pallas(
-        q, k_pages, v_pages, block_table, lengths, interpret=interp, q_tile=q_tile,
+        q, k_pages, v_pages, block_table, lengths, layer, interpret=interp,
+        q_tile=q_tile,
     )
 
 

@@ -1,12 +1,14 @@
 """Pallas TPU kernels: paged decode attention (single-query and k-query).
 
-The serving engine stores KV in a fixed pool of ``(num_pages, Hkv, bs, D)``
-pages per layer; each slot's logical sequence is scattered across pages named
-by its block-table row. Gathering those pages with ``jnp`` materializes a
-``(B, Hkv, pages_per_slot * bs, D)`` copy per layer per step — this kernel
-instead scalar-prefetches the block table so each page is DMA'd straight from
-its pool position (the gather happens in the DMA engine, like the BSR rows
-table in ``bsr_matmul.py``).
+The serving engine stores KV in one stacked pool of ``(L, num_pages, Hkv,
+bs, D)`` pages; each slot's logical sequence is scattered across pages named
+by its block-table row, the same pages in every layer. Gathering those pages
+with ``jnp`` materializes a ``(B, Hkv, pages_per_slot * bs, D)`` copy per
+layer per step — this kernel instead scalar-prefetches the block table and
+the layer index so each page is DMA'd straight from its position in the
+stacked pool (the gather happens in the DMA engine, like the BSR rows table
+in ``bsr_matmul.py``). The layer scan passes the whole stack to every layer's
+call, so no layer's pool is ever sliced out of it.
 
 Grid: (B * Hkv, pages_per_slot) — page minor, classic online softmax with
 running (max, denom, acc) VMEM scratch carried across a slot's pages. GQA is
@@ -43,11 +45,22 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+def _tables(block_table, lengths, layer, n):
+    """The scalar-prefetch table: ``[block_table (B * nb,), lengths (B,),
+    layer (1,)]``. Unmapped entries (>= n) clamp to a real page; the length
+    mask hides whatever it holds."""
+    return jnp.concatenate([
+        jnp.minimum(block_table, n - 1).reshape(-1),
+        lengths.reshape(-1),
+        jnp.reshape(layer, (1,)),
+    ]).astype(jnp.int32)
+
+
 def _kernel(
     tables_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     *, scale, bs, nb, n_kv, table_len,
 ):
-    # tables layout: [block_table (B * nb,), lengths (B,)]
+    # tables layout: [block_table (B * nb,), lengths (B,), layer (1,)]
     bh = pl.program_id(0)
     i = pl.program_id(1)
     b = bh // n_kv
@@ -65,8 +78,8 @@ def _kernel(
     @pl.when(i * bs <= length)
     def page():
         q = q_ref[0].astype(jnp.float32) * scale        # (group, D)
-        k = k_ref[0, 0].astype(jnp.float32)             # (bs, D)
-        v = v_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0, 0].astype(jnp.float32)          # (bs, D)
+        v = v_ref[0, 0, 0].astype(jnp.float32)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (group, bs)
         pos = i * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
         s = jnp.where(pos <= length, s, NEG_INF)
@@ -88,25 +101,29 @@ def _kernel(
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_pallas(
     q: jax.Array,            # (B, Hq, D) single decode query per slot
-    k_pages: jax.Array,      # (num_pages, Hkv, bs, D) page pool
+    k_pages: jax.Array,      # (L, num_pages, Hkv, bs, D) stacked page pools
     v_pages: jax.Array,
     block_table: jax.Array,  # (B, pages_per_slot) int32
     lengths: jax.Array,      # (B,) int32 pre-insert valid length per slot
+    layer: jax.Array | int,  # () int32 layer whose pages attention reads
     *,
     interpret: bool,
 ) -> jax.Array:
     b, hq, d = q.shape
-    n, hkv, bs, _ = k_pages.shape
+    _, n, hkv, bs, _ = k_pages.shape
     assert hq % hkv == 0
     group = hq // hkv
     nb = block_table.shape[1]
     scale = 1.0 / (d ** 0.5)
 
     qf = q.reshape(b * hkv, group, d)
-    # unmapped entries (>= n) clamp to a real page; the length mask hides it
-    tables = jnp.concatenate(
-        [jnp.minimum(block_table, n - 1).reshape(-1), lengths]
-    ).astype(jnp.int32)
+    tables = _tables(block_table, lengths, layer, n)
+    lyr = b * nb + b              # the layer index's slot in ``tables``
+    # one (bs, D) page of the stacked pool: (layer, page, head, 0, 0)
+    page_spec = pl.BlockSpec(
+        (1, 1, 1, bs, d),
+        lambda bh, i, t: (t[lyr], t[(bh // hkv) * nb + i], bh % hkv, 0, 0),
+    )
 
     kernel = functools.partial(
         _kernel, scale=scale, bs=bs, nb=nb, n_kv=hkv, table_len=b * nb,
@@ -116,14 +133,8 @@ def paged_attention_pallas(
         grid=(b * hkv, nb),
         in_specs=[
             pl.BlockSpec((1, group, d), lambda bh, i, t: (bh, 0, 0)),
-            pl.BlockSpec(
-                (1, 1, bs, d),
-                lambda bh, i, t: (t[(bh // hkv) * nb + i], bh % hkv, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, bs, d),
-                lambda bh, i, t: (t[(bh // hkv) * nb + i], bh % hkv, 0, 0),
-            ),
+            page_spec,
+            page_spec,
         ],
         out_specs=pl.BlockSpec((1, group, d), lambda bh, i, t: (bh, 0, 0)),
         scratch_shapes=[
@@ -151,7 +162,7 @@ def _kquery_kernel(
     tables_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     *, scale, bs, nb, n_kv, q_tile, group, table_len,
 ):
-    # tables layout: [block_table (B * nb,), lengths (B,)]
+    # tables layout: [block_table (B * nb,), lengths (B,), layer (1,)]
     bh = pl.program_id(0)
     qt = pl.program_id(1)
     i = pl.program_id(2)
@@ -173,8 +184,8 @@ def _kquery_kernel(
     @pl.when(i * bs <= length + q0 + q_tile - 1)
     def page():
         q = q_ref[0].astype(jnp.float32) * scale        # (rows, D)
-        k = k_ref[0, 0].astype(jnp.float32)             # (bs, D)
-        v = v_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0, 0].astype(jnp.float32)          # (bs, D)
+        v = v_ref[0, 0, 0].astype(jnp.float32)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (rows, bs)
         pos = i * bs + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
         qi = q0 + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0) // group
@@ -205,17 +216,18 @@ def paged_attention_kquery_pallas(
     q: jax.Array,            # (B, Hq, kq, D) — kq queries per slot, positions
     #                          length .. length + kq - 1 (speculative-verify
     #                          window or chunked-prefill chunk)
-    k_pages: jax.Array,      # (num_pages, Hkv, bs, D) page pool
+    k_pages: jax.Array,      # (L, num_pages, Hkv, bs, D) stacked page pools
     v_pages: jax.Array,
     block_table: jax.Array,  # (B, pages_per_slot) int32
     lengths: jax.Array,      # (B,) int32 pre-insert valid length per slot
+    layer: jax.Array | int,  # () int32 layer whose pages attention reads
     *,
     interpret: bool,
     q_tile: int | None = None,  # queries per grid tile; None = auto (whole
     #                             window while kq * group <= _MAX_Q_ROWS)
 ) -> jax.Array:
     b, hq, kq, d = q.shape
-    n, hkv, bs, _ = k_pages.shape
+    _, n, hkv, bs, _ = k_pages.shape
     assert hq % hkv == 0
     group = hq // hkv
     nb = block_table.shape[1]
@@ -235,9 +247,12 @@ def paged_attention_kquery_pallas(
     # rows ordered query-major: row = qi * group + g
     qf = q.reshape(b, hkv, group, kq_pad, d).transpose(0, 1, 3, 2, 4)
     qf = qf.reshape(b * hkv, kq_pad * group, d)
-    tables = jnp.concatenate(
-        [jnp.minimum(block_table, n - 1).reshape(-1), lengths]
-    ).astype(jnp.int32)
+    tables = _tables(block_table, lengths, layer, n)
+    lyr = b * nb + b              # the layer index's slot in ``tables``
+    page_spec = pl.BlockSpec(
+        (1, 1, 1, bs, d),
+        lambda bh, qt, i, t: (t[lyr], t[(bh // hkv) * nb + i], bh % hkv, 0, 0),
+    )
 
     kernel = functools.partial(
         _kquery_kernel, scale=scale, bs=bs, nb=nb, n_kv=hkv, q_tile=q_tile,
@@ -250,14 +265,8 @@ def paged_attention_kquery_pallas(
             pl.BlockSpec(
                 (1, q_tile * group, d), lambda bh, qt, i, t: (bh, qt, 0)
             ),
-            pl.BlockSpec(
-                (1, 1, bs, d),
-                lambda bh, qt, i, t: (t[(bh // hkv) * nb + i], bh % hkv, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, bs, d),
-                lambda bh, qt, i, t: (t[(bh // hkv) * nb + i], bh % hkv, 0, 0),
-            ),
+            page_spec,
+            page_spec,
         ],
         out_specs=pl.BlockSpec(
             (1, q_tile * group, d), lambda bh, qt, i, t: (bh, qt, 0)

@@ -67,24 +67,26 @@ def slr_matmul_multi_ref(x: jax.Array, p, vt, stack, ids) -> jax.Array:
 
 def paged_attention_ref(
     q: jax.Array,            # (B, Hq, D) single decode query per slot
-    k_pages: jax.Array,      # (num_pages, Hkv, bs, D) page pool
+    k_pages: jax.Array,      # (L, num_pages, Hkv, bs, D) stacked page pools
     v_pages: jax.Array,
     block_table: jax.Array,  # (B, pages_per_slot) int32; >= num_pages unmapped
     lengths: jax.Array,      # (B,) pre-insert valid length per slot
+    layer,                   # () layer whose pages attention reads
 ) -> jax.Array:
-    """Gather pages via the block table, then masked decode softmax.
+    """Gather layer ``layer``'s pages via the block table, then masked decode
+    softmax.
 
     The query at slot b sits at position ``lengths[b]`` (its KV is already in
     the pool), so keys at positions <= lengths[b] are visible.
     """
-    n, hkv, bs, d = k_pages.shape
+    _, n, hkv, bs, d = k_pages.shape
     b, hq, _ = q.shape
     group = hq // hkv
     bt = jnp.minimum(block_table, n - 1)     # clamp unmapped; mask hides it
     nb = bt.shape[1]
 
     def gather(pages):
-        g = pages[bt]                        # (B, nb, Hkv, bs, D)
+        g = pages[layer, bt]                 # (B, nb, Hkv, bs, D)
         return g.transpose(0, 2, 1, 3, 4).reshape(b, hkv, nb * bs, d)
 
     k, v = gather(k_pages), gather(v_pages)
@@ -100,10 +102,11 @@ def paged_attention_ref(
 
 def paged_attention_kquery_ref(
     q: jax.Array,            # (B, Hq, kq, D) — kq decode queries per slot
-    k_pages: jax.Array,      # (num_pages, Hkv, bs, D) page pool
+    k_pages: jax.Array,      # (L, num_pages, Hkv, bs, D) stacked page pools
     v_pages: jax.Array,
     block_table: jax.Array,  # (B, pages_per_slot) int32; >= num_pages unmapped
     lengths: jax.Array,      # (B,) pre-insert valid length per slot
+    layer,                   # () layer whose pages attention reads
 ) -> jax.Array:
     """k-query paged attention oracle (speculative-verify window AND
     chunked-prefill chunks — the kernel's query tiling must be invisible, so
@@ -113,14 +116,14 @@ def paged_attention_kquery_ref(
     window tokens is already in the pool), so it sees keys at positions
     <= lengths[b] + i.
     """
-    n, hkv, bs, d = k_pages.shape
+    _, n, hkv, bs, d = k_pages.shape
     b, hq, kq, _ = q.shape
     group = hq // hkv
     bt = jnp.minimum(block_table, n - 1)     # clamp unmapped; mask hides it
     nb = bt.shape[1]
 
     def gather(pages):
-        g = pages[bt]                        # (B, nb, Hkv, bs, D)
+        g = pages[layer, bt]                 # (B, nb, Hkv, bs, D)
         return g.transpose(0, 2, 1, 3, 4).reshape(b, hkv, nb * bs, d)
 
     k, v = gather(k_pages), gather(v_pages)

@@ -30,7 +30,14 @@ class KVCache(NamedTuple):
 
 
 class PagedLayerCache(NamedTuple):
-    """One layer's view of a block-paged KV cache (serving decode path).
+    """One layer's view of the block-paged KV cache (serving paged path).
+
+    The pools are the whole stacked cache, ``(L, num_pages, Hkv, block_size,
+    D)``, and ``layer`` names this view's layer (a traced scalar inside the
+    layer scan, a Python int when the stack is unrolled). Reads and writes
+    address the stack at ``[layer, page]`` in place: no layer's pool is ever
+    sliced out and written back, so a decode step moves only the pages
+    attention reads and the rows it inserts.
 
     Token position j of slot b lives in page ``block_table[b, j // bs]`` at
     offset ``j % bs``. Entries ``>= num_pages`` mean "unmapped" — writes to
@@ -39,16 +46,19 @@ class PagedLayerCache(NamedTuple):
     dequantize per-(position, head) — serving/kv_quant.py layout.
     """
 
-    k: jax.Array            # (num_pages, Hkv, block_size, D) page pool
+    k: jax.Array            # (L, num_pages, Hkv, block_size, D) page pools
     v: jax.Array
     block_table: jax.Array  # (B, pages_per_slot) int32
     length: jax.Array       # (B,) int32 valid tokens per slot
-    k_scale: jax.Array | None = None  # (num_pages, Hkv, block_size, 1) f32
+    layer: jax.Array | int  # () int32 layer index into the stacked pools
+    k_scale: jax.Array | None = None  # (L, num_pages, Hkv, block_size, 1) f32
     v_scale: jax.Array | None = None
 
 
 def paged_insert(cache: PagedLayerCache, kh: jax.Array, vh: jax.Array) -> PagedLayerCache:
-    """Insert t decode tokens (B, Hkv, t, D) at positions length..length+t-1.
+    """Insert t decode tokens (B, Hkv, t, D) at positions length..length+t-1
+    of layer ``cache.layer``: one small scatter per stacked pool, of one
+    ``(D,)`` row per (slot, token, head) at ``[layer, page, head, off]``.
 
     t == 1 is the classic decode insert; t == k is the speculative verify
     insert (the k draft positions land in one scatter); t == chunk is the
@@ -66,7 +76,7 @@ def paged_insert(cache: PagedLayerCache, kh: jax.Array, vh: jax.Array) -> PagedL
     admission copy-on-writes (kernels/page_copy.py) and remaps BEFORE the
     first insert. This function therefore stays collision-free unchanged.
     """
-    n, _, bs, _ = cache.k.shape
+    _, n, _, bs, _ = cache.k.shape
     nb = cache.block_table.shape[1]
     t = kh.shape[2]
     pos = cache.length[:, None] + jnp.arange(t)[None, :]           # (B, t)
@@ -78,39 +88,46 @@ def paged_insert(cache: PagedLayerCache, kh: jax.Array, vh: jax.Array) -> PagedL
     off = pos % bs
     k_tok = kh.transpose(0, 2, 1, 3)              # (B, t, Hkv, D)
     v_tok = vh.transpose(0, 2, 1, 3)
+    # every axis but the last is indexed, so each update is one (D,) row:
+    # the scatter then keeps the pool's own layout and writes in place
+    head = jnp.arange(kh.shape[1])[None, None, :]
+    page, off = page[..., None], off[..., None]   # (B, t, 1)
+
+    def put(pool, rows):
+        return pool.at[cache.layer, page, head, off].set(
+            rows.astype(pool.dtype), mode="drop"
+        )
+
     if cache.k_scale is not None:
         from ..serving.kv_quant import quantize_kv
 
         k_q, k_s = quantize_kv(k_tok)
         v_q, v_s = quantize_kv(v_tok)
         return cache._replace(
-            k=cache.k.at[page, :, off, :].set(k_q, mode="drop"),
-            v=cache.v.at[page, :, off, :].set(v_q, mode="drop"),
-            k_scale=cache.k_scale.at[page, :, off, :].set(k_s, mode="drop"),
-            v_scale=cache.v_scale.at[page, :, off, :].set(v_s, mode="drop"),
+            k=put(cache.k, k_q), v=put(cache.v, v_q),
+            k_scale=put(cache.k_scale, k_s), v_scale=put(cache.v_scale, v_s),
             length=cache.length + t,
         )
     return cache._replace(
-        k=cache.k.at[page, :, off, :].set(k_tok.astype(cache.k.dtype), mode="drop"),
-        v=cache.v.at[page, :, off, :].set(v_tok.astype(cache.v.dtype), mode="drop"),
-        length=cache.length + t,
+        k=put(cache.k, k_tok), v=put(cache.v, v_tok), length=cache.length + t,
     )
 
 
 def paged_gather(cache: PagedLayerCache) -> tuple[jax.Array, jax.Array]:
-    """Materialize each slot's logical KV sequence from its pages.
+    """Materialize each slot's logical KV sequence of layer ``cache.layer``
+    from its pages (the jnp path: int8 pages and non-Pallas kernels).
 
     Returns (k, v) of shape (B, Hkv, pages_per_slot * block_size, D) laid out
     so logical position j of the contiguous cache and position j here hold
     identical values — the decode einsum then matches the unpaged path.
     """
-    n = cache.k.shape[0]
+    n = cache.k.shape[1]
     bt = jnp.minimum(cache.block_table, n - 1)    # clamp unmapped; mask hides it
 
     def gather(pages, scale):
-        g = pages[bt]                             # (B, nb, Hkv, bs, D)
+        g = pages[cache.layer, bt]                # (B, nb, Hkv, bs, D)
         if scale is not None:
-            g = g.astype(jnp.float32) * scale[bt]
+            g = g.astype(jnp.float32) * scale[cache.layer, bt]
         b, nb, h, bs, d = g.shape
         return g.transpose(0, 2, 1, 3, 4).reshape(b, h, nb * bs, d)
 
@@ -255,6 +272,11 @@ def attention_block(
     * cross-attention: kv_override supplies precomputed (k, v) heads
     """
     b, t, _ = x.shape
+    paged_kernel = (
+        isinstance(cache, PagedLayerCache)
+        and kernel_impl == "pallas"
+        and cache.k_scale is None
+    )
     q = _proj(x, params["q"], params.get("q_bias"))
     q = q.reshape(b, t, n_heads, head_dim)
 
@@ -274,10 +296,11 @@ def attention_block(
         if isinstance(cache, PagedLayerCache):
             # t == 1: classic paged decode; t == k: speculative verify;
             # t == chunk: chunked prefill — the t positions insert in one
-            # scatter and attend through the same block-table gather
-            # (query i sees keys <= length + i)
+            # scatter and attend through the block table (query i sees keys
+            # <= length + i): in the Pallas kernel's DMA, else a jnp gather
             new_cache = paged_insert(cache, kh, vh)
-            kh, vh = paged_gather(new_cache)
+            if not paged_kernel:
+                kh, vh = paged_gather(new_cache)
         elif cache is not None:
             # insert at cache.length (decode: t == 1; chunked prefill: t == chunk)
             if jnp.ndim(cache.length) == 0:
@@ -309,11 +332,7 @@ def attention_block(
     qh = q.transpose(0, 2, 1, 3)  # (B, Hq, T, D)
 
     if cache is not None and kv_override is None:
-        if (
-            isinstance(cache, PagedLayerCache)
-            and kernel_impl == "pallas"
-            and cache.k_scale is None
-        ):
+        if paged_kernel:
             # Pallas paged-decode kernels: the page gather happens in the DMA
             # engine via the scalar-prefetched block table, not a jnp gather.
             # t == 1 is the single-query decode kernel; t > 1 the multi-query
@@ -324,14 +343,14 @@ def attention_block(
 
                 out = paged_attention(
                     qh[:, :, 0], new_cache.k, new_cache.v,
-                    new_cache.block_table, cache.length,
+                    new_cache.block_table, cache.length, cache.layer,
                 )[:, :, None, :]
             else:
                 from ..kernels.ops import paged_attention_kquery
 
                 out = paged_attention_kquery(
                     qh, new_cache.k, new_cache.v,
-                    new_cache.block_table, cache.length,
+                    new_cache.block_table, cache.length, cache.layer,
                 )
         elif t > 1 and not isinstance(cache, PagedLayerCache):
             # chunked prefill into a cache: the dense masked-score path would

@@ -202,27 +202,17 @@ def forward(
         (x, aux_total), kvs = jax.lax.scan(body, (x, aux_total), layer_xs, unroll=cfg.scan_unroll)
         new_cache = kvs  # (kh (L,B,H,T,D), vh (L,B,H,T,D))
     elif isinstance(cache, PagedKVCache):
-        # paged decode: carry the page pools (layer-sliced like the contiguous
-        # path below); block table and lengths are layer-invariant
-        quant = cache.k_scale is not None
-
+        # paged decode: the stacked page pools ride the carry whole and each
+        # layer reads and writes them in place at its index (no per-layer
+        # slice or write-back); block table and lengths are layer-invariant
         def body(carry, inp):
             x, aux, k_p, v_p, k_s, v_s = carry
             lp, l_idx = inp
             layer_cache = PagedLayerCache(
-                jax.lax.dynamic_index_in_dim(k_p, l_idx, 0, keepdims=False),
-                jax.lax.dynamic_index_in_dim(v_p, l_idx, 0, keepdims=False),
-                cache.block_table, cache.length,
-                jax.lax.dynamic_index_in_dim(k_s, l_idx, 0, keepdims=False) if quant else None,
-                jax.lax.dynamic_index_in_dim(v_s, l_idx, 0, keepdims=False) if quant else None,
+                k_p, v_p, cache.block_table, cache.length, l_idx, k_s, v_s
             )
             x, a, kv = _layer_apply(get_lp(lp), x, cfg, positions, layer_cache)
-            k_p = jax.lax.dynamic_update_index_in_dim(k_p, kv.k, l_idx, 0)
-            v_p = jax.lax.dynamic_update_index_in_dim(v_p, kv.v, l_idx, 0)
-            if quant:
-                k_s = jax.lax.dynamic_update_index_in_dim(k_s, kv.k_scale, l_idx, 0)
-                v_s = jax.lax.dynamic_update_index_in_dim(v_s, kv.v_scale, l_idx, 0)
-            return (x, aux + a, k_p, v_p, k_s, v_s), None
+            return (x, aux + a, kv.k, kv.v, kv.k_scale, kv.v_scale), None
 
         (x, aux_total, k_new, v_new, ks_new, vs_new), _ = jax.lax.scan(
             body,
@@ -269,7 +259,7 @@ def _forward_unrolled(layers, x, cfg, positions, cache: LMCache | None, collect_
 
     Semantics match the scan paths exactly: prefill (cache=None) returns
     stacked (k, v) heads when collect_kv, decode updates layer slices of the
-    full LMCache in place.
+    full LMCache, or writes the stacked page pools in place at each layer.
     """
     aux_total = jnp.zeros((), jnp.float32)
     if cache is None:
@@ -288,23 +278,17 @@ def _forward_unrolled(layers, x, cfg, positions, cache: LMCache | None, collect_
         return x, aux_total, new_cache
     t = x.shape[1]
     if isinstance(cache, PagedKVCache):
-        quant = cache.k_scale is not None
-        k_full, v_full = cache.k, cache.v
-        k_s, v_s = cache.k_scale, cache.v_scale
+        kv = cache
         for l_idx, lp in enumerate(layers):
             layer_cache = PagedLayerCache(
-                k_full[l_idx], v_full[l_idx], cache.block_table, cache.length,
-                k_s[l_idx] if quant else None, v_s[l_idx] if quant else None,
+                kv.k, kv.v, cache.block_table, cache.length, l_idx,
+                kv.k_scale, kv.v_scale,
             )
             x, a, kv = _layer_apply(lp, x, cfg, positions, layer_cache)
             aux_total = aux_total + a
-            k_full = k_full.at[l_idx].set(kv.k)
-            v_full = v_full.at[l_idx].set(kv.v)
-            if quant:
-                k_s = k_s.at[l_idx].set(kv.k_scale)
-                v_s = v_s.at[l_idx].set(kv.v_scale)
         return x, aux_total, PagedKVCache(
-            k_full, v_full, cache.block_table, cache.length + t, k_s, v_s
+            kv.k, kv.v, cache.block_table, cache.length + t,
+            kv.k_scale, kv.v_scale,
         )
     k_full, v_full = cache.k, cache.v
     for l_idx, lp in enumerate(layers):

@@ -263,8 +263,9 @@ class TestChunkWidthKernel:
 
     def _pool(self, seed=0, b=3, hq=4, hkv=2, d=8, bs=4, nbt=6, n=24):
         rng = np.random.RandomState(seed)
-        kp = jnp.asarray(rng.randn(n, hkv, bs, d), jnp.float32)
-        vp = jnp.asarray(rng.randn(n, hkv, bs, d), jnp.float32)
+        # stacked (L, n, ...) pools; the kernels read the last layer's pages
+        kp = jnp.asarray(rng.randn(2, n, hkv, bs, d), jnp.float32)
+        vp = jnp.asarray(rng.randn(2, n, hkv, bs, d), jnp.float32)
         bt = jnp.asarray(
             rng.permutation(n)[: b * nbt].reshape(b, nbt), jnp.int32
         )
@@ -276,8 +277,8 @@ class TestChunkWidthKernel:
     def test_kernel_matches_ref_at_chunk_widths(self, kq, q_tile):
         kp, vp, bt, lengths, rng = self._pool()
         q = jnp.asarray(rng.randn(3, 4, kq, d := 8), jnp.float32)
-        ref = paged_attention_kquery_ref(q, kp, vp, bt, lengths)
-        out = paged_attention_kquery(q, kp, vp, bt, lengths, q_tile=q_tile)
+        ref = paged_attention_kquery_ref(q, kp, vp, bt, lengths, 1)
+        out = paged_attention_kquery(q, kp, vp, bt, lengths, 1, q_tile=q_tile)
         assert out.shape == (3, 4, kq, d)
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), atol=1e-5
@@ -291,8 +292,8 @@ class TestChunkWidthKernel:
         kp, vp, bt, lengths, rng = self._pool()
         kq = _MAX_Q_ROWS // 2 + 8          # group=2 -> rows > _MAX_Q_ROWS
         q = jnp.asarray(rng.randn(3, 4, kq, 8), jnp.float32)
-        ref = paged_attention_kquery_ref(q, kp, vp, bt, lengths)
-        out = paged_attention_kquery(q, kp, vp, bt, lengths)
+        ref = paged_attention_kquery_ref(q, kp, vp, bt, lengths, 1)
+        out = paged_attention_kquery(q, kp, vp, bt, lengths, 1)
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), atol=1e-5
         )

@@ -134,33 +134,34 @@ class TestKQueryKernel:
     def _pool(self, seed=0, b=3, hq=4, hkv=2, d=8, bs=4, nb=4, n=10, kq=3):
         rng = np.random.RandomState(seed)
         q = jnp.asarray(rng.randn(b, hq, kq, d), jnp.float32)
-        kp = jnp.asarray(rng.randn(n, hkv, bs, d), jnp.float32)
-        vp = jnp.asarray(rng.randn(n, hkv, bs, d), jnp.float32)
+        # stacked (L, n, ...) pools; the kernels read the last layer's pages
+        kp = jnp.asarray(rng.randn(2, n, hkv, bs, d), jnp.float32)
+        vp = jnp.asarray(rng.randn(2, n, hkv, bs, d), jnp.float32)
         bt = jnp.asarray([[0, 1, 6, n], [2, 7, n, n], [3, 4, 5, n]], jnp.int32)
         lengths = jnp.asarray([5, 0, 9], jnp.int32)
         return q, kp, vp, bt, lengths
 
     def test_pallas_matches_ref(self):
         q, kp, vp, bt, lengths = self._pool()
-        out = paged_attention_kquery(q, kp, vp, bt, lengths)
-        ref = paged_attention_kquery_ref(q, kp, vp, bt, lengths)
+        out = paged_attention_kquery(q, kp, vp, bt, lengths, 1)
+        ref = paged_attention_kquery_ref(q, kp, vp, bt, lengths, 1)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
     def test_kq1_matches_single_query_kernel(self):
         q, kp, vp, bt, lengths = self._pool(kq=1)
-        out = paged_attention_kquery(q, kp, vp, bt, lengths)[:, :, 0]
-        ref = paged_attention(q[:, :, 0], kp, vp, bt, lengths)
+        out = paged_attention_kquery(q, kp, vp, bt, lengths, 1)[:, :, 0]
+        ref = paged_attention(q[:, :, 0], kp, vp, bt, lengths, 1)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
     def test_per_query_causal_window(self):
         """Query i must see exactly one more key than query i-1: zero out the
         extra key's value and the two queries coincide."""
         q, kp, vp, bt, lengths = self._pool()
-        ref = paged_attention_kquery_ref(q, kp, vp, bt, lengths)
+        ref = paged_attention_kquery_ref(q, kp, vp, bt, lengths, 1)
         # query 1 of slot 0 attends positions <= lengths[0] + 1 = 6; query 0
         # attends <= 5 — masking is enforced by construction in the oracle,
         # the kernel must agree even at ragged lengths incl. the empty slot
-        out = paged_attention_kquery(q, kp, vp, bt, lengths)
+        out = paged_attention_kquery(q, kp, vp, bt, lengths, 1)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
         assert np.all(np.isfinite(np.asarray(out)))
 

@@ -8,7 +8,9 @@ described inside a fixture, never while a module is imported, so every test
 worker collects the same tests and only the worker that runs this file loads
 the TPU compiler.
 """
+import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -206,14 +208,16 @@ def test_lowrank_and_bsr_matmul(one_chip, site):
 
 
 def paged_pool(pool_sharding, table_sharding, dtype, slots=SLOTS, heads=H,
-               page=PAGE, per_slot=PAGES_PER_SLOT, head_dim=HD):
-    """K and V page pools, block table and lengths."""
+               page=PAGE, per_slot=PAGES_PER_SLOT, head_dim=HD, layers=L):
+    """K and V stacked page pools, block table, lengths and layer index."""
     pages = slots * per_slot
+    pool = (layers, pages, heads, page, head_dim)
     return (
-        spec(pool_sharding, (pages, heads, page, head_dim), dtype),
-        spec(pool_sharding, (pages, heads, page, head_dim), dtype),
+        spec(pool_sharding, pool, dtype),
+        spec(pool_sharding, pool, dtype),
         spec(table_sharding, (slots, per_slot), jnp.int32),
         spec(table_sharding, (slots,), jnp.int32),
+        spec(table_sharding, (), jnp.int32),
     )
 
 
@@ -225,7 +229,7 @@ def test_paged_attention_decode(one_chip, dtype):
     if dtype == "phi3":
         dt, slots, heads = jnp.bfloat16, PHI3_SLOTS, PHI3_H
         pool = paged_pool(one_chip, one_chip, dt, PHI3_SLOTS, PHI3_KV,
-                          PHI3_PAGE, 2048 // PHI3_PAGE, 128)
+                          PHI3_PAGE, 2048 // PHI3_PAGE, 128, layers=4)
     else:
         dt, slots, heads = DTYPES[dtype], SLOTS, H
         pool = paged_pool(one_chip, one_chip, dt)
@@ -284,6 +288,7 @@ def test_attention_head_sharded(four_chips, kernel):
     Mosaic kernel with sharded operands, so the attention dispatchers must
     shard_map their kernels over the head axis."""
     heads4 = NamedSharding(four_chips, P(None, "model", None, None))
+    pool4 = NamedSharding(four_chips, P(None, None, "model", None, None))
     if kernel == "flash":
         q = spec(heads4, (SLOTS, H, 256, HD), jnp.float32)
         fn, args = ops.flash_attention, (q, q, q)
@@ -292,6 +297,49 @@ def test_attention_head_sharded(four_chips, kernel):
                  (SLOTS, H, HD), jnp.float32)
         whole = NamedSharding(four_chips, P())
         fn = ops.paged_attention
-        args = (q, *paged_pool(heads4, whole, jnp.float32))
+        args = (q, *paged_pool(pool4, whole, jnp.float32))
     with jax.set_mesh(four_chips):
         compiled_text(functools.partial(fn, interpret=False), *args)
+
+
+# the per-layer pool's (pages, Hkv, bs, D) as the trailing dims of an HLO
+# result that only moves bytes
+_POOL_MOVE = re.compile(
+    r"= \w+\[([\d,]+)\]\S* (copy|dynamic-slice|dynamic-update-slice)\("
+)
+
+
+@pytest.mark.parametrize("arch,slots,pages", [("olmo_1b", 8, 96),
+                                              ("phi3_medium_14b", 2, 32)])
+def test_paged_decode_writes_pool_in_place(one_chip, arch, slots, pages):
+    """The whole paged decode step at the benchmark's pool sizes (2 of its
+    layers, pages of 128, bfloat16, Pallas attention): no op copies or
+    slices a layer's pool, and the program's scratch stays below one
+    layer's pool. A scatter whose update window spans the head axis makes
+    the compiler transpose and copy the whole stacked pool instead."""
+    from repro.models import model as model_lib
+
+    cfg = dataclasses.replace(get_arch(arch), num_layers=2, kernel_impl="pallas",
+                              param_dtype=jnp.bfloat16)
+    bs = 128
+    cache = jax.eval_shape(lambda: model_lib.init_paged_cache(
+        cfg, slots, pages, bs, pages // slots))
+    params = jax.eval_shape(
+        lambda: model_lib.init_params(cfg, jax.random.PRNGKey(0)))
+    on_chip = lambda t: jax.tree.map(
+        lambda a: spec(one_chip, a.shape, a.dtype), t)
+    step = jax.jit(lambda p, t, c: model_lib.decode_step(p, t, c, cfg),
+                   donate_argnums=(2,))
+    with ops.interpret_mode(False):
+        compiled = step.lower(
+            on_chip(params), spec(one_chip, (slots, 1), jnp.int32),
+            on_chip(cache),
+        ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    pool = (pages, cfg.num_kv_heads, bs, cfg.head_dim)
+    moves = [m.group(0) for m in _POOL_MOVE.finditer(text)
+             if tuple(map(int, m.group(1).split(",")))[-4:] == pool]
+    assert not moves, moves
+    layer_pool_bytes = np.prod(pool) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_pool_bytes
