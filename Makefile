@@ -14,11 +14,12 @@ test-sharded:
 	XLA_FLAGS=--xla_force_host_platform_device_count=8 \
 	    $(PYTHON) -m pytest tests/test_sharded_serving.py -q
 
-# quick serving-throughput benchmark (interpret-mode kernels on CPU)
+# quick serving-throughput benchmark: the per-slot reference engine vs the
+# paged engine (interpret-mode kernels on CPU)
 bench-smoke:
 	$(PYTHON) -m benchmarks.serve_throughput --quick
 
-# latency SLO harness: paged vs slot-padded engine under Poisson arrivals
+# latency SLO harness: the paged engine under Poisson arrivals
 bench-latency:
 	$(PYTHON) -m benchmarks.serve_latency --quick
 

@@ -1,16 +1,11 @@
-"""Latency SLO harness: Poisson arrivals against paged vs slot-padded engines.
+"""Latency SLO harness: Poisson arrivals against the paged engine.
 
-Throughput (serve_throughput.py) hides the queueing story: the slot-padded
-engine reserves ``max_len`` KV positions per slot, so at a fixed memory
-budget it can only decode ``max_slots`` requests at once and everything else
-waits. The paged engine spends the SAME KV budget as a shared page pool, so
-short requests stop paying for the worst case and more of them decode
-concurrently — queue waits (and therefore tail TTFT) drop.
-
-This harness drives both engines with the SAME Poisson request trace in open
-loop (arrivals are submitted on the clock, whether or not the engine is
-keeping up), then reports per-engine p50/p99 time-to-first-token, inter-token
-latency, admitted-request rate, and SLO attainment → ``BENCH_latency.json``.
+Throughput (serve_throughput.py) hides the queueing story. This harness
+drives the paged engine — a fixed KV page pool shared by its decode slots —
+with a Poisson request trace in open loop (arrivals are submitted on the
+clock, whether or not the engine is keeping up), then reports p50/p99
+time-to-first-token, inter-token latency, admitted-request rate, and SLO
+attainment → ``BENCH_latency.json``.
 All latency math runs on ``time.monotonic()`` (the engines timestamp tokens
 on that clock); only request DEADLINES stay wall-clock, as an absolute SLO
 contract.
@@ -52,7 +47,6 @@ from repro.serving.elastic import ModelBank
 from repro.serving.engine import (
     EngineConfig,
     PagedServingEngine,
-    ServingEngine,
     decode_emitted_tokens,
 )
 
@@ -158,61 +152,35 @@ def run(
     requests: int = 32,
     rate_hz: float = 400.0,
     max_new: int = 16,
-    padded_slots: int = 4,
     max_len: int = 64,
     block_size: int = 8,
     paged_slots: int = 10,
+    num_blocks: int = 32,
     slo_ms: float = 2000.0,
     kv_dtype: str = "float32",
     seed: int = 0,
 ) -> dict:
-    """Both engines get the SAME KV memory budget (padded_slots * max_len
-    tokens) and the SAME arrival trace; the paged engine turns that budget
-    into a page pool shared by more decode slots."""
+    """The paged engine: ``paged_slots`` decode slots sharing a pool of
+    ``num_blocks`` pages, over one Poisson arrival trace."""
     cfg = get_arch("salaad_llama_60m").reduced()
     params = model_lib.init_params(cfg, jax.random.PRNGKey(seed))
     bank = ModelBank.single(cfg, params)
     trace = build_trace(requests, rate_hz, cfg.vocab_size, max_new, seed)
-    num_blocks = padded_slots * max_len // block_size
-
-    engines = {
-        "padded_slots": ServingEngine(
-            bank, EngineConfig(max_slots=padded_slots, max_len=max_len),
+    eng = PagedServingEngine(
+        bank,
+        EngineConfig(
+            max_slots=paged_slots, max_len=max_len,
+            block_size=block_size, num_blocks=num_blocks,
+            kv_dtype=kv_dtype,
         ),
-        "paged": PagedServingEngine(
-            bank,
-            EngineConfig(
-                max_slots=paged_slots, max_len=max_len,
-                block_size=block_size, num_blocks=num_blocks,
-                kv_dtype=kv_dtype,
-            ),
-        ),
-    }
-    rows = {}
-    for name, eng in engines.items():
-        warmup(eng, cfg.vocab_size, max_new)
-        rows[name], _ = drive_open_loop(eng, trace, slo_ms)
-        rows[name]["engine"] = name
-        rows[name]["kv_budget_tokens"] = padded_slots * max_len
-        rows[name]["decode_slots"] = eng.ecfg.max_slots
-        rows[name]["engine_config"] = engine_provenance(eng)
-
-    pad, pg = rows["padded_slots"], rows["paged"]
-    rows["summary"] = {
-        "equal_kv_budget_tokens": padded_slots * max_len,
-        "ttft_p99_speedup": round(
-            pad["ttft_p99_ms"] / max(pg["ttft_p99_ms"], 1e-9), 2
-        ),
-        "ttft_p50_speedup": round(
-            pad["ttft_p50_ms"] / max(pg["ttft_p50_ms"], 1e-9), 2
-        ),
-        "admitted_rate_ratio": round(
-            pg["admitted_req_per_s"] / max(pad["admitted_req_per_s"], 1e-9), 2
-        ),
-        "slo_attainment_padded": pad["slo_attainment"],
-        "slo_attainment_paged": pg["slo_attainment"],
-    }
-    return rows
+    )
+    warmup(eng, cfg.vocab_size, max_new)
+    row, _ = drive_open_loop(eng, trace, slo_ms)
+    row["engine"] = "paged"
+    row["kv_budget_tokens"] = num_blocks * block_size
+    row["decode_slots"] = eng.ecfg.max_slots
+    row["engine_config"] = engine_provenance(eng)
+    return {"paged": row}
 
 
 # ------------------------------------------------------ mixed (chunked) ---
@@ -375,13 +343,10 @@ def main(out: str = "BENCH_latency.json", mixed: bool = False, **kw):
         return rows
     rows = run(**kw)
     _merge_out(out, "engines", rows)
-    s = rows["summary"]
+    pg = rows["paged"]
     emit(
         "serve_latency", 0.0,
-        f"p99_ttft padded={rows['padded_slots']['ttft_p99_ms']}ms "
-        f"paged={rows['paged']['ttft_p99_ms']}ms "
-        f"(x{s['ttft_p99_speedup']}); slo {s['slo_attainment_padded']} -> "
-        f"{s['slo_attainment_paged']}",
+        f"p99_ttft paged={pg['ttft_p99_ms']}ms; slo {pg['slo_attainment']}",
     )
     return rows
 

@@ -22,7 +22,7 @@ import jax
 from repro.core.hpa import hpa_keep_ratio
 from repro.serving.deployed import DeployedModel
 from repro.serving.elastic import ModelBank
-from repro.serving.engine import EngineConfig, ReferenceEngine, ServingEngine
+from repro.serving.engine import EngineConfig, PagedServingEngine, ReferenceEngine
 
 from .common import bench_arch, emit, salaad_cfg, train_salaad
 
@@ -61,10 +61,11 @@ def run(
 
         engines = {
             "reference_per_slot": ReferenceEngine(ModelBank.single(cfg, dense), ecfg),
-            "batched_dense": ServingEngine(ModelBank.single(cfg, dense), ecfg),
+            "batched_dense": PagedServingEngine(ModelBank.single(cfg, dense),
+                                                ecfg),
         }
         if fmt != "dense":  # avoid key collision with the dense baseline
-            engines[f"batched_{fmt}"] = ServingEngine(
+            engines[f"batched_{fmt}"] = PagedServingEngine(
                 ModelBank.single(cfg, deployed), ecfg
             )
         row = {"keep": keep, "slr_params": rep["params_after"],

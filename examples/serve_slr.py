@@ -16,7 +16,7 @@ from repro.core.hpa import hpa_keep_ratio
 from repro.core.selection import SelectionConfig
 from repro.data.synthetic import DataConfig, SyntheticC4
 from repro.optim.adam import AdamConfig
-from repro.serving.engine import EngineConfig, ServingEngine
+from repro.serving.engine import EngineConfig, PagedServingEngine
 from repro.serving.slr_params import build_slr_linears
 from repro.train.trainer import Trainer, TrainerConfig
 
@@ -46,8 +46,8 @@ def main():
     # batched serving straight off the SLR weights (single-tier bank)
     from repro.serving.elastic import ModelBank
 
-    engine = ServingEngine(ModelBank.single(cfg, deployed),
-                           EngineConfig(max_slots=2, max_len=48))
+    engine = PagedServingEngine(ModelBank.single(cfg, deployed),
+                                EngineConfig(max_slots=2, max_len=48))
     for i in range(4):
         engine.submit([1 + i, 2, 3], max_new_tokens=6)
     t0 = time.time()

@@ -54,7 +54,6 @@ from repro.serving.engine import (
     EngineConfig,
     PagedServingEngine,
     ReferenceEngine,
-    ServingEngine,
     decode_emitted_tokens,
 )
 from repro.serving.slr_params import deployment_report
@@ -63,7 +62,6 @@ from repro.serving.telemetry import start_metrics_server
 
 ENGINES = {
     "paged": PagedServingEngine,
-    "batched": ServingEngine,
     "reference": ReferenceEngine,
     "speculative": SpeculativeEngine,
 }

@@ -1,68 +1,61 @@
-"""Batched serving engines: continuous batching over jitted programs.
+"""Serving engines: continuous batching over jitted programs.
 
-Two batched engines share one scheduler skeleton (admit → grow → one jitted
-decode per tick):
+``PagedServingEngine`` is the batched engine. Each tick it admits what fits,
+advances mid-prefill slots by one chunk, grows page allocations, and runs one
+jitted decode per active tier over the decode-phase slots.
 
-``ServingEngine`` (PR 1) — slot-padded: a fixed decode batch of ``max_slots``
-sequences sharing one contiguous KV cache in which EVERY slot reserves
-``max_len`` positions. Serving memory is governed by the longest possible
-request, not the actual workload.
-
-``PagedServingEngine`` — block-paged: KV lives in a fixed pool of
-``num_blocks`` pages of ``block_size`` tokens (``models.transformer.
-PagedKVCache``); a host-side :class:`BlockAllocator` hands pages to slots on
-demand. Requests admit whenever free pages cover their prompt plus a decode
-reservation (mid-stream admission — admission is re-tried every tick, not
-between request groups), finished or evicted slots return pages immediately,
-and when the pool runs dry a victim (longest-remaining or LRU) is evicted
-back to the queue and later resumes by re-prefilling prompt + generated
-tokens. Decode attention gathers pages through the block table (pure-JAX
-gather, or the Pallas ``kernels/paged_attention.py`` kernel under
+KV lives in a fixed pool of ``num_blocks`` pages of ``block_size`` tokens
+(``models.transformer.PagedKVCache``); a host-side :class:`BlockAllocator`
+hands pages to slots on demand. Requests admit whenever free pages cover their
+prompt plus a decode reservation (admission is re-tried every tick, so
+requests join mid-stream), finished or evicted slots return pages
+immediately, and when the pool runs dry a victim (longest-remaining or LRU)
+is evicted back to the queue and later resumes by re-prefilling prompt +
+generated tokens. Decode attention reads pages through the block table
+(pure-JAX gather, or the Pallas ``kernels/paged_attention.py`` kernel under
 ``kernel_impl='pallas'``); ``kv_dtype='int8'`` stores pages quantized via
 ``serving/kv_quant.py``.
 
-Chunked prefill (``EngineConfig.prefill_chunk``, paged engine only): prompt
-processing is split into block-aligned chunks interleaved with decode ticks.
-Each tick advances every mid-prefill slot by at most ONE chunk (a single
-jitted ``(S, chunk)`` program over the paged cache — the chunk's queries
-attend previously written pages, its KV scatters in at the slot's current
-length), so a long prompt no longer head-of-line-blocks active decoders, and
-eviction-resume re-prefills its prompt + generated history chunk-by-chunk
-instead of in one monolithic call. A slot whose next chunk cannot get pages
-simply stalls and resumes from the last completed chunk once pages free up.
-Greedy output is bitwise-identical to one-shot prefill
-(tests/test_chunked_prefill.py).
+Chunked prefill (``EngineConfig.prefill_chunk``): prompt processing is split
+into block-aligned chunks interleaved with decode ticks. Each tick advances
+every mid-prefill slot by at most ONE chunk (a single jitted ``(S, chunk)``
+program over the paged cache — the chunk's queries attend previously written
+pages, its KV scatters in at the slot's current length), so a long prompt no
+longer head-of-line-blocks active decoders, and eviction-resume re-prefills
+its prompt + generated history chunk-by-chunk instead of in one monolithic
+call. A slot whose next chunk cannot get pages simply stalls and resumes from
+the last completed chunk once pages free up. Greedy output is
+bitwise-identical to one-shot prefill (tests/test_chunked_prefill.py).
 
-Prefix caching (``EngineConfig.prefix_cache``, paged engine only): the
-:class:`BlockAllocator` ref-counts pages and a radix index over token
-prefixes (``serving/prefix_cache.py``) lets requests that share a prompt
-prefix share the PHYSICAL pages holding it — admission attaches matching
-full pages read-only and prefills only the unmatched suffix; the one
-divergent-write case (a fully-cached prompt resuming inside its final hit
-page) is privatized by a batched copy-on-write page copy
-(``kernels/page_copy.py``) before any program runs. Retired and evicted
-slots publish their pages back, so eviction-resume reattaches surviving
-pages, and index-only pages form an LRU tail reclaimed under pressure before
-any live slot is evicted. Streams are bitwise-identical to cache-off
-(tests/test_prefix_cache.py).
+Prefix caching (``EngineConfig.prefix_cache``): the :class:`BlockAllocator`
+ref-counts pages and a radix index over token prefixes
+(``serving/prefix_cache.py``) lets requests that share a prompt prefix share
+the PHYSICAL pages holding it — admission attaches matching full pages
+read-only and prefills only the unmatched suffix; the one divergent-write
+case (a fully-cached prompt resuming inside its final hit page) is privatized
+by a batched copy-on-write page copy (``kernels/page_copy.py``) before any
+program runs. Retired and evicted slots publish their pages back, so
+eviction-resume reattaches surviving pages, and index-only pages form an LRU
+tail reclaimed under pressure before any live slot is evicted. Streams are
+bitwise-identical to cache-off (tests/test_prefix_cache.py).
 
 Device programs (all shapes static, so serving never recompiles):
-  * ``prefill[bucket]`` — batched prompt forward; KV rows (slot-padded) or
-    whole prompt blocks (paged) and the first sampled token scatter into
-    place inside the same jitted call
+  * ``prefill[bucket]`` — batched prompt forward; whole prompt pages and the
+    first sampled token scatter into place inside the same jitted call
   * ``chunk`` — (params, tokens (S, chunk), counts (S,), slot_ids, cache,
     step) -> (first_tokens (S,), cache); at most ONE call per tick covering
     every mid-prefill slot (chunked mode replaces ``prefill`` entirely)
   * ``decode`` — (params, tokens (S, 1), cache, active (S,), step)
-    -> (next_tokens (S,), cache); ONE call per engine tick
+    -> (next_tokens (S,), cache); one call per active tier per tick
 
-Weights may be a raw param tree (dense) or a ``DeployedModel`` serving
-SLR (L + S) weights in factored / block-CSR form — the programs are format-
-agnostic because every linear site goes through ``models.layers.apply_weight``.
+Weights may be a raw param tree (dense) or a ``DeployedModel`` serving SLR
+(L + S) weights in any deployment format (``serving/deployed.py``) — the
+programs are format-agnostic because every linear site goes through
+``models.layers.apply_weight``.
 
-``ReferenceEngine`` preserves the seed per-slot/per-token path: it is the
-baseline that ``benchmarks/serve_throughput.py`` measures against, and the
-fallback for cache families without per-slot lengths (ssm/hybrid/encdec).
+``ReferenceEngine`` keeps the seed per-slot, per-token path: it serves the
+cache families without per-slot lengths (ssm/hybrid/encdec), and it is the
+batched engine's test oracle, sharing none of its paged code.
 
 Elastic tiers (``serving/elastic.py``): every engine is constructed from a
 ``ModelBank`` — the trained SLR weights held once, materialized as an ordered
@@ -71,7 +64,7 @@ pins a tier at ``submit`` (or takes the engine default); each tick the engine
 groups decode-phase slots by their *effective* tier and runs one jitted
 decode per active tier over the SHARED cache (block table and pages are
 tier-agnostic, so a slot can switch tiers mid-stream with no KV copy, and
-each tier's program compiles exactly once, on first use). On the paged engine
+each tier's program compiles exactly once, on first use).
 ``tier_policy='pressure'`` runs a :class:`~repro.serving.elastic.
 TierController`: under page pressure the serving tier downshifts (cheaper
 steps, sooner completions, sooner frees) BEFORE the engine resorts to
@@ -121,8 +114,8 @@ _now = time.monotonic
 
 BATCHED_FAMILIES = ("dense", "moe", "vlm")  # cache families with per-slot lengths
 
-# float payload dtypes; "int8" is also accepted but only by the paged engine,
-# which stores int8 payload pools + f32 scale pools (never a bare int8 cache)
+# float payload dtypes; "int8" is also accepted: the engine then stores int8
+# payload pools + f32 scale pools (never a bare int8 cache)
 _KV_DTYPES = {
     "float32": jnp.float32,
     "bfloat16": jnp.bfloat16,
@@ -189,20 +182,19 @@ class EngineConfig:
     eos_token: int | None = None
     seed: int = 0
     min_bucket: int = 8       # smallest prefill length bucket
-    # paged engine only:
+    # the KV page pool:
     block_size: int = 16      # tokens per KV page
     num_blocks: int | None = None   # page pool size; None = max_slots * max_len worth
     kv_dtype: str = "float32"       # float32 | bfloat16 | int8 (paged pages quantized)
     evict_policy: str = "longest_remaining"  # or "lru"
     decode_reserve: int | None = None  # decode headroom (tokens) required to admit;
     #                                    None = one block
-    prefill_chunk: int | None = None   # paged engine only: split prompt
-    #                                    processing into block-aligned chunks of
-    #                                    this many tokens, interleaved with
-    #                                    decode ticks (None = one-shot prefill;
-    #                                    must be a positive multiple of
-    #                                    block_size)
-    # prefix cache (paged engine only; serving/prefix_cache.py):
+    prefill_chunk: int | None = None   # split prompt processing into
+    #                                    block-aligned chunks of this many
+    #                                    tokens, interleaved with decode ticks
+    #                                    (None = one-shot prefill; must be a
+    #                                    positive multiple of block_size)
+    # prefix cache (serving/prefix_cache.py):
     prefix_cache: bool = False      # radix prompt index over ref-counted KV
     #                                 pages: admissions attach matching full
     #                                 pages read-only and prefill only the
@@ -212,9 +204,9 @@ class EngineConfig:
     #                                 (shorter hits prefill from scratch)
     # elastic tiers (serving/elastic.py):
     default_tier: int = 0           # bank tier used when submit(tier=None)
-    tier_policy: str = "static"     # static | pressure (paged engine only:
-    #                                 downshift the serving tier under page
-    #                                 pressure before resorting to eviction)
+    tier_policy: str = "static"     # static | pressure (downshift the
+    #                                 serving tier under page pressure before
+    #                                 resorting to eviction)
     tier_target_free: float = 0.25  # pressure setpoint: free-page fraction
     tier_gain: float = 4.0          # controller integral gain (tiers/error)
     tier_ema: float = 0.5           # smoothing of the free-fraction signal
@@ -502,686 +494,6 @@ def _kv_pool_device_bytes(cache) -> dict[str, int]:
     return per_dev
 
 
-class ServingEngine:
-    """Single-host batched slot-padded engine; the multi-pod path swaps the
-    jitted fns for their pjit'd versions (same signatures — launch/serve.py)."""
-
-    _speculative = False   # only serving.speculative.SpeculativeEngine drafts
-    _chunked = False       # only PagedServingEngine prefills chunk-by-chunk
-    _paged = False         # only PagedServingEngine has a page pool (the
-    #                        pressure tier policy needs one)
-
-    def __init__(self, model, params=None, ecfg: EngineConfig | None = None):
-        bank, ecfg = _resolve_engine_args(type(self).__name__, model, params,
-                                          ecfg)
-        self._init_common(bank, ecfg)
-        if ecfg.kv_dtype == "int8":
-            raise ValueError(
-                "int8 KV needs the paged engine (PagedServingEngine stores "
-                "quantized pages); the contiguous engine serves float caches"
-            )
-        cache = model_lib.init_cache(
-            self.cfg, ecfg.max_slots, ecfg.max_len,
-            dtype=_KV_DTYPES[ecfg.kv_dtype],
-        )
-        self.cache = cache._replace(
-            length=jnp.zeros((ecfg.max_slots,), jnp.int32)
-        )
-        self._place_cache()
-        self._decode = jax.jit(self._decode_fn, donate_argnums=(2,))
-        self._prefill = jax.jit(self._prefill_fn, donate_argnums=(4,))
-
-    @classmethod
-    def capabilities(cls) -> dict:
-        """Structured capability report (Engine protocol): which cache
-        families this engine serves, its KV layout, and feature availability
-        — the data behind ``EngineCapabilityError`` messages and the
-        ``launch/serve.py --help`` table."""
-        return {
-            "engine": cls.__name__,
-            "families": list(BATCHED_FAMILIES),
-            "kv": "contiguous",
-            "features": {
-                "kv_dtype": ["float32", "bfloat16"],
-                "continuous_batching": True,
-                "deadlines_edf": True,
-                "chunked_prefill": False,
-                "eviction_resume": False,
-                "speculative": False,
-                "elastic_tiers": True,
-                "tier_pressure_controller": False,
-                "prefix_caching": False,
-                "tensor_parallel": True,
-                "multi_tenant_adapters": True,
-            },
-        }
-
-    def _init_common(self, bank: ModelBank, ecfg: EngineConfig):
-        arch_cfg = bank.cfg
-        if arch_cfg.family not in BATCHED_FAMILIES:
-            raise ValueError(
-                f"batched engine needs a KV-cache family, got {arch_cfg.family!r};"
-                " use ReferenceEngine for ssm/hybrid/encdec"
-            )
-        if ecfg.spec_k and not self._speculative:
-            # never silently drop a requested feature: spec_k is only
-            # consumed by serving.speculative.SpeculativeEngine
-            raise EngineCapabilityError(
-                f"{type(self).__name__} does not speculate "
-                f"(spec_k={ecfg.spec_k} requested); use SpeculativeEngine. "
-                f"Engine capabilities: "
-                f"{json.dumps(self.capabilities(), sort_keys=True)}"
-            )
-        if ecfg.prefill_chunk is not None and not self._chunked:
-            raise EngineCapabilityError(
-                f"{type(self).__name__} prefills in one shot "
-                f"(prefill_chunk={ecfg.prefill_chunk} requested); chunked "
-                "prefill needs the paged engine. Engine capabilities: "
-                f"{json.dumps(self.capabilities(), sort_keys=True)}"
-            )
-        if ecfg.prefix_cache and not self._paged:
-            raise EngineCapabilityError(
-                f"{type(self).__name__} has no page pool to share "
-                "(prefix_cache=True requested); the radix prompt cache needs "
-                "the paged engine. Engine capabilities: "
-                f"{json.dumps(self.capabilities(), sort_keys=True)}"
-            )
-        if ecfg.tier_policy == "pressure" and not self._paged:
-            raise EngineCapabilityError(
-                f"{type(self).__name__} has no page pool to feel pressure "
-                "from (tier_policy='pressure' requested); the tier "
-                "controller needs the paged engine. Engine capabilities: "
-                f"{json.dumps(self.capabilities(), sort_keys=True)}"
-            )
-        # multi-tenant adapters: the bank type and the config flag must agree
-        # — neither a silently-ignored AdapterBank nor a flag with no pool
-        self._adapters: AdapterBank | None = \
-            bank if isinstance(bank, AdapterBank) else None
-        if ecfg.adapters != (self._adapters is not None):
-            raise ValueError(
-                "adapters=True needs an AdapterBank (serving.adapters) as the "
-                "engine's bank, and an AdapterBank needs adapters=True — got "
-                f"adapters={ecfg.adapters} with {type(bank).__name__}"
-            )
-        if self._adapters is not None:
-            if ecfg.mesh is not None:
-                raise ValueError(
-                    f"mesh={ecfg.mesh!r} + adapters is unsupported: the "
-                    "pooled adapter tables are indexed by scalar-prefetched "
-                    "DMA maps no axis partition can split; serve adapters "
-                    "unsharded"
-                )
-            if self._speculative:
-                raise EngineCapabilityError(
-                    "SpeculativeEngine does not serve AdapterBanks (draft + "
-                    "verify would each need a pool); use PagedServingEngine"
-                )
-            self._adapters.materialize(ecfg.max_resident_adapters)
-        self.cfg = arch_cfg
-        self.ecfg = ecfg
-        self.bank = bank
-        self._tier_params, self._default_tier = _bank_tier_state(bank, ecfg)
-        # tensor parallelism: resolve the mesh spec, then materialize ALL
-        # tiers against one sharded base (shared leaves placed once) so
-        # elastic / speculative / prefix-cached serving inherit TP for free
-        self.mesh = _resolve_serving_mesh(ecfg, arch_cfg, bank)
-        if self.mesh is not None:
-            self._tier_params = _device_put_tiers(self._tier_params, self.mesh)
-        # back-compat alias: the default tier's tree (the speculative engine
-        # re-points it at the verify target's tier)
-        self.params = self._tier_params[self._default_tier]
-        # effective tier per slot (requested tier + controller downshift),
-        # refreshed every tick; decode groups by this
-        self._slot_tier = np.zeros(ecfg.max_slots, np.int64)
-        # multi-tenant: adapter-pool row per slot (batched decode binds this
-        # map verbatim) + the adapter id each slot pinned (unpinned when the
-        # slot releases, so LRU never swaps out a streaming adapter)
-        self._slot_pool = np.zeros(ecfg.max_slots, np.int32)
-        self._slot_adapter: dict[int, int] = {}
-        self._tier_shift = 0
-        self.tier_controller: TierController | None = None
-        self._queue: list[Request] = []
-        self._active: dict[int, Request] = {}   # slot -> request
-        # slot -> tokens prefilled so far; a slot present here is MID-PREFILL
-        # (chunked paged engine only — always empty on the other engines) and
-        # does not participate in decode ticks
-        self._progress: dict[int, int] = {}
-        self._uid = 0
-        self._steps = 0
-        self._last_token = np.zeros(ecfg.max_slots, np.int64)
-        self._base_key = jax.random.PRNGKey(ecfg.seed)
-
-        # instrumentation: device calls vs (re)traces — tests assert the
-        # decode loop is one device call per step and compiles exactly once.
-        # The ``*_calls``/``*_traces`` pairs stay PLAIN INTS on purpose: the
-        # trace counters increment as a python side effect INSIDE traced
-        # functions (so they count traces only), which a registry-backed
-        # property could not express; the retrace detector reads their deltas
-        self.decode_calls = 0
-        self.decode_traces = 0
-        self.prefill_calls = 0
-        self.prefill_traces = 0
-
-        # observability: the unified metrics registry (+ optional tracer).
-        # The legacy counter attributes (evictions, tier_switches,
-        # downshift_ticks, prefix_*, cow_copies, ...) are now read-only
-        # properties over this registry — one metrics substrate everywhere
-        self.metrics = (EngineTelemetry if ecfg.telemetry
-                        else NullTelemetry)(type(self).__name__)
-        if self._adapters is not None:
-            self.metrics.set_resident_adapters(len(self._adapters.resident))
-        for tier in bank:
-            if tier.slr_kernel is not None:
-                self.metrics.set_slr_kernel(tier.index, ecfg.max_slots,
-                                            tier.slr_kernel)
-        self.tracer: RequestTracer | None = None
-        if ecfg.trace:
-            self.start_trace()
-
-    # ----------------------------------------------------- observability ---
-
-    def start_trace(self, tracer: RequestTracer | None = None) -> RequestTracer:
-        """Attach a per-request span tracer (serving/trace.py); subsequent
-        activity records slot-track spans. Returns the tracer
-        (``tracer.save_chrome(path)`` / ``save_jsonl(path)``)."""
-        self.tracer = tracer if tracer is not None \
-            else RequestTracer(type(self).__name__)
-        return self.tracer
-
-    # Migrated ad-hoc counters: read-only views over the metrics registry
-    # (the registry is the single writer — see the hooks at the old
-    # increment sites). With telemetry=False these all read 0.
-
-    @property
-    def evictions(self) -> int:
-        return int(self.metrics.counter_value(self.metrics.evictions))
-
-    @property
-    def tier_switches(self) -> int:
-        return int(self.metrics.counter_value(self.metrics.tier_switches))
-
-    @property
-    def downshift_ticks(self) -> int:
-        return int(self.metrics.counter_value(self.metrics.downshift_ticks))
-
-    def stats_snapshot(self) -> dict:
-        """Host-side serving stats: scheduler/jit counters plus the full
-        metrics-registry snapshot. ``launch/serve.py`` derives its summary
-        from this, and the Prometheus exporter serves the same registry."""
-        return {
-            "engine": type(self).__name__,
-            "steps": self._steps,
-            "decode_calls": self.decode_calls,
-            "decode_traces": self.decode_traces,
-            "prefill_calls": self.prefill_calls,
-            "prefill_traces": self.prefill_traces,
-            "jit_retraces": self.metrics.retraces(),
-            "slowest_step": self.metrics.slowest_step(),
-            "metrics": self.metrics.snapshot(),
-        }
-
-    def lower_decode(self, tier: int = 0) -> jax.stages.Lowered:
-        """Tier ``tier``'s decode program lowered at the engine's live
-        argument shapes, for inspection (compiled text, memory analysis)."""
-        s = self.ecfg.max_slots
-        with self._mesh_scope():
-            return self._decode.lower(
-                self._call_params(tier, self._slot_pool),
-                jnp.zeros((s, 1), jnp.int32), self._device_cache(),
-                jnp.zeros((s,), bool), jnp.asarray(self._steps, jnp.int32),
-            )
-
-    # ------------------------------------------------------------ intake ---
-
-    def submit(self, prompt: list[int], max_new_tokens: int = 16,
-               deadline: float | None = None,
-               tier: int | None = None,
-               submitted_at: float | None = None,
-               adapter: int | None = None) -> int:
-        """Enqueue a request. ``submitted_at`` (monotonic clock) lets open-
-        loop harnesses backdate the submission to the SCHEDULED arrival, so
-        TTFT/queue-wait metrics share one basis however the driver batches
-        its submits; None = now. ``adapter`` picks a registered AdapterBank
-        adapter (None = the bank's default; rejected on plain-bank engines)."""
-        try:
-            self._validate(prompt, max_new_tokens)
-            tier_r = self._resolve_tier(tier)
-            adapter_r = self._resolve_adapter(adapter)
-        except RequestRejected:
-            self.metrics.on_reject()
-            raise
-        self._uid += 1
-        self._queue.append(
-            Request(self._uid, list(prompt), max_new_tokens,
-                    submitted_at=_now() if submitted_at is None
-                    else submitted_at,
-                    deadline=deadline, tier=tier_r, adapter=adapter_r)
-        )
-        self.metrics.on_submit()
-        return self._uid
-
-    def _validate(self, prompt: list[int], max_new_tokens: int):
-        _validate_request(prompt, max_new_tokens, self.ecfg.max_len)
-
-    def _resolve_tier(self, tier: int | None) -> int:
-        return _resolve_request_tier(self.bank, self._default_tier, tier)
-
-    def _resolve_adapter(self, adapter: int | None) -> int | None:
-        """Validated adapter id (None = the bank's default). Submit-time
-        errors are ``RequestRejected``, like tiers; an adapter unregistered
-        AFTER submit is caught at admission instead (the request finishes
-        rejected — the graceful error path)."""
-        if self._adapters is None:
-            if adapter is not None:
-                raise RequestRejected(
-                    f"adapter={adapter} needs an AdapterBank engine "
-                    "(serving.adapters); this engine serves a plain ModelBank"
-                )
-            return None
-        aid = self._adapters.default_adapter if adapter is None else adapter
-        if aid not in self._adapters.registry:
-            raise RequestRejected(f"unknown adapter id {aid}")
-        return aid
-
-    # ------------------------------------------------------------- tiers ---
-
-    def _effective_tier(self, req: Request) -> int:
-        """Requested tier plus the controller's downshift, clamped to the
-        cheap end of the bank (downshift only ever moves toward smaller
-        capacities; it never upgrades a request past what it asked for)."""
-        return min(req.tier + self._tier_shift, len(self._tier_params) - 1)
-
-    def _update_tier_shift(self):
-        """Hook: the paged engine integrates page pressure here."""
-
-    def _refresh_slot_tiers(self):
-        """Recompute each active slot's effective tier. A change is pure
-        host-side bookkeeping — the KV cache is tier-agnostic (no copy) and
-        every tier's program is already compiled after its first use, so a
-        mid-stream switch costs nothing on device."""
-        for slot, req in self._active.items():
-            eff = self._effective_tier(req)
-            if eff != self._slot_tier[slot]:
-                self.metrics.inc(self.metrics.tier_switches)
-                if self.tracer is not None:
-                    self.tracer.instant(slot, "tier_switch", uid=req.uid,
-                                        frm=int(self._slot_tier[slot]), to=eff)
-                self._slot_tier[slot] = eff
-
-    def _tier_groups(self, slots) -> list[tuple]:
-        """Active slots grouped by program key (ascending): the effective
-        tier, widened — grouped adapter mode only — to ``(tier, pool row)``
-        so every call serves ONE adapter through the single-tenant ops.
-        Batched adapter mode keeps the plain tier key: one multi-adapter
-        program covers mixed-adapter slots."""
-        grouped = self._adapters is not None \
-            and self._adapters.mode == "grouped"
-        groups: dict = {}
-        for s in slots:
-            key = (int(self._slot_tier[s]), int(self._slot_pool[s])) \
-                if grouped else int(self._slot_tier[s])
-            groups.setdefault(key, []).append(s)
-        return sorted(groups.items())
-
-    # ---------------------------------------------------------- adapters ---
-
-    def _adapter_admit(self, req: Request, done: list[Request]):
-        """Acquire + pin ``req``'s adapter into the device pool. Returns
-        ``("ok", row)`` (``row`` is None on plain banks); ``("busy", None)``
-        when every pool row is pinned by a streaming slot — keep the request
-        queued and retry next tick; ``("gone", None)`` when the adapter was
-        unregistered after submit — the request finishes rejected."""
-        if self._adapters is None:
-            return "ok", None
-        try:
-            row, swapped = self._adapters.acquire(req.adapter)
-        except AdapterError:
-            req.done = True
-            req.finished_at = _now()
-            self.metrics.on_reject()
-            done.append(req)
-            return "gone", None
-        if row is None:
-            return "busy", None
-        if swapped:
-            self.metrics.inc(self.metrics.adapter_swaps)
-        self._adapters.pin(req.adapter)
-        self.metrics.set_resident_adapters(len(self._adapters.resident))
-        return "ok", row
-
-    def _call_params(self, key, rows=None):
-        """The parameter tree for ONE program call: the tier's tree on plain
-        banks; on AdapterBanks a fresh ``bind`` of the live pool — a grouped
-        key carries its pool row (one adapter per call, scalar sel), batched
-        calls bind the ``rows`` map (slot- or group-indexed, matching the
-        program's row convention). Binds are data-only: same treedef and
-        shapes every call, so programs never retrace across adapters."""
-        if self._adapters is None:
-            return self._tier_params[key]
-        if isinstance(key, tuple):            # grouped: (tier, pool row)
-            return self._adapters.bind(key[1])
-        return self._adapters.bind(np.asarray(rows, np.int32))
-
-    def _order_queue(self):
-        """Earliest-deadline-first admission order, shared by BOTH batched
-        engines (the slot-padded engine used to pop FIFO and ignore
-        deadlines). Tiebreaks are stable: among equal deadlines (or no
-        deadlines at all) evicted/resumed requests go first — they already
-        spent pool time, and finishing them releases memory soonest — then
-        FIFO by uid. EDF stays primary: an evicted request with a LATER
-        deadline does not jump an urgent fresh one (the old paged queue-head
-        insert did exactly that, and the pre-admit re-sort then dropped it)."""
-        self._queue.sort(
-            key=lambda r: (
-                r.deadline is None, r.deadline or 0.0, -r.evictions, r.uid
-            )
-        )
-
-    @property
-    def has_work(self) -> bool:
-        return bool(self._queue or self._active)
-
-    @property
-    def queued(self) -> int:
-        return len(self._queue)
-
-    # ----------------------------------------------------- device programs ---
-
-    def _sample(self, logits: jax.Array, step: jax.Array, salt: int,
-                slots: jax.Array | None = None) -> jax.Array:
-        """Greedy or temperature sampling, on device. logits: (S, vocab).
-
-        ``salt`` separates the prefill / decode / draft / verify streams — all
-        can sample within the same engine tick and must not share gumbel
-        noise. Each row additionally folds its slot id (``slots``; default row
-        index) into the key, so slots carry independent streams: eviction /
-        re-prefill resume and draft-vs-verify sampling never correlate across
-        slots. The greedy path is untouched.
-        """
-        if self.ecfg.greedy or self.ecfg.temperature <= 0:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        key = jax.random.fold_in(jax.random.fold_in(self._base_key, step), salt)
-        if slots is None:
-            slots = jnp.arange(logits.shape[0])
-        keys = jax.vmap(lambda s: jax.random.fold_in(key, s))(slots)
-        g = jax.vmap(lambda k: jax.random.gumbel(k, logits.shape[-1:]))(keys)
-        return jnp.argmax(logits / self.ecfg.temperature + g, axis=-1).astype(jnp.int32)
-
-    def _decode_fn(self, params, tokens, cache, active, step):
-        self.decode_traces += 1  # python side effect: counts traces only
-        logits, new_cache = model_lib.decode_step(params, tokens, cache, self.cfg)
-        # only active slots advance their valid prefix; inactive slots wrote a
-        # junk row at their frozen position — the next real token overwrites it
-        # (paged: inactive slots map to unmapped pages, the write dropped)
-        new_len = jnp.where(active, new_cache.length, cache.length)
-        next_tok = self._sample(logits[:, -1], step, salt=0)
-        return next_tok, new_cache._replace(length=new_len)
-
-    def _prefill_fn(self, params, tokens, lengths, slot_ids, cache, step):
-        self.prefill_traces += 1
-        logits, pcache = model_lib.prefill(
-            params, {"tokens": tokens}, self.cfg, max_len=self.ecfg.max_len,
-            cache_dtype=cache.k.dtype,
-        )
-        # scatter the prefilled KV rows / lengths into the target slots;
-        # padded rows carry slot_id == max_slots and drop out of bounds
-        k = cache.k.at[:, slot_ids].set(pcache.k, mode="drop")
-        v = cache.v.at[:, slot_ids].set(pcache.v, mode="drop")
-        new_len = cache.length.at[slot_ids].set(lengths, mode="drop")
-        # the logits at the last prompt position yield the first generated token
-        last = jnp.take_along_axis(logits, (lengths - 1)[:, None, None], axis=1)
-        first_tok = self._sample(last[:, 0], step, salt=1, slots=slot_ids)
-        return first_tok, cache._replace(k=k, v=v, length=new_len)
-
-    # ------------------------------------------------------------- steps ---
-
-    def _bucket(self, n: int) -> int:
-        b = self.ecfg.min_bucket
-        while b < n:
-            b *= 2
-        return min(b, self.ecfg.max_len)
-
-    def _admit(self, free: list[int], done: list[Request], step: int):
-        """Batch all admissible queued requests through one prefill call PER
-        EFFECTIVE TIER (earliest deadline first — see ``_order_queue``; a
-        single-tier bank degenerates to exactly the old one-call admit)."""
-        take = min(len(free), len(self._queue))
-        if not take:
-            return
-        self._order_queue()
-        reqs = [self._queue.pop(0) for _ in range(take)]
-        s = self.ecfg.max_slots
-        now = _now()
-        admitted: list[tuple[int, Request]] = []
-        requeue: list[Request] = []
-        for req in reqs:
-            astat, arow = self._adapter_admit(req, done)
-            if astat == "gone":
-                continue
-            if astat == "busy":     # every pool row pinned: retry next tick
-                requeue.append(req)
-                continue
-            slot = free.pop()
-            self.metrics.on_admit(req, slot, now,
-                                  prefill_tokens=len(req.prompt))
-            req.admitted_at = now
-            self._active[slot] = req
-            self._slot_tier[slot] = self._effective_tier(req)
-            if arow is not None:
-                self._slot_pool[slot] = arow
-                self._slot_adapter[slot] = req.adapter
-            if self.tracer is not None:
-                self.tracer.request_begin(slot, req.uid, t=now, tier=req.tier)
-                self.tracer.begin_span(slot, "prefill", t=now,
-                                       tokens=len(req.prompt))
-            admitted.append((slot, req))
-        self._queue[:0] = requeue
-        for key, slots in self._tier_groups(slot for slot, _ in admitted):
-            group = [(slot, self._active[slot]) for slot in slots]
-            bucket = self._bucket(max(len(r.prompt) for _, r in group))
-            tokens = np.zeros((s, bucket), np.int32)
-            lengths = np.ones((s,), np.int32)     # padded rows: 1 valid token
-            slot_ids = np.full((s,), s, np.int32)  # out-of-range => dropped
-            rows = np.zeros((s,), np.int32)        # GROUP-indexed pool rows
-            for i, (slot, req) in enumerate(group):
-                tokens[i, : len(req.prompt)] = req.prompt
-                lengths[i] = len(req.prompt)
-                slot_ids[i] = slot
-                rows[i] = self._slot_pool[slot]
-            with self.metrics.measure_program(
-                f"prefill[{bucket}]", key, traces=lambda: self.prefill_traces,
-                rows=len(group), tokens=int(lengths[: len(group)].sum()),
-                positions=tokens.size,
-            ):
-                first, self.cache = self._prefill(
-                    self._call_params(key, rows), jnp.asarray(tokens),
-                    jnp.asarray(lengths), jnp.asarray(slot_ids), self.cache,
-                    jnp.asarray(step, jnp.int32),
-                )
-                self.prefill_calls += 1
-                with self.metrics.span("serve.fetch"):
-                    firsts = np.asarray(first)   # one fetch per tier group
-            with self.metrics.span("serve.record"):
-                for i, (slot, req) in enumerate(group):
-                    req.prefill_emitted += 1
-                    self._record(slot, req, int(firsts[i]), free, done)
-
-    def _record(self, slot: int, req: Request, tok: int, free, done):
-        now = _now()
-        req.out_tokens.append(tok)
-        req.token_times.append(now)
-        first = req.first_token_at == 0.0
-        if first:
-            req.first_token_at = now
-        # the ONE emission point: serve_tokens_total{kind="emitted"} counts
-        # each generated token exactly once, however many times eviction
-        # re-prefills its context (re-work lands in kind="prefill_compute")
-        self.metrics.on_token(req, now, first)
-        if self._adapters is not None:
-            self.metrics.inc(self.metrics.adapter_tokens, 1, str(req.adapter))
-        tr = self.tracer
-        if tr is not None and tr.has_open(slot, "prefill"):
-            # prefill (or resume re-prefill) just yielded its token: close
-            # the prefill span and open the decode envelope
-            tr.end_span(slot, "prefill", t=now)
-            if first:
-                tr.instant(slot, "first_token", t=now, uid=req.uid)
-            tr.begin_span(slot, "decode", t=now, uid=req.uid)
-        self._last_token[slot] = tok
-        if len(req.out_tokens) >= req.max_new_tokens or (
-            self.ecfg.eos_token is not None and tok == self.ecfg.eos_token
-        ):
-            req.done = True
-            req.finished_at = now
-            self.metrics.on_finish()
-            if tr is not None:
-                if tr.has_open(slot, "decode"):
-                    tr.end_span(slot, "decode", t=now)
-                tr.request_end(slot, req.uid, t=now,
-                               tokens=len(req.out_tokens),
-                               evictions=req.evictions)
-            done.append(req)
-            self._retire(slot, req)
-            del self._active[slot]
-            free.append(slot)
-            self._release(slot)
-
-    def _retire(self, slot: int, req: Request):
-        """Hook: the prefix-caching paged engine publishes the slot's full
-        pages into the radix index here (finish AND eviction), before
-        ``_release`` returns whatever it kept to the pool."""
-
-    def _release(self, slot: int):
-        """Hook extended by the paged engine (it returns the slot's pages);
-        the base unpins the slot's adapter so LRU residency can swap it out
-        once no slot streams with it."""
-        if self._adapters is not None:
-            aid = self._slot_adapter.pop(slot, None)
-            if aid is not None:
-                self._adapters.unpin(aid)
-
-    def _pre_decode(self, free: list[int], done: list[Request]):
-        """Hook: the paged engine grows page allocations / evicts here."""
-
-    def _prefill_progress(self, free: list[int], done: list[Request],
-                          step: int):
-        """Hook: the chunked paged engine advances mid-prefill slots by one
-        chunk here (at most one jitted chunk program per tick)."""
-
-    def _device_cache(self):
-        """Hook: the paged engine pushes host block-table updates here."""
-        return self.cache
-
-    def _place_cache(self):
-        """Shard the KV cache over the mesh (head axis over 'model'; block
-        tables / lengths replicated) and record the per-device pool bytes
-        gauge. No-op without a mesh — single-device arrays stay as-is."""
-        if self.mesh is not None:
-            self.cache = jax.device_put(
-                self.cache, self.mesh.cache_shardings(self.cache)
-            )
-        if self.metrics.enabled:
-            self.metrics.set_pool_device_bytes(_kv_pool_device_bytes(self.cache))
-
-    def _mesh_scope(self):
-        """The mesh context for one tick: activates the ServingMesh so
-        ``parallel.sharding.constrain`` and the shard_map-wrapped paged
-        kernels see it at trace time; a null context when unsharded."""
-        return self.mesh if self.mesh is not None else contextlib.nullcontext()
-
-    def step(self) -> list[Request]:
-        """ONE engine tick: admit whatever fits, refresh effective tiers
-        (pressure controller first — downshift precedes any eviction),
-        advance mid-prefill slots by one chunk, then one jitted decode step
-        per active tier over the decode-phase slots. Returns requests that
-        finished this tick."""
-        with self.metrics.measure_tick(self._steps + 1, len(self._active),
-                                       len(self._queue)):
-            with self._mesh_scope():
-                done = self._step_inner()
-            self._update_gauges()
-        return done
-
-    def _step_inner(self) -> list[Request]:
-        done: list[Request] = []
-        s = self.ecfg.max_slots
-        self._steps += 1
-        free = [x for x in range(s) if x not in self._active]
-        with self.metrics.span("serve.admit"):
-            self._admit(free, done, self._steps)
-        if not self._active:
-            return done
-        self._update_tier_shift()
-        self._refresh_slot_tiers()
-        self._prefill_progress(free, done, self._steps)
-        self._pre_decode(free, done)
-        active = np.zeros((s,), bool)
-        for slot in self._active:
-            if slot not in self._progress:   # mid-prefill slots don't decode
-                active[slot] = True
-        if active.any():
-            self._decode_tick(active, free, done)
-        return done
-
-    def _update_gauges(self):
-        """End-of-tick pool/queue gauges — host counters only, no device
-        reads (the paged engine adds page-pool occupancy). Short-circuits
-        when telemetry is off so gauge ARGUMENTS cost nothing either."""
-        if not self.metrics.enabled:
-            return
-        self.metrics.set_pool(queue=len(self._queue),
-                              active=len(self._active),
-                              shift=self._tier_shift)
-        if self._adapters is not None:
-            self.metrics.set_resident_adapters(len(self._adapters.resident))
-
-    def _decode_tick(self, active: np.ndarray, free: list[int],
-                     done: list[Request]):
-        """Device portion of a tick (hook: the speculative engine replaces
-        this with its draft + k-wide verify program): ONE jitted decode per
-        active tier, every call masked to its tier's slots over the shared
-        cache. A single-tier bank degenerates to exactly one call per tick;
-        each tier's program compiles once, on first use, so a slot switching
-        tiers mid-stream never triggers a retrace."""
-        s = self.ecfg.max_slots
-        tokens = np.zeros((s, 1), np.int32)
-        decode_slots = [int(x) for x in np.nonzero(active)[0]]
-        for slot in decode_slots:
-            tokens[slot, 0] = self._last_token[slot]
-        tok_dev = jnp.asarray(tokens)
-        step_dev = jnp.asarray(self._steps, jnp.int32)
-        out = np.zeros((s,), np.int64)
-        for key, slots in self._tier_groups(decode_slots):
-            mask = np.zeros((s,), bool)
-            mask[slots] = True
-            with self.metrics.measure_program(
-                "decode", key, traces=lambda: self.decode_traces,
-                rows=len(slots),
-            ):
-                nxt, self.cache = self._decode(
-                    self._call_params(key, self._slot_pool), tok_dev,
-                    self._device_cache(), jnp.asarray(mask), step_dev,
-                )
-                self.decode_calls += 1
-                with self.metrics.span("serve.fetch"):
-                    toks = np.asarray(nxt)   # one host sync per active tier
-            out[slots] = toks[slots]
-        with self.metrics.span("serve.record"):
-            for slot, req in list(self._active.items()):
-                if slot in self._progress:
-                    continue
-                self._record(slot, req, int(out[slot]), free, done)
-
-    def run(self, max_steps: int = 10_000) -> list[Request]:
-        """Drive everything to completion (batch mode)."""
-        done: list[Request] = []
-        steps = 0
-        while self.has_work and steps < max_steps:
-            steps += 1
-            done.extend(self.step())
-        return done
-
-
 # ------------------------------------------------------------------ paged ---
 
 
@@ -1288,15 +600,16 @@ class BlockAllocator:
         self._free.extend(pages)
 
 
-class PagedServingEngine(ServingEngine):
-    """Continuously-batched engine over a block-paged KV cache.
+class PagedServingEngine:
+    """The batched engine: continuous batching over a block-paged KV cache.
 
     Serving memory is ``num_blocks * block_size`` tokens of KV shared by all
-    slots — short requests no longer pay for ``max_len``. Admission happens
-    whenever a slot AND enough free pages exist (checked every tick); decode
-    allocations grow one page at a time, and pool exhaustion evicts a victim
-    back to the queue (it resumes later by re-prefilling prompt + generated
-    tokens, which under greedy decoding reproduces the same continuation).
+    slots — a request holds the pages it fills, not ``max_len``. Admission
+    happens whenever a slot AND enough free pages exist (checked every tick);
+    decode allocations grow one page at a time, and pool exhaustion evicts a
+    victim back to the queue (it resumes later by re-prefilling prompt +
+    generated tokens, which under greedy decoding reproduces the same
+    continuation).
 
     With ``prefill_chunk`` set, admission only reserves pages for the FIRST
     chunk and marks the slot mid-prefill; each tick then advances every
@@ -1315,29 +628,134 @@ class PagedServingEngine(ServingEngine):
     reclaims under pressure.
     """
 
-    _chunked = True
-    _paged = True
+    _speculative = False   # only serving.speculative.SpeculativeEngine drafts
+    _write_window = 1      # KV positions one decode tick writes per slot (the
+    #                        speculative engine widens it to its k drafts)
 
     def __init__(self, model, params=None, ecfg: EngineConfig | None = None):
         bank, ecfg = _resolve_engine_args(type(self).__name__, model, params,
                                           ecfg)
-        self._init_common(bank, ecfg)
-        arch_cfg = self.cfg
+        arch_cfg = bank.cfg
+        if arch_cfg.family not in BATCHED_FAMILIES:
+            raise ValueError(
+                f"{type(self).__name__} needs a KV-cache family, got "
+                f"{arch_cfg.family!r}; use ReferenceEngine for "
+                "ssm/hybrid/encdec"
+            )
+        if ecfg.spec_k and not self._speculative:
+            # never silently drop a requested feature: spec_k is only
+            # consumed by serving.speculative.SpeculativeEngine
+            raise EngineCapabilityError(
+                f"{type(self).__name__} does not speculate "
+                f"(spec_k={ecfg.spec_k} requested); use SpeculativeEngine. "
+                f"Engine capabilities: "
+                f"{json.dumps(self.capabilities(), sort_keys=True)}"
+            )
+        # multi-tenant adapters: the bank type and the config flag must agree
+        # — neither a silently-ignored AdapterBank nor a flag with no pool
+        self._adapters: AdapterBank | None = \
+            bank if isinstance(bank, AdapterBank) else None
+        if ecfg.adapters != (self._adapters is not None):
+            raise ValueError(
+                "adapters=True needs an AdapterBank (serving.adapters) as the "
+                "engine's bank, and an AdapterBank needs adapters=True — got "
+                f"adapters={ecfg.adapters} with {type(bank).__name__}"
+            )
+        if self._adapters is not None:
+            if ecfg.mesh is not None:
+                raise ValueError(
+                    f"mesh={ecfg.mesh!r} + adapters is unsupported: the "
+                    "pooled adapter tables are indexed by scalar-prefetched "
+                    "DMA maps no axis partition can split; serve adapters "
+                    "unsharded"
+                )
+            if self._speculative:
+                raise EngineCapabilityError(
+                    "SpeculativeEngine does not serve AdapterBanks (draft + "
+                    "verify would each need a pool); use PagedServingEngine"
+                )
+            self._adapters.materialize(ecfg.max_resident_adapters)
+        self.cfg = arch_cfg
+        self.ecfg = ecfg
+        self.bank = bank
+        self._tier_params, self._default_tier = _bank_tier_state(bank, ecfg)
+        # tensor parallelism: resolve the mesh spec, then materialize ALL
+        # tiers against one sharded base (shared leaves placed once) so
+        # elastic / speculative / prefix-cached serving inherit TP for free
+        self.mesh = _resolve_serving_mesh(ecfg, arch_cfg, bank)
+        if self.mesh is not None:
+            self._tier_params = _device_put_tiers(self._tier_params, self.mesh)
+        # back-compat alias: the default tier's tree (the speculative engine
+        # re-points it at the verify target's tier)
+        self.params = self._tier_params[self._default_tier]
+        # effective tier per slot (requested tier + controller downshift),
+        # refreshed every tick; decode groups by this
+        self._slot_tier = np.zeros(ecfg.max_slots, np.int64)
+        # multi-tenant: adapter-pool row per slot (batched decode binds this
+        # map verbatim) + the adapter id each slot pinned (unpinned when the
+        # slot releases, so LRU never swaps out a streaming adapter)
+        self._slot_pool = np.zeros(ecfg.max_slots, np.int32)
+        self._slot_adapter: dict[int, int] = {}
+        self._tier_shift = 0
+        self.tier_controller: TierController | None = None
+        if ecfg.tier_policy == "pressure":
+            self.tier_controller = TierController(
+                len(bank),
+                TierControllerConfig(
+                    target_free_frac=ecfg.tier_target_free,
+                    gain=ecfg.tier_gain, ema=ecfg.tier_ema,
+                ),
+            )
+        self._queue: list[Request] = []
+        self._active: dict[int, Request] = {}   # slot -> request
+        # slot -> tokens prefilled so far; a slot present here is MID-PREFILL
+        # (chunked prefill only) and does not participate in decode ticks
+        self._progress: dict[int, int] = {}
+        self._uid = 0
+        self._steps = 0
+        self._last_token = np.zeros(ecfg.max_slots, np.int64)
+        self._base_key = jax.random.PRNGKey(ecfg.seed)
+
+        # instrumentation: device calls vs (re)traces — tests assert the
+        # decode loop is one device call per step and compiles exactly once.
+        # The ``*_calls``/``*_traces`` pairs stay PLAIN INTS on purpose: the
+        # trace counters increment as a python side effect INSIDE traced
+        # functions (so they count traces only), which a registry-backed
+        # property could not express; the retrace detector reads their deltas
+        self.decode_calls = 0
+        self.decode_traces = 0
+        self.prefill_calls = 0
+        self.prefill_traces = 0
+        self.chunk_calls = 0
+        self.chunk_traces = 0
+
+        # observability: the unified metrics registry (+ optional tracer).
+        # The legacy counter attributes (evictions, tier_switches,
+        # downshift_ticks, prefix_*, cow_copies, ...) are now read-only
+        # properties over this registry — one metrics substrate everywhere
+        self.metrics = (EngineTelemetry if ecfg.telemetry
+                        else NullTelemetry)(type(self).__name__)
+        if self._adapters is not None:
+            self.metrics.set_resident_adapters(len(self._adapters.resident))
+        for tier in bank:
+            if tier.slr_kernel is not None:
+                self.metrics.set_slr_kernel(tier.index, ecfg.max_slots,
+                                            tier.slr_kernel)
+        self.tracer: RequestTracer | None = None
+        if ecfg.trace:
+            self.start_trace()
+
+        # the page pool: block tables are ``max_len`` rounded up to whole pages
         bs = ecfg.block_size
-        assert bs >= 1
         self._bs = bs
         self._max_len = -(-ecfg.max_len // bs) * bs
         self._nb_slot = self._max_len // bs          # block-table width
         self.num_blocks = ecfg.num_blocks or ecfg.max_slots * self._nb_slot
         self.allocator = BlockAllocator(self.num_blocks)
         self._quantized = ecfg.kv_dtype == "int8"
+        # EngineConfig checks the chunk is a positive multiple of block_size
         self._chunk = ecfg.prefill_chunk
         if self._chunk is not None:
-            if self._chunk < 1 or self._chunk % bs:
-                raise ValueError(
-                    f"prefill_chunk={self._chunk} must be a positive multiple "
-                    f"of block_size={bs} (chunks scatter whole pages)"
-                )
             self._chunk = min(self._chunk, self._max_len)
         self.cache = model_lib.init_paged_cache(
             arch_cfg, ecfg.max_slots, self.num_blocks, bs, self._nb_slot,
@@ -1351,8 +769,6 @@ class PagedServingEngine(ServingEngine):
         self._table_dirty = False
         self._pages: dict[int, list[int]] = {}       # slot -> page ids
         self._ptarget: dict[int, int] = {}           # slot -> prefill target len
-        self.chunk_calls = 0
-        self.chunk_traces = 0
         # prefix sharing (serving/prefix_cache.py): radix index over prompt
         # prefixes at page granularity + the CoW copy program. Multi-tenant:
         # cached KV depends on the adapter's weights, so pages must never
@@ -1368,14 +784,6 @@ class PagedServingEngine(ServingEngine):
         # runs, and junk rows written meanwhile must not land in pages the
         # slot attached read-only
         self._len_reset: dict[int, int] = {}
-        if ecfg.tier_policy == "pressure":
-            self.tier_controller = TierController(
-                len(self.bank),
-                TierControllerConfig(
-                    target_free_frac=ecfg.tier_target_free,
-                    gain=ecfg.tier_gain, ema=ecfg.tier_ema,
-                ),
-            )
         self._place_cache()
         self._decode = jax.jit(self._decode_fn, donate_argnums=(2,))
         self._prefill = jax.jit(self._prefill_fn, donate_argnums=(5,))
@@ -1390,16 +798,54 @@ class PagedServingEngine(ServingEngine):
 
     @classmethod
     def capabilities(cls) -> dict:
-        caps = ServingEngine.capabilities.__func__(cls)
-        caps["kv"] = "paged"
-        caps["features"].update(
-            kv_dtype=["float32", "bfloat16", "int8"],
-            chunked_prefill=True,
-            eviction_resume=True,
-            tier_pressure_controller=True,
-            prefix_caching=True,
-        )
-        return caps
+        """Structured capability report (Engine protocol): which cache
+        families this engine serves, its KV layout, and feature availability
+        — the data behind ``EngineCapabilityError`` messages and the
+        ``launch/serve.py --help`` table."""
+        return {
+            "engine": cls.__name__,
+            "families": list(BATCHED_FAMILIES),
+            "kv": "paged",
+            "features": {
+                "kv_dtype": ["float32", "bfloat16", "int8"],
+                "continuous_batching": True,
+                "deadlines_edf": True,
+                "chunked_prefill": True,
+                "eviction_resume": True,
+                "speculative": False,
+                "elastic_tiers": True,
+                "tier_pressure_controller": True,
+                "prefix_caching": True,
+                "tensor_parallel": True,
+                "multi_tenant_adapters": True,
+            },
+        }
+
+    # ----------------------------------------------------- observability ---
+
+    def start_trace(self, tracer: RequestTracer | None = None) -> RequestTracer:
+        """Attach a per-request span tracer (serving/trace.py); subsequent
+        activity records slot-track spans. Returns the tracer
+        (``tracer.save_chrome(path)`` / ``save_jsonl(path)``)."""
+        self.tracer = tracer if tracer is not None \
+            else RequestTracer(type(self).__name__)
+        return self.tracer
+
+    # Migrated ad-hoc counters: read-only views over the metrics registry
+    # (the registry is the single writer — see the hooks at the old
+    # increment sites). With telemetry=False these all read 0.
+
+    @property
+    def evictions(self) -> int:
+        return int(self.metrics.counter_value(self.metrics.evictions))
+
+    @property
+    def tier_switches(self) -> int:
+        return int(self.metrics.counter_value(self.metrics.tier_switches))
+
+    @property
+    def downshift_ticks(self) -> int:
+        return int(self.metrics.counter_value(self.metrics.downshift_ticks))
 
     # Prefix-cache counters: registry-backed read-only views (the hooks in
     # ``_admit`` are the single writers). With telemetry=False these read 0.
@@ -1430,6 +876,205 @@ class PagedServingEngine(ServingEngine):
         return int(self.metrics.counter_value(self.metrics.prefix,
                                               "reattached_pages"))
 
+    def stats_snapshot(self) -> dict:
+        """Host-side serving stats: scheduler/jit counters plus the full
+        metrics-registry snapshot. ``launch/serve.py`` derives its summary
+        from this, and the Prometheus exporter serves the same registry."""
+        return {
+            "engine": type(self).__name__,
+            "steps": self._steps,
+            "decode_calls": self.decode_calls,
+            "decode_traces": self.decode_traces,
+            "prefill_calls": self.prefill_calls,
+            "prefill_traces": self.prefill_traces,
+            "jit_retraces": self.metrics.retraces(),
+            "slowest_step": self.metrics.slowest_step(),
+            "metrics": self.metrics.snapshot(),
+        }
+
+    def lower_decode(self, tier: int = 0) -> jax.stages.Lowered:
+        """Tier ``tier``'s decode program lowered at the engine's live
+        argument shapes, for inspection (compiled text, memory analysis)."""
+        s = self.ecfg.max_slots
+        with self._mesh_scope():
+            return self._decode.lower(
+                self._call_params(tier, self._slot_pool),
+                jnp.zeros((s, 1), jnp.int32), self._device_cache(),
+                jnp.zeros((s,), bool), jnp.asarray(self._steps, jnp.int32),
+            )
+
+    # ------------------------------------------------------------ intake ---
+
+    def submit(self, prompt: list[int], max_new_tokens: int = 16,
+               deadline: float | None = None,
+               tier: int | None = None,
+               submitted_at: float | None = None,
+               adapter: int | None = None) -> int:
+        """Enqueue a request. ``submitted_at`` (monotonic clock) lets open-
+        loop harnesses backdate the submission to the SCHEDULED arrival, so
+        TTFT/queue-wait metrics share one basis however the driver batches
+        its submits; None = now. ``adapter`` picks a registered AdapterBank
+        adapter (None = the bank's default; rejected on plain-bank engines)."""
+        try:
+            self._validate(prompt, max_new_tokens)
+            tier_r = self._resolve_tier(tier)
+            adapter_r = self._resolve_adapter(adapter)
+        except RequestRejected:
+            self.metrics.on_reject()
+            raise
+        self._uid += 1
+        self._queue.append(
+            Request(self._uid, list(prompt), max_new_tokens,
+                    submitted_at=_now() if submitted_at is None
+                    else submitted_at,
+                    deadline=deadline, tier=tier_r, adapter=adapter_r)
+        )
+        self.metrics.on_submit()
+        return self._uid
+
+    def _validate(self, prompt: list[int], max_new_tokens: int):
+        _validate_request(prompt, max_new_tokens, self.ecfg.max_len)
+        need = -(-(len(prompt) + max_new_tokens) // self._bs)
+        if need > self.num_blocks:
+            raise RequestRejected(
+                f"request needs {need} KV pages but the whole pool holds "
+                f"{self.num_blocks}"
+            )
+
+    def _resolve_tier(self, tier: int | None) -> int:
+        return _resolve_request_tier(self.bank, self._default_tier, tier)
+
+    def _resolve_adapter(self, adapter: int | None) -> int | None:
+        """Validated adapter id (None = the bank's default). Submit-time
+        errors are ``RequestRejected``, like tiers; an adapter unregistered
+        AFTER submit is caught at admission instead (the request finishes
+        rejected — the graceful error path)."""
+        if self._adapters is None:
+            if adapter is not None:
+                raise RequestRejected(
+                    f"adapter={adapter} needs an AdapterBank engine "
+                    "(serving.adapters); this engine serves a plain ModelBank"
+                )
+            return None
+        aid = self._adapters.default_adapter if adapter is None else adapter
+        if aid not in self._adapters.registry:
+            raise RequestRejected(f"unknown adapter id {aid}")
+        return aid
+
+    # ------------------------------------------------------------- tiers ---
+
+    def _effective_tier(self, req: Request) -> int:
+        """Requested tier plus the controller's downshift, clamped to the
+        cheap end of the bank (downshift only ever moves toward smaller
+        capacities; it never upgrades a request past what it asked for)."""
+        return min(req.tier + self._tier_shift, len(self._tier_params) - 1)
+
+    def _update_tier_shift(self):
+        """Integrate page pressure into the serving-tier downshift (BEFORE
+        ``_pre_decode`` can evict anyone — the controller spends capacity
+        quality first, requests last). Index-only cached pages count as free:
+        they are one ``reclaim`` away from the pool, so a cache-warm engine
+        must not read as a starved one."""
+        if self.tier_controller is None:
+            return
+        free_like = self.allocator.free_blocks + sum(
+            pc.reclaimable_pages for pc in self._all_prefixes()
+        )
+        self._tier_shift = self.tier_controller.update(
+            free_like / self.num_blocks
+        )
+        if self._tier_shift > 0:
+            self.metrics.inc(self.metrics.downshift_ticks)
+
+    def _refresh_slot_tiers(self):
+        """Recompute each active slot's effective tier. A change is pure
+        host-side bookkeeping — the KV cache is tier-agnostic (no copy) and
+        every tier's program is already compiled after its first use, so a
+        mid-stream switch costs nothing on device."""
+        for slot, req in self._active.items():
+            eff = self._effective_tier(req)
+            if eff != self._slot_tier[slot]:
+                self.metrics.inc(self.metrics.tier_switches)
+                if self.tracer is not None:
+                    self.tracer.instant(slot, "tier_switch", uid=req.uid,
+                                        frm=int(self._slot_tier[slot]), to=eff)
+                self._slot_tier[slot] = eff
+
+    def _tier_groups(self, slots) -> list[tuple]:
+        """Active slots grouped by program key (ascending): the effective
+        tier, widened — grouped adapter mode only — to ``(tier, pool row)``
+        so every call serves ONE adapter through the single-tenant ops.
+        Batched adapter mode keeps the plain tier key: one multi-adapter
+        program covers mixed-adapter slots."""
+        grouped = self._adapters is not None \
+            and self._adapters.mode == "grouped"
+        groups: dict = {}
+        for s in slots:
+            key = (int(self._slot_tier[s]), int(self._slot_pool[s])) \
+                if grouped else int(self._slot_tier[s])
+            groups.setdefault(key, []).append(s)
+        return sorted(groups.items())
+
+    # ---------------------------------------------------------- adapters ---
+
+    def _adapter_admit(self, req: Request, done: list[Request]):
+        """Acquire + pin ``req``'s adapter into the device pool. Returns
+        ``("ok", row)`` (``row`` is None on plain banks); ``("busy", None)``
+        when every pool row is pinned by a streaming slot — keep the request
+        queued and retry next tick; ``("gone", None)`` when the adapter was
+        unregistered after submit — the request finishes rejected."""
+        if self._adapters is None:
+            return "ok", None
+        try:
+            row, swapped = self._adapters.acquire(req.adapter)
+        except AdapterError:
+            req.done = True
+            req.finished_at = _now()
+            self.metrics.on_reject()
+            done.append(req)
+            return "gone", None
+        if row is None:
+            return "busy", None
+        if swapped:
+            self.metrics.inc(self.metrics.adapter_swaps)
+        self._adapters.pin(req.adapter)
+        self.metrics.set_resident_adapters(len(self._adapters.resident))
+        return "ok", row
+
+    def _call_params(self, key, rows=None):
+        """The parameter tree for ONE program call: the tier's tree on plain
+        banks; on AdapterBanks a fresh ``bind`` of the live pool — a grouped
+        key carries its pool row (one adapter per call, scalar sel), batched
+        calls bind the ``rows`` map (slot- or group-indexed, matching the
+        program's row convention). Binds are data-only: same treedef and
+        shapes every call, so programs never retrace across adapters."""
+        if self._adapters is None:
+            return self._tier_params[key]
+        if isinstance(key, tuple):            # grouped: (tier, pool row)
+            return self._adapters.bind(key[1])
+        return self._adapters.bind(np.asarray(rows, np.int32))
+
+    def _order_queue(self):
+        """Earliest-deadline-first admission order. Tiebreaks are stable:
+        among equal deadlines (or no deadlines at all) evicted/resumed requests go first — they already
+        spent pool time, and finishing them releases memory soonest — then
+        FIFO by uid. EDF stays primary: an evicted request with a LATER
+        deadline does not jump an urgent fresh one (the old paged queue-head
+        insert did exactly that, and the pre-admit re-sort then dropped it)."""
+        self._queue.sort(
+            key=lambda r: (
+                r.deadline is None, r.deadline or 0.0, -r.evictions, r.uid
+            )
+        )
+
+    @property
+    def has_work(self) -> bool:
+        return bool(self._queue or self._active)
+
+    @property
+    def queued(self) -> int:
+        return len(self._queue)
+
     def _prefix_of(self, aid: int | None) -> PrefixCache | None:
         """The radix index serving adapter ``aid``: the shared one on plain
         banks, a per-adapter index under multi-tenant serving (created on
@@ -1449,51 +1094,37 @@ class PagedServingEngine(ServingEngine):
             return [self._prefix]
         return list(self._prefix_caches.values())
 
-    def _update_gauges(self):
-        if not self.metrics.enabled:
-            return
-        super()._update_gauges()
-        # _prefix.pages (an O(1) count of index-held pages), NOT
-        # reclaimable_pages — that walks the whole radix tree and a per-tick
-        # walk is exactly the overhead telemetry promises not to add
-        self.metrics.set_pool(
-            free=self.allocator.free_blocks,
-            cached=sum(pc.pages for pc in self._all_prefixes()),
-        )
-
-    def _update_tier_shift(self):
-        """Integrate page pressure into the serving-tier downshift (BEFORE
-        ``_pre_decode`` can evict anyone — the controller spends capacity
-        quality first, requests last). Index-only cached pages count as free:
-        they are one ``reclaim`` away from the pool, so a cache-warm engine
-        must not read as a starved one."""
-        if self.tier_controller is None:
-            return
-        free_like = self.allocator.free_blocks + sum(
-            pc.reclaimable_pages for pc in self._all_prefixes()
-        )
-        self._tier_shift = self.tier_controller.update(
-            free_like / self.num_blocks
-        )
-        if self._tier_shift > 0:
-            self.metrics.inc(self.metrics.downshift_ticks)
-
-    # ------------------------------------------------------------ intake ---
-
-    def _validate(self, prompt: list[int], max_new_tokens: int):
-        super()._validate(prompt, max_new_tokens)
-        need = -(-(len(prompt) + max_new_tokens) // self._bs)
-        if need > self.num_blocks:
-            raise RequestRejected(
-                f"request needs {need} KV pages but the whole pool holds "
-                f"{self.num_blocks}"
-            )
-
-    def _bucket(self, n: int) -> int:
-        b = super()._bucket(n)
-        return min(-(-max(b, self._bs) // self._bs) * self._bs, self._max_len)
-
     # ----------------------------------------------------- device programs ---
+
+    def _sample(self, logits: jax.Array, step: jax.Array, salt: int,
+                slots: jax.Array | None = None) -> jax.Array:
+        """Greedy or temperature sampling, on device. logits: (S, vocab).
+
+        ``salt`` separates the prefill / decode / draft / verify streams — all
+        can sample within the same engine tick and must not share gumbel
+        noise. Each row additionally folds its slot id (``slots``; default row
+        index) into the key, so slots carry independent streams: eviction /
+        re-prefill resume and draft-vs-verify sampling never correlate across
+        slots. The greedy path is untouched.
+        """
+        if self.ecfg.greedy or self.ecfg.temperature <= 0:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        key = jax.random.fold_in(jax.random.fold_in(self._base_key, step), salt)
+        if slots is None:
+            slots = jnp.arange(logits.shape[0])
+        keys = jax.vmap(lambda s: jax.random.fold_in(key, s))(slots)
+        g = jax.vmap(lambda k: jax.random.gumbel(k, logits.shape[-1:]))(keys)
+        return jnp.argmax(logits / self.ecfg.temperature + g, axis=-1).astype(jnp.int32)
+
+    def _decode_fn(self, params, tokens, cache, active, step):
+        self.decode_traces += 1  # python side effect: counts traces only
+        logits, new_cache = model_lib.decode_step(params, tokens, cache, self.cfg)
+        # only active slots advance their valid prefix; inactive slots wrote a
+        # junk row at their frozen position — the next real token overwrites
+        # it, and a slot with no pages writes to an unmapped page (dropped)
+        new_len = jnp.where(active, new_cache.length, cache.length)
+        next_tok = self._sample(logits[:, -1], step, salt=0)
+        return next_tok, new_cache._replace(length=new_len)
 
     def _prefill_fn(self, params, tokens, lengths, slot_ids, page_map, cache, step):
         self.prefill_traces += 1
@@ -1541,6 +1172,16 @@ class PagedServingEngine(ServingEngine):
         return tok, cache
 
     # ------------------------------------------------------------- steps ---
+
+    def _bucket(self, n: int) -> int:
+        """Prefill width for an ``n``-token prompt: the next power-of-two
+        multiple of ``min_bucket``, rounded up to whole pages and capped at
+        the block table's span."""
+        b = self.ecfg.min_bucket
+        while b < n:
+            b *= 2
+        b = min(b, self.ecfg.max_len)
+        return min(-(-max(b, self._bs) // self._bs) * self._bs, self._max_len)
 
     def _admit(self, free: list[int], done: list[Request], step: int):
         """Admit every queued request that a free slot + free pages can cover
@@ -1909,6 +1550,46 @@ class PagedServingEngine(ServingEngine):
             with self.metrics.span("serve.fetch"):
                 return np.asarray(first)
 
+    def _record(self, slot: int, req: Request, tok: int, free, done):
+        now = _now()
+        req.out_tokens.append(tok)
+        req.token_times.append(now)
+        first = req.first_token_at == 0.0
+        if first:
+            req.first_token_at = now
+        # the ONE emission point: serve_tokens_total{kind="emitted"} counts
+        # each generated token exactly once, however many times eviction
+        # re-prefills its context (re-work lands in kind="prefill_compute")
+        self.metrics.on_token(req, now, first)
+        if self._adapters is not None:
+            self.metrics.inc(self.metrics.adapter_tokens, 1, str(req.adapter))
+        tr = self.tracer
+        if tr is not None and tr.has_open(slot, "prefill"):
+            # prefill (or resume re-prefill) just yielded its token: close
+            # the prefill span and open the decode envelope
+            tr.end_span(slot, "prefill", t=now)
+            if first:
+                tr.instant(slot, "first_token", t=now, uid=req.uid)
+            tr.begin_span(slot, "decode", t=now, uid=req.uid)
+        self._last_token[slot] = tok
+        if len(req.out_tokens) >= req.max_new_tokens or (
+            self.ecfg.eos_token is not None and tok == self.ecfg.eos_token
+        ):
+            req.done = True
+            req.finished_at = now
+            self.metrics.on_finish()
+            if tr is not None:
+                if tr.has_open(slot, "decode"):
+                    tr.end_span(slot, "decode", t=now)
+                tr.request_end(slot, req.uid, t=now,
+                               tokens=len(req.out_tokens),
+                               evictions=req.evictions)
+            done.append(req)
+            self._retire(slot, req)
+            del self._active[slot]
+            free.append(slot)
+            self._release(slot)
+
     def _pre_decode(self, free: list[int], done: list[Request]):
         """Grow each active slot's pages to cover this tick's KV writes; evict
         when the pool is dry. The next decode writes the KV of the latest
@@ -1916,7 +1597,7 @@ class PagedServingEngine(ServingEngine):
         engine widens the window (``_write_window`` > 1) to cover all k draft
         positions. Writes past the table's capacity drop device-side, so the
         need is capped at the table width."""
-        window = getattr(self, "_write_window", 1)
+        window = self._write_window
         for slot in list(self._active):
             req = self._active.get(slot)
             if req is None or slot in self._progress:
@@ -2002,7 +1683,12 @@ class PagedServingEngine(ServingEngine):
         del pages[:n_full]
 
     def _release(self, slot: int):
-        super()._release(slot)      # unpin the slot's adapter (base hook)
+        """Return the slot's pages and unpin its adapter (LRU residency may
+        swap the adapter out once no slot streams with it)."""
+        if self._adapters is not None:
+            aid = self._slot_adapter.pop(slot, None)
+            if aid is not None:
+                self._adapters.unpin(aid)
         pages = self._pages.pop(slot, None)
         if pages:
             # release, not free: attached pages fall back to their remaining
@@ -2043,6 +1729,127 @@ class PagedServingEngine(ServingEngine):
             self._len_reset.clear()
         return self.cache
 
+    def _place_cache(self):
+        """Shard the KV cache over the mesh (head axis over 'model'; block
+        tables / lengths replicated) and record the per-device pool bytes
+        gauge. No-op without a mesh — single-device arrays stay as-is."""
+        if self.mesh is not None:
+            self.cache = jax.device_put(
+                self.cache, self.mesh.cache_shardings(self.cache)
+            )
+        if self.metrics.enabled:
+            self.metrics.set_pool_device_bytes(_kv_pool_device_bytes(self.cache))
+
+    def _mesh_scope(self):
+        """The mesh context for one tick: activates the ServingMesh so
+        ``parallel.sharding.constrain`` and the shard_map-wrapped paged
+        kernels see it at trace time; a null context when unsharded."""
+        return self.mesh if self.mesh is not None else contextlib.nullcontext()
+
+    def step(self) -> list[Request]:
+        """ONE engine tick: admit whatever fits, refresh effective tiers
+        (pressure controller first — downshift precedes any eviction),
+        advance mid-prefill slots by one chunk, then one jitted decode step
+        per active tier over the decode-phase slots. Returns requests that
+        finished this tick."""
+        with self.metrics.measure_tick(self._steps + 1, len(self._active),
+                                       len(self._queue)):
+            with self._mesh_scope():
+                done = self._step_inner()
+            self._update_gauges()
+        return done
+
+    def _step_inner(self) -> list[Request]:
+        done: list[Request] = []
+        s = self.ecfg.max_slots
+        self._steps += 1
+        free = [x for x in range(s) if x not in self._active]
+        with self.metrics.span("serve.admit"):
+            self._admit(free, done, self._steps)
+        if not self._active:
+            return done
+        self._update_tier_shift()
+        self._refresh_slot_tiers()
+        self._prefill_progress(free, done, self._steps)
+        self._pre_decode(free, done)
+        active = np.zeros((s,), bool)
+        for slot in self._active:
+            if slot not in self._progress:   # mid-prefill slots don't decode
+                active[slot] = True
+        if active.any():
+            self._decode_tick(active, free, done)
+        return done
+
+    def _update_gauges(self):
+        """End-of-tick pool/queue gauges — host counters only, no device
+        reads. Short-circuits when telemetry is off so gauge ARGUMENTS cost
+        nothing either. ``_prefix.pages`` is an O(1) count of index-held
+        pages, NOT ``reclaimable_pages`` — that walks the whole radix tree,
+        and a per-tick walk is exactly the overhead telemetry promises not to
+        add."""
+        if not self.metrics.enabled:
+            return
+        self.metrics.set_pool(queue=len(self._queue),
+                              active=len(self._active),
+                              shift=self._tier_shift,
+                              free=self.allocator.free_blocks,
+                              cached=sum(pc.pages
+                                         for pc in self._all_prefixes()))
+        if self._adapters is not None:
+            self.metrics.set_resident_adapters(len(self._adapters.resident))
+
+    def _decode_tick(self, active: np.ndarray, free: list[int],
+                     done: list[Request]):
+        """Device portion of a tick (hook: the speculative engine replaces
+        this with its draft + k-wide verify program): ONE jitted decode per
+        active tier, every call masked to its tier's slots over the shared
+        cache. A single-tier bank degenerates to exactly one call per tick;
+        each tier's program compiles once, on first use, so a slot switching
+        tiers mid-stream never triggers a retrace."""
+        s = self.ecfg.max_slots
+        tokens = np.zeros((s, 1), np.int32)
+        decode_slots = [int(x) for x in np.nonzero(active)[0]]
+        for slot in decode_slots:
+            tokens[slot, 0] = self._last_token[slot]
+        tok_dev = jnp.asarray(tokens)
+        step_dev = jnp.asarray(self._steps, jnp.int32)
+        out = np.zeros((s,), np.int64)
+        for key, slots in self._tier_groups(decode_slots):
+            mask = np.zeros((s,), bool)
+            mask[slots] = True
+            with self.metrics.measure_program(
+                "decode", key, traces=lambda: self.decode_traces,
+                rows=len(slots),
+            ):
+                nxt, self.cache = self._decode(
+                    self._call_params(key, self._slot_pool), tok_dev,
+                    self._device_cache(), jnp.asarray(mask), step_dev,
+                )
+                self.decode_calls += 1
+                with self.metrics.span("serve.fetch"):
+                    toks = np.asarray(nxt)   # one host sync per active tier
+            out[slots] = toks[slots]
+        with self.metrics.span("serve.record"):
+            for slot, req in list(self._active.items()):
+                if slot in self._progress:
+                    continue
+                self._record(slot, req, int(out[slot]), free, done)
+
+    def run(self, max_steps: int = 10_000) -> list[Request]:
+        """Drive everything to completion (batch mode)."""
+        done: list[Request] = []
+        steps = 0
+        while self.has_work and steps < max_steps:
+            steps += 1
+            done.extend(self.step())
+        return done
+
+
+# The benchmark's own tests (bench/tests/test_bench_check.py and
+# bench/tests/test_bench_phi3.py) patch ``ServingEngine._record``; the name
+# stays for them and nothing else uses it.
+ServingEngine = PagedServingEngine
+
 
 # -------------------------------------------------------------- reference ---
 
@@ -2051,8 +1858,9 @@ class ReferenceEngine:
     """The seed per-slot, per-token engine (scalar-length cache, slot slicing
     with host write-back, one ``int()`` sync per slot per token).
 
-    Kept as (a) the measured baseline for ``benchmarks/serve_throughput.py``
-    and (b) the serving path for cache families without per-slot lengths.
+    Kept as (a) the serving path for cache families without per-slot lengths
+    and (b) the test oracle for :class:`PagedServingEngine`, sharing none of
+    its paged code.
     Implements the :class:`~repro.serving.elastic.Engine` protocol, including
     per-request bank tiers (each slot decodes with its requested tier's
     parameter tree — no pressure controller, there is no page pool to feel
@@ -2080,13 +1888,13 @@ class ReferenceEngine:
             missing.append("prefix_cache=True (radix prompt cache)")
         if ecfg.mesh is not None:
             missing.append(
-                f"mesh={ecfg.mesh!r} (tensor-parallel serving needs the "
-                "batched engines)"
+                f"mesh={ecfg.mesh!r} (tensor-parallel serving needs "
+                "PagedServingEngine)"
             )
         if ecfg.adapters or isinstance(bank, AdapterBank):
             missing.append(
-                "multi-tenant adapters (AdapterBank serving needs the "
-                "batched engines)"
+                "multi-tenant adapters (AdapterBank serving needs "
+                "PagedServingEngine)"
             )
         if missing:
             raise _capability_error(type(self), arch_cfg.family, missing)
@@ -2118,7 +1926,7 @@ class ReferenceEngine:
             return model_lib.decode_step(p, tok, cache, arch_cfg)
 
         self._decode = jax.jit(_decode_fn)
-        # the same telemetry schema as the batched engines (the registry is
+        # the same telemetry schema as the batched engine (the registry is
         # shared infrastructure, not a paged-engine feature)
         self.metrics = (EngineTelemetry if ecfg.telemetry
                         else NullTelemetry)(type(self).__name__)
@@ -2149,10 +1957,10 @@ class ReferenceEngine:
 
     # ----------------------------------------------------- observability ---
 
-    start_trace = ServingEngine.start_trace
+    start_trace = PagedServingEngine.start_trace
 
     def stats_snapshot(self) -> dict:
-        """Same shape as the batched engines' snapshot; the reference loop
+        """Same shape as the batched engine's snapshot; the reference loop
         has no prefill program (prompts insert token-by-token through the
         decode step), so the prefill counters report 0."""
         return {
@@ -2180,7 +1988,7 @@ class ReferenceEngine:
             if adapter is not None:
                 raise RequestRejected(
                     f"adapter={adapter}: ReferenceEngine serves no adapters "
-                    "(AdapterBank needs the batched engines)"
+                    "(AdapterBank needs PagedServingEngine)"
                 )
         except RequestRejected:
             self.metrics.on_reject()

@@ -566,8 +566,8 @@ class SpeculativeEngine(PagedServingEngine):
                           tier: int = 0, rows=None):
         # `tier` is the base engine's grouping hook; here it is always the
         # target tier (the draft prefills alongside in the same program).
-        # `rows` is the adapter-pool map — always None here: _init_common
-        # rejects AdapterBanks on speculative engines
+        # `rows` is the adapter-pool map — always None here: the engine's
+        # constructor rejects AdapterBanks on speculative engines
         del rows
         real = slot_ids < slot_ids.size
         with self.metrics.measure_program(

@@ -35,7 +35,6 @@ from repro.serving.engine import (
     PagedServingEngine,
     ReferenceEngine,
     RequestRejected,
-    ServingEngine,
 )
 from repro.serving.speculative import SpeculativeEngine
 
@@ -129,16 +128,6 @@ class TestSingleTenantParity:
         got = run_multi(eng, prompts, [0, 0])
         assert got == run_single(PagedServingEngine, cfg, adapters[1],
                                  prompts, **ekw)
-
-    def test_slot_padded_engine_parity(self, trained, deployed):
-        cfg, _, _ = trained
-        base, adapters = deployed["fused"]
-        eng = ServingEngine(AdapterBank(base, [adapters[1]]),
-                            EngineConfig(adapters=True, max_slots=2,
-                                         max_len=32))
-        got = run_multi(eng, PROMPTS[:2], [0, 0])
-        assert got == run_single(ServingEngine, cfg, adapters[1],
-                                 PROMPTS[:2], max_slots=2, max_len=32)
 
 
 class TestMixedAdapters:
@@ -311,14 +300,14 @@ class TestValidation:
                                                   block_size=8))
         cfg, params, _ = trained
         with pytest.raises(ValueError):
-            ServingEngine(ModelBank.single(cfg, params),
-                          EngineConfig(adapters=True, max_slots=2,
-                                       max_len=32))
+            PagedServingEngine(ModelBank.single(cfg, params),
+                               EngineConfig(adapters=True, max_slots=2,
+                                            max_len=32))
 
     def test_adapter_kwarg_without_bank_rejected(self, trained):
         cfg, params, _ = trained
-        eng = ServingEngine(ModelBank.single(cfg, params),
-                            EngineConfig(max_slots=2, max_len=32))
+        eng = PagedServingEngine(ModelBank.single(cfg, params),
+                                 EngineConfig(max_slots=2, max_len=32))
         with pytest.raises(RequestRejected):
             eng.submit([1, 2], max_new_tokens=2, adapter=0)
 

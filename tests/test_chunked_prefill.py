@@ -12,8 +12,7 @@ not raw float pages).
 
 Also covers the PR 4 satellites: ``decode_emitted_tokens`` accounting when an
 eviction lands mid-prefill (the old ``1 + evictions`` convention overcounted
-prefill emissions), EDF admission ordering unified across both batched
-engines, monotonic-clock timestamps, and the query-tiled k-query kernel at
+prefill emissions), EDF admission ordering, monotonic-clock timestamps, and the query-tiled k-query kernel at
 chunk widths.
 """
 import dataclasses
@@ -22,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _greedy_oracle import greedy_streams
 
 from repro.configs.base import get_arch
 from repro.kernels.ops import paged_attention_kquery
@@ -36,7 +36,6 @@ from repro.serving.engine import (
     PagedServingEngine,
     ReferenceEngine,
     Request,
-    ServingEngine,
     decode_emitted_tokens,
 )
 from repro.serving.speculative import SpeculativeEngine
@@ -306,13 +305,12 @@ class TestChunkedEngineEquivalence:
     @pytest.mark.parametrize("chunk", [8, 16, 32])
     def test_streams_match_oneshot_and_padded(self, tiny, chunk):
         """The core acceptance invariant: chunked greedy output is bitwise
-        identical to one-shot paged AND slot-padded output, under slot reuse
-        and mid-stream admission; the chunk program compiles exactly once and
-        fully replaces the one-shot prefill program."""
+        identical to one-shot paged output AND to greedy decoding through the
+        plain model API, under slot reuse and mid-stream admission; the chunk
+        program compiles exactly once and fully replaces the one-shot prefill
+        program."""
         cfg, params = tiny
-        ref = run_tokens(
-            ServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=64))
-        )
+        ref = greedy_streams(cfg, params, PROMPTS, 5, 64)
         one = run_tokens(PagedServingEngine(
             ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=64, block_size=8)
         ))
@@ -399,10 +397,6 @@ class TestChunkedEngineEquivalence:
         'never silently drop a requested feature' convention)."""
         cfg, params = tiny
         with pytest.raises(EngineCapabilityError):
-            ServingEngine(ModelBank.single(cfg, params), EngineConfig(
-                max_slots=2, max_len=64, prefill_chunk=16
-            ))
-        with pytest.raises(EngineCapabilityError):
             ReferenceEngine(ModelBank.single(cfg, params), EngineConfig(
                 max_slots=2, max_len=64, prefill_chunk=16
             ))
@@ -416,8 +410,7 @@ class TestChunkedEviction:
         prefill token, so prefill_emitted == 1 + evictions here."""
         cfg, params = tiny
         prompts = [[5, 7, 11], [3, 1, 4]]
-        e_ref = ServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=16))
-        ref = run_tokens(e_ref, prompts, max_new=10)
+        ref = greedy_streams(cfg, params, prompts, 10, 16)
 
         eng = PagedServingEngine(ModelBank.single(cfg, params), EngineConfig(
             max_slots=2, max_len=16, block_size=4, num_blocks=4,
@@ -447,8 +440,7 @@ class TestChunkedEviction:
         tokens."""
         cfg, params = tiny
         prompts = [list(range(2, 22)), list(range(30, 50))]   # 20 toks each
-        e_ref = ServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=32))
-        ref = run_tokens(e_ref, prompts, max_new=4)
+        ref = greedy_streams(cfg, params, prompts, 4, 32)
 
         eng = PagedServingEngine(ModelBank.single(cfg, params), EngineConfig(
             max_slots=2, max_len=32, block_size=4, num_blocks=8,
@@ -483,8 +475,7 @@ class TestChunkedEviction:
         victim, so both requests finish with the reference streams."""
         cfg, params = tiny
         prompts = [list(range(1, 49)), list(range(50, 98))]   # 48 toks each
-        e_ref = ServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=64))
-        ref = run_tokens(e_ref, prompts, max_new=4)
+        ref = greedy_streams(cfg, params, prompts, 4, 64)
         eng = PagedServingEngine(ModelBank.single(cfg, params), EngineConfig(
             max_slots=2, max_len=64, block_size=8, num_blocks=9,
             prefill_chunk=8,
@@ -506,8 +497,7 @@ class TestChunkedEviction:
         cfg, params = tiny
         prompts = [list(range(1, 41)), list(range(41, 81)),
                    list(range(81, 121))]                  # 40 toks each
-        e_ref = ServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=3, max_len=64))
-        ref = run_tokens(e_ref, prompts, max_new=4)
+        ref = greedy_streams(cfg, params, prompts, 4, 64)
         eng = PagedServingEngine(ModelBank.single(cfg, params), EngineConfig(
             max_slots=3, max_len=64, block_size=4, num_blocks=14,
             prefill_chunk=8,
@@ -529,8 +519,7 @@ class TestChunkedEviction:
         # the short request finishes prefill immediately and decodes while
         # the long one's chunks grow into the pool
         prompts = [list(range(2, 26)), [7, 7, 7]]
-        e_ref = ServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=32))
-        ref = run_tokens(e_ref, prompts, max_new=6)
+        ref = greedy_streams(cfg, params, prompts, 6, 32)
         eng = PagedServingEngine(ModelBank.single(cfg, params), EngineConfig(
             max_slots=2, max_len=32, block_size=4, num_blocks=8,
             decode_reserve=1, prefill_chunk=4,
@@ -549,13 +538,16 @@ class TestEDFAdmission:
         return Request(uid, [1], deadline=deadline, evictions=evictions)
 
     def test_order_unified_across_engines(self, tiny):
-        """Both batched engines share one EDF order: earliest deadline first,
+        """The batched engines (the paged engine and its speculative
+        subclass) share one EDF order: earliest deadline first,
         evicted/resumed requests break ties, then FIFO."""
         cfg, params = tiny
         for eng in (
-            ServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=16)),
             PagedServingEngine(ModelBank.single(cfg, params), EngineConfig(
                 max_slots=2, max_len=16, block_size=8
+            )),
+            SpeculativeEngine(ModelBank.single(cfg, params), EngineConfig(
+                max_slots=2, max_len=16, block_size=8, spec_k=2
             )),
         ):
             eng._queue = [
@@ -569,11 +561,14 @@ class TestEDFAdmission:
             eng._order_queue()
             assert [r.uid for r in eng._queue] == [5, 4, 2, 1, 3, 6]
 
-    def test_padded_engine_admits_edf(self, tiny):
-        """The slot-padded engine used to pop FIFO ignoring deadlines; now an
-        urgent late submission is admitted (and finishes) first."""
+    @pytest.mark.parametrize("chunk", [None, 8])
+    def test_paged_engine_admits_edf(self, tiny, chunk):
+        """One slot, three queued requests: an urgent late submission is
+        admitted (and finishes) first, with one-shot and chunked prefill."""
         cfg, params = tiny
-        eng = ServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=1, max_len=32))
+        eng = PagedServingEngine(ModelBank.single(cfg, params), EngineConfig(
+            max_slots=1, max_len=32, block_size=8, prefill_chunk=chunk
+        ))
         eng.submit([5, 7, 11], max_new_tokens=3, deadline=100.0)
         eng.submit([3, 1], max_new_tokens=3, deadline=50.0)
         eng.submit([8, 8, 2], max_new_tokens=3, deadline=1.0)

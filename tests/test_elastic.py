@@ -195,7 +195,6 @@ class TestEngineProtocol:
         cfg, params = tiny
         bank = ModelBank.single(cfg, params)
         engines = [
-            ServingEngine(bank, EngineConfig(max_slots=1, max_len=16)),
             PagedServingEngine(bank, EngineConfig(max_slots=1, max_len=16,
                                                   block_size=8)),
             ReferenceEngine(bank, EngineConfig(max_slots=1, max_len=16)),
@@ -206,18 +205,36 @@ class TestEngineProtocol:
             assert isinstance(eng, Engine), type(eng).__name__
 
     def test_capabilities_are_structured(self):
-        for cls in (ServingEngine, PagedServingEngine, ReferenceEngine,
-                    SpeculativeEngine):
+        for cls in (PagedServingEngine, ReferenceEngine, SpeculativeEngine):
             caps = cls.capabilities()
             assert caps["engine"] == cls.__name__
             assert isinstance(caps["families"], list)
             assert isinstance(caps["features"], dict)
             json.dumps(caps)                      # serializable by contract
         assert PagedServingEngine.capabilities()["features"]["chunked_prefill"]
-        assert not ServingEngine.capabilities()["features"]["chunked_prefill"]
+        assert not ReferenceEngine.capabilities()["features"]["chunked_prefill"]
         assert "int8" in PagedServingEngine.capabilities()["features"]["kv_dtype"]
         assert "ssm" in ReferenceEngine.capabilities()["families"]
         assert SpeculativeEngine.capabilities()["features"]["speculative"]
+
+    def test_serving_engine_name_is_the_paged_engine(self):
+        """``ServingEngine`` is only another name for the batched engine, so
+        code that patches it (the benchmark's falsification tests) patches
+        the engine that serves."""
+        assert ServingEngine is PagedServingEngine
+
+    @pytest.mark.parametrize("family", ["ssm", "hybrid", "encdec"])
+    def test_batched_engine_refuses_families_without_kv_lengths(self, tiny,
+                                                                family):
+        """Families without a per-slot-length KV cache are served by
+        ReferenceEngine; the batched engine refuses them at construction and
+        names the engine to use."""
+        cfg, params = tiny
+        bank = ModelBank.single(dataclasses.replace(cfg, family=family),
+                                params)
+        with pytest.raises(ValueError, match="ReferenceEngine"):
+            PagedServingEngine(bank, EngineConfig(max_slots=1, max_len=16,
+                                                  block_size=8))
 
     def test_capability_table_renders(self):
         table = format_capability_table({
@@ -271,8 +288,6 @@ class TestStructuredCapabilityErrors:
         cfg, params = tiny
         bank = ModelBank.single(cfg, params)
         with pytest.raises(EngineCapabilityError):
-            ServingEngine(bank, EngineConfig(tier_policy="pressure"))
-        with pytest.raises(EngineCapabilityError):
             ReferenceEngine(bank, EngineConfig(tier_policy="pressure"))
         # the paged engine accepts it
         PagedServingEngine(bank, EngineConfig(tier_policy="pressure",
@@ -311,10 +326,11 @@ class TestRemovedCtors:
     def test_old_ctor_raises_and_message_names_bank(self, tiny):
         cfg, params = tiny
         with pytest.raises(TypeError, match="ModelBank"):
-            ServingEngine(cfg, params, EngineConfig(max_slots=2, max_len=32))
+            PagedServingEngine(cfg, params, EngineConfig(max_slots=2,
+                                                         max_len=32))
         # the replacement form serves fine
-        new = ServingEngine(ModelBank.single(cfg, params),
-                            EngineConfig(max_slots=2, max_len=32))
+        new = PagedServingEngine(ModelBank.single(cfg, params),
+                                 EngineConfig(max_slots=2, max_len=32))
         assert run_tokens(new, [[5, 7, 11], [3, 1]])
 
     def test_old_paged_and_spec_ctors_raise(self, tiny):
@@ -330,11 +346,11 @@ class TestRemovedCtors:
         cfg, params = tiny
         bank = ModelBank.single(cfg, params)
         with pytest.raises(TypeError):
-            ServingEngine(bank, params)          # weights after a bank
+            PagedServingEngine(bank, params)          # weights after a bank
         with pytest.raises(TypeError):
-            ServingEngine(cfg, EngineConfig())   # old form missing weights
+            PagedServingEngine(cfg, EngineConfig())   # old form, no weights
         with pytest.raises(TypeError):
-            ServingEngine(params)                # raw tree: no arch config
+            PagedServingEngine(params)                # raw tree: no arch cfg
 
     def test_keyword_ecfg_accepted(self, tiny):
         """The documented call shape Engine(bank, ecfg=...) must work by
@@ -342,7 +358,8 @@ class TestRemovedCtors:
         used to mistake keyword ecfg for the deprecated third argument)."""
         cfg, params = tiny
         bank = ModelBank.single(cfg, params)
-        eng = ServingEngine(bank, ecfg=EngineConfig(max_slots=1, max_len=16))
+        eng = PagedServingEngine(bank, ecfg=EngineConfig(max_slots=1,
+                                                         max_len=16))
         assert eng.ecfg.max_slots == 1
         spec = SpeculativeEngine(bank, ecfg=EngineConfig(
             max_slots=1, max_len=16, block_size=8, spec_k=2))
@@ -406,21 +423,6 @@ class TestTierEquivalence:
             dict(max_slots=3, max_len=64, block_size=8, prefill_chunk=8),
         )
         assert eng.chunk_calls > 0     # the chunk path actually ran
-
-    def test_batched_engine_serves_tiers_too(self, banks):
-        """The slot-padded engine shares the tier grouping: pinned slots
-        match fixed-budget batched engines."""
-        bank = banks["factored"]
-        ecfg_kw = dict(max_slots=3, max_len=32)
-        eng = ServingEngine(bank, EngineConfig(**ecfg_kw))
-        for i, p in enumerate(self.PROMPTS):
-            eng.submit(p, max_new_tokens=4, tier=i)
-        multi = {r.tier: r.out_tokens for r in eng.run()}
-        for t in range(3):
-            fixed = ServingEngine(ModelBank.single(bank.cfg, bank[t].model),
-                                  EngineConfig(**ecfg_kw))
-            fixed.submit(self.PROMPTS[t], max_new_tokens=4)
-            assert multi[t] == fixed.run()[0].out_tokens
 
     def test_reference_engine_serves_tiers(self, banks):
         bank = banks["dense"]

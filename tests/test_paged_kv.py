@@ -3,13 +3,15 @@
 Covers the PR 2 tentpole invariants: block-allocator accounting, paged
 decode attention matching the contiguous cache bitwise, the Pallas paged
 kernel matching its jnp oracle, and the paged engine producing IDENTICAL
-token streams to the slot-padded engine on a fixed trace — including under
-mid-stream admission, forced eviction, and int8 page quantization.
+token streams to greedy decoding through the plain model API on a fixed trace
+— including under mid-stream admission, forced eviction, and int8 page
+quantization.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _greedy_oracle import greedy_streams
 
 from repro.configs.base import get_arch
 from repro.kernels.ops import paged_attention
@@ -23,7 +25,6 @@ from repro.serving.engine import (
     PagedServingEngine,
     ReferenceEngine,
     RequestRejected,
-    ServingEngine,
 )
 
 
@@ -207,9 +208,10 @@ class TestPagedEngine:
 
     def test_matches_unpaged_engine_midstream_admission(self, tiny):
         """6 requests over 2 slots: admissions happen mid-stream while other
-        slots are mid-decode; token streams must be identical per uid."""
+        slots are mid-decode; each uid's stream must be identical to its
+        greedy decode on a private contiguous cache."""
         cfg, params = tiny
-        ref = self._tokens(ServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=32)))
+        ref = greedy_streams(cfg, params, self.PROMPTS, 5, 32)
         got = self._tokens(PagedServingEngine(
             ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=32, block_size=8)
         ))
@@ -222,10 +224,7 @@ class TestPagedEngine:
         request resumes by re-prefilling and must emit the same tokens."""
         cfg, params = tiny
         prompts = [[5, 7, 11], [3, 1, 4]]
-        e_ref = ServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=16))
-        for p in prompts:
-            e_ref.submit(p, max_new_tokens=10)
-        ref = {r.uid: r.out_tokens for r in e_ref.run()}
+        ref = greedy_streams(cfg, params, prompts, 10, 16)
 
         eng = PagedServingEngine(ModelBank.single(cfg, params), EngineConfig(
             max_slots=2, max_len=16, block_size=4, num_blocks=4,
@@ -257,7 +256,6 @@ class TestPagedEngine:
     def test_rejects_oversized_requests(self, tiny):
         cfg, params = tiny
         for eng in (
-            ServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=16)),
             PagedServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=16, block_size=8)),
             ReferenceEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=16)),
         ):
@@ -291,11 +289,6 @@ class TestPagedEngine:
         assert eng.cache.k.dtype == jnp.int8 and eng.cache.k_scale is not None
         got = self._tokens(eng)
         assert got == ref
-
-    def test_int8_rejected_by_contiguous_engine(self, tiny):
-        cfg, params = tiny
-        with pytest.raises(ValueError):
-            ServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, kv_dtype="int8"))
 
     def test_pallas_kernel_through_engine(self, tiny):
         """kernel_impl='pallas' routes paged decode through the Pallas kernel

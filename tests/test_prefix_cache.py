@@ -34,7 +34,6 @@ from repro.serving.engine import (
     EngineConfig,
     PagedServingEngine,
     ReferenceEngine,
-    ServingEngine,
 )
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.speculative import SpeculativeEngine
@@ -401,9 +400,12 @@ class TestSpeculativeEquivalence:
 
 class TestCapabilityGates:
     def test_batched_engine_rejects_prefix_cache(self, tiny):
+        """The refusal carries the structured capability report, which says
+        the engine has no prefix caching."""
         cfg, params = tiny
-        with pytest.raises(EngineCapabilityError, match="page pool"):
-            ServingEngine(ModelBank.single(cfg, params), EngineConfig(prefix_cache=True))
+        with pytest.raises(EngineCapabilityError,
+                           match='"prefix_caching": false'):
+            ReferenceEngine(ModelBank.single(cfg, params), EngineConfig(prefix_cache=True))
 
     def test_reference_engine_rejects_prefix_cache(self, tiny):
         cfg, params = tiny

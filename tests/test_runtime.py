@@ -255,11 +255,11 @@ class TestGradCompression:
 class TestServingEngine:
     def test_batch_serving_completes(self):
         from repro.serving.elastic import ModelBank
-        from repro.serving.engine import EngineConfig, ServingEngine
+        from repro.serving.engine import EngineConfig, PagedServingEngine
 
         cfg = get_arch("olmo_1b").reduced()
         params = model_lib.init_params(cfg, jax.random.PRNGKey(0))
-        eng = ServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=32))
+        eng = PagedServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=32))
         uids = [eng.submit([1, 2, 3], max_new_tokens=4) for _ in range(5)]
         done = eng.run()
         assert len(done) == 5
@@ -268,12 +268,12 @@ class TestServingEngine:
     def test_engine_matches_direct_decode(self):
         """Engine output == greedy decode with the plain model API."""
         from repro.serving.elastic import ModelBank
-        from repro.serving.engine import EngineConfig, ServingEngine
+        from repro.serving.engine import EngineConfig, PagedServingEngine
 
         cfg = get_arch("olmo_1b").reduced()
         params = model_lib.init_params(cfg, jax.random.PRNGKey(0))
         prompt = [5, 7, 11]
-        eng = ServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=32))
+        eng = PagedServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=32))
         eng.submit(prompt, max_new_tokens=3)
         out = eng.run()[0].out_tokens
 

@@ -12,7 +12,7 @@ from repro.core.selection import SelectionConfig
 from repro.models import model as model_lib
 from repro.serving.deployed import DeployedModel
 from repro.serving.elastic import ModelBank
-from repro.serving.engine import EngineConfig, ServingEngine
+from repro.serving.engine import EngineConfig, PagedServingEngine
 from repro.serving.slr_params import build_slr_linears, deployment_report
 
 
@@ -128,7 +128,7 @@ class TestBatchedEngine:
 
     def test_one_device_call_per_decode_step(self, trained):
         cfg, params, state, blocks = trained
-        eng = ServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=32))
+        eng = PagedServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=32))
         for i in range(5):
             eng.submit([1 + i, 2, 3], max_new_tokens=4)
         done = eng.run()
@@ -147,7 +147,7 @@ class TestBatchedEngine:
         """Per-slot lengths + batched sampling == independent greedy rollouts."""
         cfg, params, state, blocks = trained
         prompts = [[5, 7, 11], [3, 1], [2, 9, 4, 6]]
-        eng = ServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=32))
+        eng = PagedServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=32))
         for p in prompts:
             eng.submit(p, max_new_tokens=4)
         by_uid = {r.uid: r.out_tokens for r in eng.run()}
@@ -161,7 +161,7 @@ class TestBatchedEngine:
         outs = {}
         for fmt in ("dense", "factored"):
             dm = DeployedModel.build(cfg, params, comp, blocks, fmt=fmt)
-            eng = ServingEngine(dm, EngineConfig(max_slots=2, max_len=32))
+            eng = PagedServingEngine(dm, EngineConfig(max_slots=2, max_len=32))
             eng.submit([4, 8, 15], max_new_tokens=4)
             eng.submit([16, 23], max_new_tokens=4)
             outs[fmt] = [r.out_tokens for r in sorted(eng.run(), key=lambda r: r.uid)]

@@ -33,7 +33,6 @@ from repro.serving.engine import (
     EngineConfig,
     PagedServingEngine,
     ReferenceEngine,
-    ServingEngine,
     _device_put_tiers,
     _kv_pool_device_bytes,
 )
@@ -91,7 +90,7 @@ class TestMeshSpec:
         assert json.loads(json.dumps(dataclasses.asdict(cfg)))["mesh"] == "model=2"
 
     def test_capabilities_report_tensor_parallel(self):
-        for eng in (ServingEngine, PagedServingEngine, SpeculativeEngine):
+        for eng in (PagedServingEngine, SpeculativeEngine):
             assert eng.capabilities()["features"]["tensor_parallel"] is True
         assert ReferenceEngine.capabilities()["features"]["tensor_parallel"] \
             is False
@@ -171,14 +170,6 @@ class TestShardedParity:
                 eng = PagedServingEngine(bank, ecfg(mesh=spec, **kw))
                 assert drive(eng, False) == base, (kw, spec)
                 assert eng.stats_snapshot()["jit_retraces"] == 0
-
-    def test_slot_padded_engine(self, trained):
-        *_, bank = trained
-        base = drive(ServingEngine(bank, EngineConfig(max_slots=4, max_len=32)))
-        eng = ServingEngine(bank, EngineConfig(max_slots=4, max_len=32,
-                                               mesh="model=2"))
-        assert drive(eng) == base
-        assert eng.stats_snapshot()["jit_retraces"] == 0
 
     def test_logits_allclose(self, trained):
         """Full-forward oracle: logits under the sharded param placement are

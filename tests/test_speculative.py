@@ -31,7 +31,6 @@ from repro.serving.engine import (
     PagedServingEngine,
     ReferenceEngine,
     RequestRejected,
-    ServingEngine,
 )
 from repro.serving.speculative import (
     SpecController,
@@ -411,7 +410,7 @@ class TestPerSlotPRNG:
         """Same logits + same slot id => same sample; different slot ids =>
         independent streams (and the greedy path ignores slots entirely)."""
         cfg, params, _ = tiny
-        eng = ServingEngine(ModelBank.single(cfg, params), EngineConfig(
+        eng = PagedServingEngine(ModelBank.single(cfg, params), EngineConfig(
             max_slots=4, max_len=32, greedy=False, temperature=1.0
         ))
         logits = jnp.tile(
@@ -428,7 +427,7 @@ class TestPerSlotPRNG:
 
     def test_greedy_untouched(self, tiny):
         cfg, params, _ = tiny
-        eng = ServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=32))
+        eng = PagedServingEngine(ModelBank.single(cfg, params), EngineConfig(max_slots=2, max_len=32))
         logits = jax.random.normal(jax.random.PRNGKey(1), (2, cfg.vocab_size))
         out = eng._sample(logits, jnp.asarray(0), salt=0)
         assert np.array_equal(np.asarray(out), np.asarray(jnp.argmax(logits, -1)))
@@ -446,7 +445,7 @@ class TestReferenceEngineCapabilities:
         """spec_k must never be silently ignored: only SpeculativeEngine
         consumes it, every other engine fails loudly."""
         cfg, params, _ = tiny
-        for cls in (ServingEngine, PagedServingEngine):
+        for cls in (PagedServingEngine, ReferenceEngine):
             with pytest.raises(EngineCapabilityError):
                 cls(ModelBank.single(cfg, params), EngineConfig(max_slots=2, spec_k=4))
 

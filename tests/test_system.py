@@ -15,7 +15,7 @@ from repro.data.synthetic import DataConfig, SyntheticC4
 from repro.models import model as model_lib
 from repro.optim.adam import AdamConfig
 from repro.serving.elastic import ModelBank
-from repro.serving.engine import EngineConfig, ServingEngine
+from repro.serving.engine import EngineConfig, PagedServingEngine
 from repro.train import checkpoint
 from repro.train.trainer import Trainer, TrainerConfig
 
@@ -88,8 +88,8 @@ class TestEndToEnd:
         cfg, trainer, state, data, _ = pipeline
         slr_c, _ = hpa_keep_ratio(state.slr, trainer.blocks, 0.6, kappa=0.7)
         deploy = surrogate_params(state.params, slr_c, trainer.blocks)
-        engine = ServingEngine(ModelBank.single(cfg, deploy),
-                               EngineConfig(max_slots=2, max_len=48))
+        engine = PagedServingEngine(ModelBank.single(cfg, deploy),
+                                    EngineConfig(max_slots=2, max_len=48))
         engine.submit([1, 2, 3], max_new_tokens=4)
         engine.submit([4, 5], max_new_tokens=4)
         done = engine.run()

@@ -33,7 +33,6 @@ from repro.serving.engine import (
     EngineConfig,
     PagedServingEngine,
     ReferenceEngine,
-    ServingEngine,
 )
 from repro.serving.speculative import SpeculativeEngine
 from repro.serving.telemetry import (
@@ -391,7 +390,6 @@ class TestProvenance:
         # construction only — provenance never runs the model, so these
         # engines jit nothing
         engines = [
-            ServingEngine(bank, EngineConfig(max_slots=1, max_len=16)),
             PagedServingEngine(bank, EngineConfig(max_slots=1, max_len=16,
                                                   block_size=8)),
             ReferenceEngine(bank, EngineConfig(max_slots=1, max_len=16)),
@@ -419,7 +417,6 @@ class TestProvenance:
         cfg, params = tiny
         bank = ModelBank.single(cfg, params)
         engines = [
-            ServingEngine(bank, EngineConfig(max_slots=1, max_len=16)),
             PagedServingEngine(bank, EngineConfig(max_slots=1, max_len=16,
                                                   block_size=8)),
             ReferenceEngine(bank, EngineConfig(max_slots=1, max_len=16)),
